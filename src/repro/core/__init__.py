@@ -21,8 +21,7 @@ from repro.core.init import init_centroids, kmeans_plus_plus, random_init
 from repro.core.kmeans import (KMeans, KMeansConfig, KMeansState, lloyd_stats,
                                lloyd_step, make_kmeans_fn)
 from repro.core.parallel import (ParallelContext, build_mesh, make_host_mesh,
-                                 make_production_mesh, parse_mesh_flag,
-                                 shard_map_compat)
+                                 make_production_mesh, parse_mesh_flag)
 from repro.core.plan import (KernelPlan, KernelPlanner, default_planner,
                              detect_hardware, set_default_planner)
 from repro.core.streaming import (StreamingKMeans, SufficientStats,
@@ -33,7 +32,7 @@ __all__ = [
     "make_kmeans_fn",
     "make_distributed_kmeans", "shard_points", "ChunkedKMeans", "ChunkedStats",
     "ParallelContext", "build_mesh", "make_host_mesh", "make_production_mesh",
-    "parse_mesh_flag", "shard_map_compat",
+    "parse_mesh_flag",
     "StreamingKMeans", "SufficientStats", "partial_fit_step",
     "KernelPlan", "KernelPlanner", "default_planner", "detect_hardware",
     "set_default_planner",

@@ -10,9 +10,7 @@ surface:
   compress_pod_axis)`` — builds a ``ParallelContext`` and returns its
   jitted Lloyd loop ``fit(x_sharded, c0) -> (centroids, assignments,
   inertia)``;
-- ``shard_points`` — host-array placement along the data axes;
-- ``shard_map_compat`` — re-exported for older imports (new code should
-  go through ``ParallelContext.shard_map``).
+- ``shard_points`` — host-array placement along the data axes.
 
 The centroid statistics ``(s_k, n_k)`` are *sufficient statistics* and
 associative, so the out-of-core chunk reduction (core.chunked), the
@@ -46,8 +44,7 @@ import jax
 from jax.sharding import Mesh
 
 from repro.core.kmeans import KMeansConfig
-# shard_map_compat re-exported for backward compatibility
-from repro.core.parallel import ParallelContext, shard_map_compat  # noqa: F401
+from repro.core.parallel import ParallelContext
 
 Array = jax.Array
 

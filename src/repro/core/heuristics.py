@@ -94,8 +94,9 @@ TPU_V6E = Hardware(
 # ``jax.devices()[0].device_kind`` (lowercased, spaces stripped) substring
 # -> Hardware row. Ordered: first match wins, so the more specific names
 # come first ("tpu v5 lite" must not match the bare-"v5" v5p row).
-# ``core.plan.detect_hardware`` walks this table; TPU_V5E is its explicit
-# fallback for unknown generations and non-TPU (interpret-mode) backends.
+# ``core.plan.detect_hardware`` walks this table; a TPU that matches no
+# row is an error, and only the CPU backend (interpret mode) plans
+# against TPU_V5E.
 HARDWARE_TABLE = (
     ("v6", TPU_V6E),
     ("v5p", TPU_V5P),
@@ -129,25 +130,43 @@ def _fit_minor(limit: int, size: int, align: int) -> int:
     return best
 
 
+def _mxu_split(elems: int, bytes_in: int) -> int:
+    """VMEM the MXU's full-precision f32 contraction adds for ``elems``
+    operand elements: each f32 operand is split into three bf16 parts
+    (``kernels.flash_assign.matmul_precision``); other dtypes feed the
+    MXU as they are."""
+    return 3 * elems * 2 if bytes_in == 4 else 0
+
+
+# The footprints below count what Mosaic allocates for one grid step:
+# every pipelined block twice (double buffering, including the blocks
+# whose index never changes), scratch, and the large vector temporaries.
+# Each was checked against the scoped VMEM the TPU compiler needs at the
+# planner's tiles (v5e, jax 0.9.0); tests/core/test_heuristics.py pins
+# that the choosers stay inside the budget they model.
+
 def assign_footprint(bn: int, bk: int, d: int, bytes_in: int) -> int:
-    """VMEM bytes held live by one FlashAssign grid step (double-buffered)."""
-    x_tile = bn * d * bytes_in          # resident across K sweep
-    c_tiles = 2 * bk * d * bytes_in     # double-buffered stream
-    score = bn * bk * 4                 # f32 intermediate
-    state = bn * (4 + 4)                # running (m, a)
-    out = bn * (4 + 4)
-    return x_tile + c_tiles + score + state + out
+    """VMEM bytes held live by one FlashAssign grid step."""
+    x_tiles = 2 * bn * d * bytes_in     # point tile (double-buffered)
+    c_tiles = 2 * bk * d * bytes_in     # centroid stream (double-buffered)
+    mxu = _mxu_split((bn + bk) * d, bytes_in)
+    csq = bk * d * 4                    # f32 c*c product
+    score = bn * bk * 4                 # f32 (bk, bn) score tile
+    state = bn * (4 + 4) * 8            # (1, bn) min/argmin rows
+    out = 2 * bn * (4 + 4) * 8          # (1, bn) output rows
+    return x_tiles + c_tiles + mxu + csq + score + state + out
 
 
 def update_footprint(bn: int, bk: int, d: int, bytes_in: int) -> int:
     """VMEM bytes for one sort-inverse grid step."""
     x_tiles = 2 * bn * d * bytes_in     # double-buffered point stream
-    ids = 2 * bn * 4
-    onehot = bn * bk * bytes_in
-    acc = bk * d * 4                    # resident output block (f32)
+    ids = 2 * bn * 4 * 8                # (1, bn) id rows
+    onehot = 4 * bn * bk * 4            # one-hot, its transpose, masks
+    mxu = _mxu_split(bn * (bk + d), bytes_in)
+    acc = 2 * bk * d * 4                # output block (double-buffered)
     partial = bk * d * 4
-    cnt = bk * 4 * 2
-    return x_tiles + ids + onehot + acc + partial + cnt
+    cnt = 2 * bk * 4 * 8
+    return x_tiles + ids + onehot + mxu + acc + partial + cnt
 
 
 def fused_footprint(bn: int, bk: int, d: int, bytes_in: int,
@@ -155,66 +174,71 @@ def fused_footprint(bn: int, bk: int, d: int, bytes_in: int,
     """VMEM bytes held live by one FlashLloyd grid step.
 
     The full centroid set and the f32 ``(K_pad, d)`` sums accumulator are
-    resident across the whole grid — that ``~2·K_pad·d·4`` term is the new
+    resident across the whole grid — that ``K_pad·d`` term is the
     constraint the two-pass path does not have, and the reason the fused
     path only wins at small-to-moderate ``K·d`` (see DESIGN.md).
     """
     x_tiles = 2 * bn * d * bytes_in     # double-buffered point stream
-    c_res = k_pad * d * bytes_in        # resident centroid block
-    acc = k_pad * d * 4 + k_pad * 4     # resident f32 sums + counts
-    score = bn * bk * 4                 # f32 score slice (sweep 1)
-    onehot = bn * bk * bytes_in         # one-hot slice (sweep 2)
-    state = bn * (4 + 4) + bn * 4       # (m, a) carry + assignment out
-    return x_tiles + c_res + acc + score + onehot + state
+    c_res = 2 * k_pad * d * bytes_in    # resident centroid block
+    acc = 2 * (k_pad * d * 4 + k_pad * 4)   # resident f32 sums + counts
+    sweep = 4 * bn * bk * 4             # score / ids / one-hot slices
+    mxu = _mxu_split((2 * bn + bk) * d, bytes_in)
+    state = bn * (4 + 4) * 8 + 2 * bn * 4 * 8   # argmin rows + out row
+    return x_tiles + c_res + acc + sweep + mxu + state
 
 
 def probe_footprint(bn: int, bk: int, l: int, d: int, bytes_in: int) -> int:
     """VMEM bytes held live by one FlashProbe grid step.
 
     Like FlashAssign but the running state is an L-best pool instead of a
-    scalar argmin, and each selection round materializes the merged
-    ``(B_N, L + B_K)`` candidate pool (f32 scores + i32 indices).
+    scalar argmin, and the selection rounds carry the tile's scores and
+    the pool (values + indices) through a ``fori_loop``.
     """
-    q_tile = bn * d * bytes_in          # resident across K sweep
+    q_tiles = 2 * bn * d * bytes_in     # query tile (double-buffered)
     c_tiles = 2 * bk * d * bytes_in     # double-buffered stream
+    mxu = _mxu_split((bn + bk) * d, bytes_in)
     score = bn * bk * 4                 # f32 intermediate
-    merged = bn * (l + bk) * (4 + 4)    # merged (vals, idxs) pool
+    select = 2 * bn * (l + bk) * 4      # round carry + temporaries
     state = bn * l * (4 + 4)            # running L-best scratch
-    out = bn * l * (4 + 4)
-    return q_tile + c_tiles + score + merged + state + out
+    out = 2 * bn * l * (4 + 4)
+    return q_tiles + c_tiles + mxu + score + select + state + out
 
 
 def scan_footprint(bb: int, bc: int, l: int, d: int, bytes_in: int) -> int:
     """VMEM bytes held live by one grouped-probe (posting-list scan) grid
     step: the candidate stream carries a per-query leading axis, so its
-    double-buffered tile costs ``2·B_B·B_C·d·b`` — the dominant term."""
-    q_tile = bb * d * bytes_in          # resident across C sweep
+    double-buffered tile costs ``2·B_B·B_C·d·b`` and the two f32 products
+    the VPU reduces over d (``q·c`` and ``c·c``) as much again — the
+    dominant terms."""
+    q_tiles = 2 * bb * d * bytes_in
     c_tiles = 2 * bb * bc * d * bytes_in  # double-buffered per-query stream
+    prods = 2 * bb * bc * d * 4         # f32 q·c and c·c products
     score = bb * bc * 4 * 2             # f32 score + csq intermediates
-    merged = bb * (l + bc) * (4 + 4)    # merged (vals, idxs) pool
+    select = 2 * bb * (l + bc) * 4      # selection round carry
     state = bb * l * (4 + 4)
-    out = bb * l * (4 + 4)
-    return q_tile + c_tiles + score + merged + state + out
+    out = 2 * bb * l * (4 + 4)
+    return q_tiles + c_tiles + prods + score + select + state + out
 
 
 def scan_q8_footprint(bb: int, bw: int, l: int, d: int) -> int:
     """VMEM bytes held live by one quantized grouped-scan grid step.
 
     The streamed candidate tile is int8 codes (``2·B_B·B_W·d·1``) plus a
-    per-slot f32 scale strip; the kernel dequantizes in-register, so the
-    f32 residual intermediate (``B_B·B_W·d·4``) — not the code stream —
-    is the dominant VMEM term. That is the codec trade stated plainly:
-    HBM traffic shrinks ~4x while the on-chip working set stays f32-sized.
+    per-slot f32 scale strip; the kernel widens the codes to f32 in
+    VMEM, so the f32 intermediates (``B_B·B_W·d·4`` each) — not the code
+    stream — are the dominant VMEM term. That is the codec trade stated
+    plainly: HBM traffic shrinks ~4x while the on-chip working set stays
+    f32-sized.
     """
-    q_tile = bb * d * 4                 # resident q' tile (f32)
+    q_tiles = 2 * bb * d * 4            # q' tile (f32, double-buffered)
     c_tiles = 2 * bb * bw * d * 1       # double-buffered int8 code stream
     s_tiles = 2 * bb * bw * 4           # double-buffered f32 scale strip
-    deq = bb * bw * d * 4               # f32 dequantized residual
-    score = bb * bw * 4 * 2             # f32 score + rsq intermediates
-    merged = bb * (l + bw) * (4 + 4)    # merged (vals, idxs) pool
+    deq = 3 * bb * bw * d * 4           # f32 codes + q·c and c·c products
+    score = bb * bw * 4 * 2             # f32 score + csq intermediates
+    select = 2 * bb * (l + bw) * 4      # selection round carry
     state = bb * l * (4 + 4)
-    out = bb * l * (4 + 4)
-    return q_tile + c_tiles + s_tiles + deq + score + merged + state + out
+    out = 2 * bb * l * (4 + 4)
+    return q_tiles + c_tiles + s_tiles + deq + score + select + state + out
 
 
 def choose_scan_q8_blocks(b: int, c: int, d: int, l: int, *,
@@ -490,6 +514,10 @@ def choose_blocks(n: int, k: int, d: int, *, dtype_bytes: int = 4,
         a_bk //= 2
     while assign_footprint(a_bn, a_bk, d, dtype_bytes) > budget and a_bn > hw.sublane:
         a_bn //= 2
+    # very large d: the centroid tile's rows are sublanes of the (B_K, B_N)
+    # score tile, so B_K may go below a lane width as a last resort
+    while assign_footprint(a_bn, a_bk, d, dtype_bytes) > budget and a_bk > hw.sublane:
+        a_bk //= 2
 
     # --- Sort-inverse: B_K bounds both the one-hot minor dim and the
     # resident accumulator (bk*d f32); keep it modest, grow the point

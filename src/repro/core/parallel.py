@@ -56,22 +56,6 @@ from repro.utils import sharding as shu
 Array = jax.Array
 
 
-def shard_map_compat(f, *, mesh, in_specs, out_specs):
-    """``jax.shard_map`` across jax versions.
-
-    jax >= 0.6 exports it at top level (replication checking spelled
-    ``check_vma``); 0.4.x only has ``jax.experimental.shard_map.shard_map``
-    (spelled ``check_rep``). Checking is disabled either way: pallas_call
-    outputs carry no replication/vma info.
-    """
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_rep=False)
-
-
 # ---------------------------------------------------------------------------
 # mesh construction — the one helper every launcher builds meshes through
 # ---------------------------------------------------------------------------
@@ -86,7 +70,11 @@ def build_mesh(shape: Sequence[int], axes: Sequence[str]) -> Mesh:
     shape, axes = tuple(int(s) for s in shape), tuple(axes)
     if len(shape) != len(axes):
         raise ValueError(f"mesh shape {shape} and axes {axes} disagree")
-    return jax.make_mesh(shape, axes)
+    # Auto axes: shardings propagate through XLA as the shard_map
+    # programs and the host-side glue around them expect (jax >= 0.9
+    # defaults make_mesh to Explicit axes, sharding in types)
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
@@ -216,9 +204,10 @@ class ParallelContext:
     def spmd(self, f, in_specs, out_specs):
         """Build a per-shard SPMD program over this mesh (shard_map
         under the hood — the only entry point drivers use, so the raw
-        mechanism never leaks outside this module)."""
-        return shard_map_compat(f, mesh=self.mesh, in_specs=in_specs,
-                                out_specs=out_specs)
+        mechanism never leaks outside this module). Replication checking
+        is off: pallas_call outputs carry no vma information."""
+        return jax.shard_map(f, mesh=self.mesh, in_specs=in_specs,
+                             out_specs=out_specs, check_vma=False)
 
     def put(self, x, spec: P):
         return jax.device_put(x, NamedSharding(self.mesh, spec))

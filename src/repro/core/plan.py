@@ -15,8 +15,8 @@ never exhaustively re-tuned per call. The closed-form math lives in
   batch sizes shares one plan), backed by a persistent on-disk JSON
   cache so repeated launches skip planning entirely;
 - **hardware** — ``detect_hardware()`` maps ``jax.devices()`` onto the
-  ``heuristics.HARDWARE_TABLE`` with ``TPU_V5E`` as the explicit
-  fallback (unknown TPU generations, CPU/GPU interpret mode);
+  ``heuristics.HARDWARE_TABLE``; the CPU backend (interpret mode) plans
+  against ``TPU_V5E``, and an unknown device is an error;
 - **measured refinement** — ``refine="measure"`` (or ``fold_measured``)
   folds ``core.autotune.exhaustive_tune`` results back into the cache,
   making the exhaustive tuner a planner *backend* instead of an island:
@@ -45,7 +45,7 @@ from repro.kernels.ops import BlockConfig
 # Bump whenever KernelPlan fields or chooser semantics change: a disk
 # cache written by an older version is *stale*, and is ignored (not
 # fatal) rather than deserialized into wrong plans.
-CACHE_VERSION = 1
+CACHE_VERSION = 2
 
 OPS = ("assign", "update", "step", "probe", "scan", "scan_q8", "route",
        "rescore")
@@ -81,23 +81,28 @@ def detect_hardware(devices=None) -> heuristics.Hardware:
     """Map ``jax.devices()`` onto the ``heuristics.HARDWARE_TABLE``.
 
     Matching is by substring of ``device_kind`` (lowercased, spaces
-    stripped), most specific first. Unknown TPU generations and non-TPU
-    backends (CPU/GPU — where the kernels run in interpret mode and the
-    block shapes only need to be *feasible*) fall back to ``TPU_V5E``
-    explicitly, so planning never fails for lack of a hardware row.
+    stripped), most specific first. Only the CPU backend — where the
+    kernels run in interpret mode and the block shapes only need to be
+    *feasible* — plans against ``TPU_V5E``. Everything else fails
+    loudly rather than planning for the wrong chip: a device enumeration
+    that raises propagates, and an empty device list, a TPU generation
+    missing from the table, or any other platform raises.
     """
     if devices is None:
-        try:
-            devices = jax.devices()
-        except Exception:  # backend init failure — plan for the fallback
-            return heuristics.TPU_V5E
+        devices = jax.devices()
     if not devices:
+        raise RuntimeError("detect_hardware: no devices to plan for")
+    dev = devices[0]
+    if dev.platform == "cpu":
         return heuristics.TPU_V5E
-    kind = str(getattr(devices[0], "device_kind", "")).lower().replace(" ", "")
-    for needle, hw in heuristics.HARDWARE_TABLE:
-        if needle in kind:
-            return hw
-    return heuristics.TPU_V5E
+    kind = str(dev.device_kind).lower().replace(" ", "")
+    if dev.platform == "tpu":
+        for needle, hw in heuristics.HARDWARE_TABLE:
+            if needle in kind:
+                return hw
+    raise RuntimeError(
+        f"detect_hardware: no HARDWARE_TABLE row for platform "
+        f"{dev.platform!r}, device_kind {dev.device_kind!r}")
 
 
 def hardware_by_name(name: str | None) -> heuristics.Hardware:

@@ -735,7 +735,13 @@ class IVFIndex:
                         rescore_mult=rescore_mult,
                         rescore_bytes=rescore_bytes, rescore=rescore,
                         router=router)
-            index._fold(xj, a, m)
+            if pctx is None:
+                index._fold(xj, a, m)
+            else:
+                # per-shard statistics through the add program: a Pallas
+                # kernel on the TPU cannot take mesh-sharded operands
+                # outside a shard_map
+                index._add_sharded(xj)
         else:
             # out-of-core: ChunkedKMeans trains (init from the first
             # chunk), then the same chunk stream is inverted via add().

@@ -34,7 +34,13 @@ _INF = float("inf")
 
 def _flash_assign_kernel(x_ref, c_ref, a_ref, m_ref, m_scr, a_scr, *,
                          block_k: int, k_actual: int):
-    """One (point-tile, centroid-tile) grid step."""
+    """One (point-tile, centroid-tile) grid step.
+
+    Scores are laid out transposed, ``(B_K, B_N)``: the min/argmin then
+    reduces over sublanes and lands as a lane-dense ``(1, B_N)`` row — the
+    layout of the running state and of the output blocks, so nothing is
+    relaid into 1-D vectors.
+    """
     kt = pl.program_id(1)
     nk = pl.num_programs(1)
 
@@ -46,19 +52,19 @@ def _flash_assign_kernel(x_ref, c_ref, a_ref, m_ref, m_scr, a_scr, *,
     x = x_ref[...]                                   # (bn, d)
     c = c_ref[...]                                   # (bk, d)
 
-    # MXU: cross term with f32 accumulation.
+    # MXU: cross term with f32 accumulation, transposed (bk, bn).
     cross = jax.lax.dot_general(
-        x, c, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
-    csq = jnp.sum(c.astype(jnp.float32) * c.astype(jnp.float32), axis=-1)
-    score = csq[None, :] - 2.0 * cross               # (bn, bk) f32
+        c, x, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32,
+        precision=matmul_precision(x.dtype))
+    c32 = c.astype(jnp.float32)
+    csq = jnp.sum(c32 * c32, axis=1, keepdims=True)  # (bk, 1)
+    score = csq - 2.0 * cross                        # (bk, bn) f32
 
     # Mask padded centroids (tail tile only).
-    k_ids = kt * block_k + jax.lax.broadcasted_iota(jnp.int32, score.shape, 1)
+    k_ids = kt * block_k + jax.lax.broadcasted_iota(jnp.int32, score.shape, 0)
     score = jnp.where(k_ids < k_actual, score, _INF)
 
-    local_m = jnp.min(score, axis=1)                 # (bn,)
-    local_a = (kt * block_k
-               + jnp.argmin(score, axis=1).astype(jnp.int32))  # (bn,)
+    local_m, local_a = argmin_rows(score, kt * block_k)   # (1, bn) each
 
     # Online argmin: strict '<' keeps the earliest index on exact ties,
     # matching jnp.argmin's first-occurrence semantics.
@@ -74,21 +80,51 @@ def _flash_assign_kernel(x_ref, c_ref, a_ref, m_ref, m_scr, a_scr, *,
         m_ref[...] = m_scr[...]
 
 
+def matmul_precision(dtype):
+    """Full-f32 MXU contraction for f32 operands (the exactness contract:
+    scores must not silently drop to one bf16 pass); the operand's own
+    precision otherwise."""
+    return (jax.lax.Precision.HIGHEST if jnp.dtype(dtype) == jnp.float32
+            else None)
+
+
+def argmin_rows(score: Array, base) -> tuple[Array, Array]:
+    """Column-wise ``(min, argmin)`` of a transposed ``(B_K, B_N)`` score
+    tile as lane-dense ``(1, B_N)`` rows; ``base`` is the tile's first
+    global index. One index-carrying reduction (``jnp.argmin``, first
+    occurrence on exact ties) — a min followed by an ``== min`` mask can
+    miss when the compiler evaluates the score twice with different
+    rounding."""
+    m = jnp.min(score, axis=0, keepdims=True)
+    a = base + jnp.argmin(score, axis=0, keepdims=True).astype(jnp.int32)
+    return m, a
+
+
+def row_to_col(row: Array) -> Array:
+    """A lane-dense ``(1, n)`` row as an ``(n, 1)`` column: one 2-D
+    transpose of the row broadcast to a full sublane tile."""
+    return jnp.transpose(jnp.broadcast_to(row, (8, row.shape[1])))[:, 0:1]
+
+
 def flash_assign_raw(x: Array, c: Array, *, block_n: int, block_k: int,
                      k_actual: int, interpret: bool = False
                      ) -> tuple[Array, Array]:
     """Pallas call on pre-padded inputs.
 
     x: (N_pad, d), c: (K_pad, d) with N_pad % block_n == K_pad % block_k == 0.
-    Returns (assignments int32 (N_pad,), scores f32 (N_pad,)) where score is
-    ``||c_a||^2 - 2 x.c_a`` (add ``||x||^2`` for the true squared distance).
+    Returns (assignments int32 (N_tiles, 1, block_n), scores f32
+    (N_tiles, 1, block_n)) — one lane-dense row per point tile (reshape to
+    ``(N_pad,)``) — where score is ``||c_a||^2 - 2 x.c_a`` (add
+    ``||x||^2`` for the true squared distance).
     """
     n_pad, d = x.shape
     k_pad = c.shape[0]
-    grid = (n_pad // block_n, k_pad // block_k)
+    n_tiles = n_pad // block_n
+    grid = (n_tiles, k_pad // block_k)
 
     kernel = functools.partial(
         _flash_assign_kernel, block_k=block_k, k_actual=k_actual)
+    row = pl.BlockSpec((None, 1, block_n), lambda i, k: (i, 0, 0))
 
     return pl.pallas_call(
         kernel,
@@ -97,17 +133,14 @@ def flash_assign_raw(x: Array, c: Array, *, block_n: int, block_k: int,
             pl.BlockSpec((block_n, d), lambda i, k: (i, 0)),
             pl.BlockSpec((block_k, d), lambda i, k: (k, 0)),
         ],
-        out_specs=[
-            pl.BlockSpec((block_n,), lambda i, k: (i,)),
-            pl.BlockSpec((block_n,), lambda i, k: (i,)),
-        ],
+        out_specs=[row, row],
         out_shape=[
-            jax.ShapeDtypeStruct((n_pad,), jnp.int32),
-            jax.ShapeDtypeStruct((n_pad,), jnp.float32),
+            jax.ShapeDtypeStruct((n_tiles, 1, block_n), jnp.int32),
+            jax.ShapeDtypeStruct((n_tiles, 1, block_n), jnp.float32),
         ],
         scratch_shapes=[
-            pltpu.VMEM((block_n,), jnp.float32),
-            pltpu.VMEM((block_n,), jnp.int32),
+            pltpu.VMEM((1, block_n), jnp.float32),
+            pltpu.VMEM((1, block_n), jnp.int32),
         ],
         interpret=interpret,
     )(x, c)

@@ -18,8 +18,9 @@ Structure: grid ``(N_tiles,)`` with an inner ``fori_loop`` K-sweep.
   term is dropped on-chip, re-added for the inertia only);
 - sweep 2 revisits the same centroid slices, builds the tile-local one-hot
   ``(B_N, B_K)`` in registers, and accumulates one MXU matmul
-  ``onehot^T @ x_tile`` plus counts into the ``(K_pad, d)`` / ``(K_pad,)``
-  f32 output blocks, which stay resident in VMEM for the whole grid
+  ``onehot^T @ x_tile`` plus counts into the ``(K_pad, d)`` sums and
+  ``(K_pad/B_K, B_K)`` count rows, f32 output blocks that stay resident
+  in VMEM for the whole grid
   (constant index map — initialized at tile 0, flushed once at the end).
 
 The price is that the full centroid set and the f32 accumulators must be
@@ -41,6 +42,9 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.flash_assign import (argmin_rows, matmul_precision,
+                                        row_to_col)
+
 Array = jax.Array
 
 _INF = float("inf")
@@ -49,7 +53,14 @@ _INF = float("inf")
 def _flash_lloyd_kernel(x_ref, c_ref, a_ref, s_ref, cnt_ref, j_ref, *,
                         block_n: int, block_k: int, k_actual: int,
                         n_actual: int):
-    """One point-tile grid step: argmin K-sweep, then accumulate K-sweep."""
+    """One point-tile grid step: argmin K-sweep, then accumulate K-sweep.
+
+    Sweep 1 scores transposed ``(B_K, B_N)`` slices (FlashAssign's
+    layout), so the argmin state and the assignment row are lane-dense
+    ``(1, B_N)`` rows; sweep 2 turns the row into a column once and
+    builds the ``(B_N, B_K)`` one-hot, whose column sums are the
+    lane-dense ``(1, B_K)`` count rows.
+    """
     i = pl.program_id(0)
     nk = c_ref.shape[0] // block_k
 
@@ -57,10 +68,11 @@ def _flash_lloyd_kernel(x_ref, c_ref, a_ref, s_ref, cnt_ref, j_ref, *,
     def _init():
         s_ref[...] = jnp.zeros_like(s_ref[...])
         cnt_ref[...] = jnp.zeros_like(cnt_ref[...])
-        j_ref[...] = jnp.zeros_like(j_ref[...])
+        j_ref[0, 0] = jnp.float32(0.0)
 
     x = x_ref[...]                                    # (bn, d), resident
-    # rank-2 iota: Mosaic rejects 1-D iota (same idiom as flash_assign)
+    prec = matmul_precision(x.dtype)
+    # rank-2 iota: Mosaic rejects 1-D iota
     row_ids = i * block_n + jax.lax.broadcasted_iota(
         jnp.int32, (block_n, 1), 0)
     row_valid = row_ids < n_actual                    # (bn, 1)
@@ -68,48 +80,49 @@ def _flash_lloyd_kernel(x_ref, c_ref, a_ref, s_ref, cnt_ref, j_ref, *,
     # ---- sweep 1: online argmin over centroid slices (FlashAssign math).
     def _argmin_body(kt, carry):
         m, a = carry
-        c = c_ref[pl.ds(kt * block_k, block_k), :]   # (bk, d)
-        cross = jax.lax.dot_general(
-            x, c, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        csq = jnp.sum(c.astype(jnp.float32) * c.astype(jnp.float32), axis=-1)
-        score = csq[None, :] - 2.0 * cross            # (bn, bk) f32
+        start = pl.multiple_of(kt * block_k, block_k)
+        c = c_ref[pl.ds(start, block_k), :]           # (bk, d)
+        cross = jax.lax.dot_general(                  # (bk, bn)
+            c, x, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32, precision=prec)
+        c32 = c.astype(jnp.float32)
+        csq = jnp.sum(c32 * c32, axis=1, keepdims=True)
+        score = csq - 2.0 * cross                     # (bk, bn) f32
         k_ids = kt * block_k + jax.lax.broadcasted_iota(
-            jnp.int32, score.shape, 1)
+            jnp.int32, score.shape, 0)
         score = jnp.where(k_ids < k_actual, score, _INF)
-        local_m = jnp.min(score, axis=1)
-        local_a = (kt * block_k
-                   + jnp.argmin(score, axis=1).astype(jnp.int32))
+        local_m, local_a = argmin_rows(score, kt * block_k)
         # strict '<' keeps the earliest index on exact ties (argmin parity)
         better = local_m < m
         return jnp.where(better, local_m, m), jnp.where(better, local_a, a)
 
     m, a = jax.lax.fori_loop(
         0, nk, _argmin_body,
-        (jnp.full((block_n,), _INF, jnp.float32),
-         jnp.zeros((block_n,), jnp.int32)))
+        (jnp.full((1, block_n), _INF, jnp.float32),
+         jnp.zeros((1, block_n), jnp.int32)))
     a_ref[...] = a
 
     # Inertia: re-add the dropped ||x||^2, clamp fp residue, mask padding.
+    a_col, m_col = row_to_col(a), row_to_col(m)       # (bn, 1) each
     x32 = x.astype(jnp.float32)
-    xsq = jnp.sum(x32 * x32, axis=-1)
-    dist = jnp.maximum(m + xsq, 0.0)[:, None]         # (bn, 1)
+    xsq = jnp.sum(x32 * x32, axis=-1, keepdims=True)
+    dist = jnp.maximum(m_col + xsq, 0.0)
     j_ref[0, 0] += jnp.sum(jnp.where(row_valid, dist, 0.0))
 
     # ---- sweep 2: one-hot statistics into the resident accumulators.
     def _accum_body(kt, _):
-        rel = a - kt * block_k                        # (bn,)
-        cols = jax.lax.broadcasted_iota(
+        start = pl.multiple_of(kt * block_k, block_k)
+        cols = kt * block_k + jax.lax.broadcasted_iota(
             jnp.int32, (block_n, block_k), 1)
-        onehot = jnp.logical_and(rel[:, None] == cols, row_valid)
+        onehot = jnp.logical_and(a_col == cols, row_valid)
         oh = onehot.astype(x.dtype)
         # MXU: (bk, bn) @ (bn, d) f32-accumulated == slice-local sums.
         partial = jax.lax.dot_general(
             oh, x, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        sl = pl.ds(kt * block_k, block_k)
-        s_ref[sl, :] += partial
-        cnt_ref[sl] += jnp.sum(onehot.astype(jnp.float32), axis=0)
+            preferred_element_type=jnp.float32, precision=prec)
+        s_ref[pl.ds(start, block_k), :] += partial
+        cnt_ref[pl.ds(kt, 1), :] += jnp.sum(onehot.astype(jnp.float32),
+                                            axis=0, keepdims=True)
         return 0
 
     jax.lax.fori_loop(0, nk, _accum_body, 0)
@@ -121,13 +134,16 @@ def flash_lloyd_raw(x: Array, c: Array, *, block_n: int, block_k: int,
     """Pallas call on pre-padded inputs.
 
     x: (N_pad, d), c: (K_pad, d) with N_pad % block_n == K_pad % block_k == 0.
-    Returns ``(assignments int32 (N_pad,), sums f32 (K_pad, d),
-    counts f32 (K_pad,), inertia f32 (1, 1))``; padded rows/centroids
-    contribute nothing to the statistics.
+    Returns ``(assignments int32 (N_tiles, 1, block_n), sums f32
+    (K_pad, d), counts f32 (K_pad / block_k, block_k), inertia f32
+    (1, 1))`` — assignments and counts in lane-dense rows (reshape to
+    ``(N_pad,)`` / ``(K_pad,)``); padded rows/centroids contribute
+    nothing to the statistics.
     """
     n_pad, d = x.shape
     k_pad = c.shape[0]
-    grid = (n_pad // block_n,)
+    n_tiles = n_pad // block_n
+    nk = k_pad // block_k
 
     kernel = functools.partial(
         _flash_lloyd_kernel, block_n=block_n, block_k=block_k,
@@ -135,23 +151,23 @@ def flash_lloyd_raw(x: Array, c: Array, *, block_n: int, block_k: int,
 
     return pl.pallas_call(
         kernel,
-        grid=grid,
+        grid=(n_tiles,),
         in_specs=[
             pl.BlockSpec((block_n, d), lambda i: (i, 0)),
             pl.BlockSpec((k_pad, d), lambda i: (0, 0)),   # resident
         ],
         out_specs=[
-            pl.BlockSpec((block_n,), lambda i: (i,)),
+            pl.BlockSpec((None, 1, block_n), lambda i: (i, 0, 0)),
             pl.BlockSpec((k_pad, d), lambda i: (0, 0)),   # resident acc
-            pl.BlockSpec((k_pad,), lambda i: (0,)),
+            pl.BlockSpec((nk, block_k), lambda i: (0, 0)),
             # scalar inertia accumulator lives in SMEM (Mosaic idiom)
             pl.BlockSpec((1, 1), lambda i: (0, 0),
                          memory_space=pltpu.SMEM),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((n_pad,), jnp.int32),
+            jax.ShapeDtypeStruct((n_tiles, 1, block_n), jnp.int32),
             jax.ShapeDtypeStruct((k_pad, d), jnp.float32),
-            jax.ShapeDtypeStruct((k_pad,), jnp.float32),
+            jax.ShapeDtypeStruct((nk, block_k), jnp.float32),
             jax.ShapeDtypeStruct((1, 1), jnp.float32),
         ],
         interpret=interpret,

@@ -16,17 +16,16 @@ scratch and persists across the K sweep for a fixed query tile. The
 ``N x K`` score matrix never exists in HBM — per-sweep IO is
 ``O(Q d + K d)`` reads + ``O(Q L)`` writes.
 
-Per grid step the tile's ``(B_Q, B_K)`` crossterm scores are concatenated
-with the running L-best pool and reduced by L rounds of (min, argmin,
-mask) — a static selection network, unrolled at trace time (L is small:
-nprobe or topk). Tie-breaking matches ``jax.lax.top_k``: for equal
-scores the lower centroid index wins, because
+Per grid step the tile's ``(B_Q, B_K)`` crossterm scores and the running
+L-best pool are reduced together by L rounds of (min, lowest-index
+argmin, mask) in a ``fori_loop`` — one round's temporaries live at a
+time, which keeps the kernel inside the scoped-VMEM limit at the
+planner's tiles. Tie-breaking matches ``jax.lax.top_k``: for equal
+scores the lower centroid index wins, because each round picks the
+lowest global index among the entries equal to the row minimum, and
 
-- within a tile, ``jnp.argmin`` picks the first occurrence (lowest index);
-- K tiles are swept in ascending index order and the running pool is
-  stored *before* the new tile's scores in the merged candidate row, so
-  an earlier (lower-index) winner is re-selected ahead of an equal
-  newcomer;
+- K tiles are swept in ascending index order, so every pool entry has a
+  lower index than every entry of the new tile;
 - the running pool itself is kept sorted by (score, index) — the
   invariant each selection round preserves.
 
@@ -44,30 +43,46 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.flash_assign import matmul_precision
+
 Array = jax.Array
 
 _INF = float("inf")
 
 
-def _select_l_best(mv: Array, mi: Array, l: int) -> tuple[Array, Array]:
-    """L rounds of (min, argmin, mask) over the merged candidate pool.
+def _select_l_best(pool_v: Array, pool_i: Array, tile_v: Array,
+                   tile_i: Array, l: int) -> tuple[Array, Array]:
+    """L rounds of (min, lowest-index argmin, mask) over the running pool
+    ``(B_Q, L)`` and the new tile ``(B_Q, B)``, kept as two arrays (no
+    unaligned lane concatenation).
 
-    mv/mi: (bq, P) merged scores / global indices. Returns the L smallest
-    scores per row in ascending (score, index) order. ``take_along_axis``
-    is avoided (Mosaic-unfriendly gather); the selected index is extracted
-    with a one-hot reduction instead.
+    Returns the L smallest scores per row in ascending (score, index)
+    order. Each round takes the row minimum and, among entries equal to
+    it, the lowest global index — ``jax.lax.top_k``'s tie rule: the pool
+    holds earlier (lower-index) tiles' winners, and within a tile
+    indices ascend with position. The rounds run in a ``fori_loop`` so
+    only one round's temporaries are ever live.
     """
-    cols = jax.lax.broadcasted_iota(jnp.int32, mv.shape, 1)
-    vals, idxs = [], []
-    for _ in range(l):
-        m = jnp.min(mv, axis=1)
-        am = jnp.argmin(mv, axis=1).astype(jnp.int32)
-        sel = cols == am[:, None]
-        idx = jnp.sum(jnp.where(sel, mi, 0), axis=1)
-        vals.append(m)
-        idxs.append(idx)
-        mv = jnp.where(sel, _INF, mv)
-    return jnp.stack(vals, axis=1), jnp.stack(idxs, axis=1)
+    big = jnp.iinfo(jnp.int32).max
+    out_cols = jax.lax.broadcasted_iota(jnp.int32, pool_v.shape, 1)
+
+    def rnd(j, carry):
+        pv, tv, ov, oi = carry
+        m = jnp.minimum(jnp.min(pv, axis=1, keepdims=True),
+                        jnp.min(tv, axis=1, keepdims=True))
+        idx = jnp.minimum(
+            jnp.min(jnp.where(pv == m, pool_i, big), axis=1, keepdims=True),
+            jnp.min(jnp.where(tv == m, tile_i, big), axis=1, keepdims=True))
+        ov = jnp.where(out_cols == j, m, ov)
+        oi = jnp.where(out_cols == j, idx, oi)
+        pv = jnp.where(pool_i == idx, _INF, pv)
+        tv = jnp.where(tile_i == idx, _INF, tv)
+        return pv, tv, ov, oi
+
+    _, _, ov, oi = jax.lax.fori_loop(
+        0, l, rnd, (pool_v, tile_v, jnp.full_like(pool_v, _INF),
+                    jnp.zeros_like(pool_i)))
+    return ov, oi
 
 
 def _flash_probe_kernel(q_ref, c_ref, csq_ref, i_ref, v_ref, v_scr, i_scr, *,
@@ -92,18 +107,17 @@ def _flash_probe_kernel(q_ref, c_ref, csq_ref, i_ref, v_ref, v_scr, i_scr, *,
 
     # MXU: cross term with f32 accumulation (FlashAssign math).
     cross = jax.lax.dot_general(
-        q, c, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
+        q, c, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32,
+        precision=matmul_precision(q.dtype))
     score = csq_ref[...] - 2.0 * cross               # (bq, bk) f32
 
     # Mask padded centroids (tail tile only).
     k_ids = kt * block_k + jax.lax.broadcasted_iota(jnp.int32, score.shape, 1)
     score = jnp.where(k_ids < k_actual, score, _INF)
 
-    # Merge: running L-best first (earlier tiles = lower indices), then
-    # this tile's candidates — first-occurrence argmin gives top_k ties.
-    mv = jnp.concatenate([v_scr[...], score], axis=1)   # (bq, l + bk)
-    mi = jnp.concatenate([i_scr[...], k_ids], axis=1)
-    new_v, new_i = _select_l_best(mv, mi, l)
+    # Merge the running L-best (earlier tiles = lower indices) with this
+    # tile's candidates — lowest-index tie-breaking gives top_k ties.
+    new_v, new_i = _select_l_best(v_scr[...], i_scr[...], score, k_ids, l)
     v_scr[...] = new_v
     i_scr[...] = new_i
 
@@ -142,9 +156,7 @@ def _flash_probe_grouped_kernel(q_ref, c_ref, i_ref, v_ref, v_scr, i_scr, *,
     c_ids = ct * block_c + jax.lax.broadcasted_iota(jnp.int32, score.shape, 1)
     score = jnp.where(c_ids < c_actual, score, _INF)
 
-    mv = jnp.concatenate([v_scr[...], score], axis=1)
-    mi = jnp.concatenate([i_scr[...], c_ids], axis=1)
-    new_v, new_i = _select_l_best(mv, mi, l)
+    new_v, new_i = _select_l_best(v_scr[...], i_scr[...], score, c_ids, l)
     v_scr[...] = new_v
     i_scr[...] = new_i
 
@@ -186,23 +198,22 @@ def _flash_probe_grouped_q8_kernel(q_ref, c_ref, s_ref, i_ref, v_ref,
         v_scr[...] = jnp.full_like(v_scr[...], _INF)
         i_scr[...] = jnp.zeros_like(i_scr[...])
 
-    qp = q_ref[...].reshape(q_ref.shape[0], -1).astype(jnp.float32)
-    c = c_ref[...].reshape(c_ref.shape[0], block_w, -1)   # (bq, bw, d)
-    s = s_ref[...].reshape(s_ref.shape[0], block_w)       # (bq, bw) f32
+    qp = q_ref[...].astype(jnp.float32)                  # (bq, d)
+    c = c_ref[...].astype(jnp.float32)                   # (bq, bw, d)
+    s = s_ref[...]                                       # (bq, bw) f32
 
-    r = c.astype(jnp.float32) * s[..., None]              # dequant in VMEM
-    cross = jnp.sum(qp[:, None, :] * r, axis=-1)          # (bq, bw)
-    rsq = jnp.sum(r * r, axis=-1)
-    qsq = jnp.sum(qp * qp, axis=-1)
-    score = qsq[:, None] - 2.0 * cross + rsq
+    # ||q' - s c||^2 with the scale factored out of the d-reductions, so
+    # the (bq, bw) scale row never has to be broadcast along d
+    cross = jnp.sum(qp[:, None, :] * c, axis=-1)          # (bq, bw)
+    csq = jnp.sum(c * c, axis=-1)
+    qsq = jnp.sum(qp * qp, axis=-1, keepdims=True)
+    score = qsq - 2.0 * s * cross + (s * s) * csq
 
     c_ids = (pt * w_total + wt * block_w
              + jax.lax.broadcasted_iota(jnp.int32, score.shape, 1))
     score = jnp.where(s > 0.0, score, _INF)
 
-    mv = jnp.concatenate([v_scr[...], score], axis=1)
-    mi = jnp.concatenate([i_scr[...], c_ids], axis=1)
-    new_v, new_i = _select_l_best(mv, mi, l)
+    new_v, new_i = _select_l_best(v_scr[...], i_scr[...], score, c_ids, l)
     v_scr[...] = new_v
     i_scr[...] = new_i
 
@@ -218,14 +229,16 @@ def flash_probe_grouped_q8_raw(qp: Array, codes: Array, scales: Array, *,
                                ) -> tuple[Array, Array]:
     """Pallas call on pre-padded inputs (the quantized scan).
 
-    qp: (B_pad, nprobe, d) f32 per-probe shifted queries, codes:
-    (B_pad, nprobe, W_pad, d) int8, scales: (B_pad, nprobe, W_pad) f32
+    qp: (nprobe, B_pad, d) f32 per-probe shifted queries, codes:
+    (B_pad, nprobe, W_pad, d) int8, scales: (nprobe, B_pad, W_pad) f32
     with B_pad % block_b == W_pad % block_w == 0; padding slots must
-    carry scale 0.0. Returns ``(indices int32 (B_pad, l), dists f32
-    (B_pad, l))`` — indices into the flattened (nprobe·W_pad) candidate
-    axis, dists the true quantized squared distances.
+    carry scale 0.0. The probe axis leads the query-side arrays so every
+    block's two minor dims are whole ``(B, d)`` / ``(B, W)`` tiles.
+    Returns ``(indices int32 (B_pad, l), dists f32 (B_pad, l))`` —
+    indices into the flattened (nprobe·W_pad) candidate axis, dists the
+    true quantized squared distances.
     """
-    b_pad, nprobe, d = qp.shape
+    nprobe, b_pad, d = qp.shape
     w_pad = codes.shape[2]
     grid = (b_pad // block_b, nprobe, w_pad // block_w)
 
@@ -237,10 +250,11 @@ def flash_probe_grouped_q8_raw(qp: Array, codes: Array, scales: Array, *,
         kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((block_b, 1, d), lambda i, p, w: (i, p, 0)),
-            pl.BlockSpec((block_b, 1, block_w, d),
+            pl.BlockSpec((None, block_b, d), lambda i, p, w: (p, i, 0)),
+            pl.BlockSpec((block_b, None, block_w, d),
                          lambda i, p, w: (i, p, w, 0)),
-            pl.BlockSpec((block_b, 1, block_w), lambda i, p, w: (i, p, w)),
+            pl.BlockSpec((None, block_b, block_w),
+                         lambda i, p, w: (p, i, w)),
         ],
         out_specs=[
             pl.BlockSpec((block_b, l), lambda i, p, w: (i, 0)),
@@ -261,16 +275,17 @@ def flash_probe_grouped_q8_raw(qp: Array, codes: Array, scales: Array, *,
 def flash_probe_grouped_raw(q: Array, c: Array, *, l: int, block_b: int,
                             block_c: int, c_actual: int,
                             interpret: bool = False) -> tuple[Array, Array]:
-    """Pallas call on pre-padded inputs (the posting-list scan).
+    """Pallas call on batch-padded inputs (the posting-list scan).
 
-    q: (B_pad, d), c: (B_pad, C_pad, d) with B_pad % block_b == C_pad %
-    block_c == 0 and ``l <= c_actual``. Returns ``(indices int32
-    (B_pad, l), scores f32 (B_pad, l))`` — indices are positions into
-    each query's own candidate axis.
+    q: (B_pad, d), c: (B_pad, C, d) with B_pad % block_b == 0 and
+    ``l <= c_actual``. C need not divide into ``block_c`` tiles: the last
+    tile reads past the end and the kernel masks candidates
+    ``>= c_actual``. Returns ``(indices int32 (B_pad, l), scores f32
+    (B_pad, l))`` — indices are positions into each query's own
+    candidate axis.
     """
     b_pad, d = q.shape
-    c_pad = c.shape[1]
-    grid = (b_pad // block_b, c_pad // block_c)
+    grid = (b_pad // block_b, pl.cdiv(c.shape[1], block_c))
 
     kernel = functools.partial(
         _flash_probe_grouped_kernel, block_c=block_c, c_actual=c_actual, l=l)

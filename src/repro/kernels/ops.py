@@ -1,8 +1,9 @@
 """jit'd public wrappers around the flash-kmeans Pallas kernels.
 
 Handles shape padding to tile multiples, platform dispatch (interpret mode
-on CPU, compiled Pallas on TPU), batching, and the host-side prologue of
-the sort-inverse update (argsort + row gather + tile-pair compaction).
+on the CPU backend, compiled Pallas on TPU, an error elsewhere), batching,
+and the host-side prologue of the sort-inverse update (argsort + row
+gather + tile-pair compaction).
 
 Block resolution: every wrapper accepts an optional ``plan=``
 (``core.plan.KernelPlan``) and/or explicit ``block_*`` overrides. When
@@ -51,8 +52,16 @@ class BlockConfig:
 
 
 def default_interpret() -> bool:
-    """Pallas interpret mode everywhere except a real TPU backend."""
-    return jax.default_backend() != "tpu"
+    """Compiled Mosaic kernels on a TPU, the Pallas interpreter on the CPU
+    backend (tests, CPU debugging). Any other backend is an error: no
+    kernel silently falls back to the interpreter."""
+    backend = jax.default_backend()
+    if backend == "tpu":
+        return False
+    if backend == "cpu":
+        return True
+    raise RuntimeError(f"the Pallas kernels run on a TPU, or interpreted "
+                       f"on the CPU backend; got backend {backend!r}")
 
 
 def _plan_leg(plan, leg: str) -> tuple[int, int]:
@@ -191,7 +200,7 @@ def flash_assign(x: Array, c: Array, *, block_n: int | None = None,
     cp = _pad_to(c, block_k, 0, 0)
     a, m = _fa.flash_assign_raw(xp, cp, block_n=block_n, block_k=block_k,
                                 k_actual=k, interpret=interpret)
-    a, m = a[:n], m[:n]
+    a, m = a.reshape(-1)[:n], m.reshape(-1)[:n]
     if want_dists:
         x32 = x.astype(jnp.float32)
         m = m + jnp.sum(x32 * x32, axis=-1)
@@ -254,6 +263,7 @@ def sort_inverse_update(x: Array, a: Array, *, k: int,
         x_sorted, a_sorted, pair_n, pair_k,
         block_n=block_n, block_k=block_k, k_tiles=k_tiles,
         interpret=interpret)
+    cnt_pad = cnt_pad.reshape(-1)
     # k-tiles with no intersecting point tile are never visited by the
     # kernel grid — their output blocks are uninitialized. Zero them.
     visited = jnp.zeros((k_tiles + 1,), jnp.bool_).at[pair_k].set(True)
@@ -299,7 +309,7 @@ def flash_lloyd_step(x: Array, c: Array, *, block_n: int | None = None,
     a, s, cnt, j = _fl.flash_lloyd_raw(
         xp, cp, block_n=block_n, block_k=block_k, k_actual=k, n_actual=n,
         interpret=interpret)
-    return a[:n], s[:k], cnt[:k], j[0, 0]
+    return a.reshape(-1)[:n], s[:k], cnt.reshape(-1)[:k], j[0, 0]
 
 
 # ---------------------------------------------------------------------------
@@ -398,7 +408,9 @@ def flash_probe_grouped(q: Array, c: Array, *, l: int,
                                      q.dtype.itemsize, l=l,
                                      hw_name=plan.hw if plan else None)
     qp = _pad_to(q, block_b, 0, 0)
-    cp = _pad_to(_pad_to(c, block_b, 0, 0), block_c, 1, 0)
+    # the candidate axis is not padded: a copy of the (B, C, d) block can
+    # be as large as the block itself; the kernel masks the ragged tail
+    cp = _pad_to(c, block_b, 0, 0)
     idx, v = _fp.flash_probe_grouped_raw(
         qp, cp, l=l_pad, block_b=block_b, block_c=block_c, c_actual=c_n,
         interpret=interpret)
@@ -447,9 +459,12 @@ def flash_probe_grouped_q8(qp: Array, codes: Array, scales: Array, *,
     block_b, block_w = _audit_blocks("scan_q8", block_b, block_w, d,
                                      codes.dtype.itemsize, l=l,
                                      hw_name=plan.hw if plan else None)
-    qpp = _pad_to(qp, block_b, 0, 0)
+    # probe axis leading on the query side (small arrays; the code
+    # stream keeps its gathered layout)
+    qpp = jnp.swapaxes(_pad_to(qp, block_b, 0, 0), 0, 1)
     cp = _pad_to(_pad_to(codes, block_b, 0, 0), block_w, 2, 0)
-    sp = _pad_to(_pad_to(scales, block_b, 0, 0), block_w, 2, 0.0)
+    sp = jnp.swapaxes(
+        _pad_to(_pad_to(scales, block_b, 0, 0), block_w, 2, 0.0), 0, 1)
     w_pad = cp.shape[2]
     idx, v = _fp.flash_probe_grouped_q8_raw(
         qpp, cp, sp, l=l_pad, block_b=block_b, block_w=block_w,

@@ -32,6 +32,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.flash_assign import matmul_precision, row_to_col
+
 Array = jax.Array
 
 
@@ -76,19 +78,22 @@ def _sort_inverse_kernel(pair_n_ref, pair_k_ref, a_ref, x_ref,
     prev_k = pair_k_ref[jnp.maximum(g - 1, 0)]
     first = jnp.logical_or(g == 0, prev_k != k_idx)
 
-    ids = a_ref[...]                                  # (bn,) int32, sorted
+    ids = row_to_col(a_ref[...])                      # (bn, 1) int32, sorted
     x = x_ref[...]                                    # (bn, d)
 
     # Tile-local one-hot relative to this k-tile's base id. Out-of-range
     # ids (rows belonging to neighbouring k-tiles) produce all-zero rows.
-    rel = ids - k_idx * block_k                       # (bn,)
+    rel = ids - k_idx * block_k                       # (bn, 1)
     cols = jax.lax.broadcasted_iota(jnp.int32, (ids.shape[0], block_k), 1)
-    onehot = (rel[:, None] == cols).astype(x.dtype)   # (bn, bk)
+    onehot = (rel == cols).astype(x.dtype)            # (bn, bk)
 
     # MXU: (bk, bn) @ (bn, d) with f32 accumulation == segment-local sums.
     partial = jax.lax.dot_general(
-        onehot, x, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32)
-    pcnt = jnp.sum(onehot.astype(jnp.float32), axis=0)  # (bk,)
+        onehot, x, (((0,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32,
+        precision=matmul_precision(x.dtype))
+    # (1, bk) lane-dense row
+    pcnt = jnp.sum(onehot.astype(jnp.float32), axis=0, keepdims=True)
 
     @pl.when(first)
     def _store():
@@ -107,11 +112,15 @@ def sort_inverse_update_raw(x_sorted: Array, a_sorted: Array,
                             interpret: bool = False) -> tuple[Array, Array]:
     """Pallas call on pre-sorted, pre-padded inputs.
 
-    Returns ``(sums f32 ((k_tiles+1)*block_k, d), counts f32 ((k_tiles+1)*block_k,))``
-    — the trailing dummy block collects padding and is sliced off by ops.
+    Returns ``(sums f32 ((k_tiles+1)*block_k, d), counts f32
+    (1, (k_tiles+1)*block_k))`` — the trailing dummy block collects
+    padding and is sliced off by ops.
     """
     n_pad, d = x_sorted.shape
     g_max = pair_n.shape[0]
+    k_rows = (k_tiles + 1) * block_k
+    # assignments as lane-dense rows, one per point tile
+    a_rows = a_sorted.reshape(n_pad // block_n, 1, block_n)
 
     kernel = functools.partial(_sort_inverse_kernel, block_k=block_k)
 
@@ -119,12 +128,13 @@ def sort_inverse_update_raw(x_sorted: Array, a_sorted: Array,
         num_scalar_prefetch=2,
         grid=(g_max,),
         in_specs=[
-            pl.BlockSpec((block_n,), lambda g, pn, pk: (pn[g],)),
+            pl.BlockSpec((None, 1, block_n),
+                         lambda g, pn, pk: (pn[g], 0, 0)),
             pl.BlockSpec((block_n, d), lambda g, pn, pk: (pn[g], 0)),
         ],
         out_specs=[
             pl.BlockSpec((block_k, d), lambda g, pn, pk: (pk[g], 0)),
-            pl.BlockSpec((block_k,), lambda g, pn, pk: (pk[g],)),
+            pl.BlockSpec((1, block_k), lambda g, pn, pk: (0, pk[g])),
         ],
     )
 
@@ -132,8 +142,8 @@ def sort_inverse_update_raw(x_sorted: Array, a_sorted: Array,
         kernel,
         grid_spec=grid_spec,
         out_shape=[
-            jax.ShapeDtypeStruct(((k_tiles + 1) * block_k, d), jnp.float32),
-            jax.ShapeDtypeStruct(((k_tiles + 1) * block_k,), jnp.float32),
+            jax.ShapeDtypeStruct((k_rows, d), jnp.float32),
+            jax.ShapeDtypeStruct((1, k_rows), jnp.float32),
         ],
         interpret=interpret,
-    )(pair_n, pair_k, a_sorted, x_sorted)
+    )(pair_n, pair_k, a_rows, x_sorted)

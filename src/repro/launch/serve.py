@@ -26,6 +26,7 @@ from repro.configs.base import get_config
 from repro.core.parallel import ParallelContext, parse_mesh_flag
 from repro.models import model as M
 from repro.serve.engine import Engine, SearchConfig, SearchEngine, ServeConfig
+from repro.utils.compile_cache import configure_compile_cache
 
 
 def _serve_lm(args) -> None:
@@ -229,6 +230,7 @@ def main() -> None:
                          "(deterministic chaos; implies interesting "
                          "counters)")
     args = ap.parse_args()
+    configure_compile_cache()
 
     if args.mode == "search":
         _serve_search(args)

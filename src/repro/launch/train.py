@@ -27,6 +27,7 @@ from repro.models import model as M
 from repro.optim import adamw
 from repro.train.train_step import make_train_step
 from repro.train.trainer import Trainer, TrainerConfig
+from repro.utils.compile_cache import configure_compile_cache
 
 
 def main() -> None:
@@ -49,6 +50,7 @@ def main() -> None:
     ap.add_argument("--lr", type=float, default=3e-4)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    configure_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:

@@ -83,3 +83,49 @@ def test_heuristic_close_to_exhaustive_interpret():
     if key in rep.table:
         assert rep.table[key] <= rep.best_assign_us * 3.0 + 1e4
     assert rep.num_compiles >= 8  # exhaustive really sweeps
+
+
+# the paper's fit regimes, the smoke run's search geometry and small/odd
+# shapes: (op, planning shape) pairs the KernelPlanner sizes tiles for
+_PLANNED = {
+    "assign": [(65536, 1024, 128), (1_048_576, 65536, 512), (8192, 65536, 512),
+               (1000, 37, 19), (1_000_000, 1024, 4096)],
+    "update": [(65536, 1024, 128), (1_048_576, 65536, 512), (8192, 65536, 512),
+               (1000, 37, 19)],
+    "step": [(8_388_608, 1024, 128), (262_144, 1024, 128), (65536, 256, 128),
+             (100_000, 4096, 256), (1000, 37, 19)],
+    "probe": [(1024, 1024, 128, 32), (128, 1024, 128, 32),
+              (8192, 1024, 128, 8), (128, 32, 128, 6),
+              (100_000, 4096, 128, 64)],
+    "scan": [(128, 2048, 128, 40), (128, 65536, 128, 10), (128, 40, 128, 10),
+             (64, 512, 24, 8), (4096, 8192, 128, 100)],
+    "scan_q8": [(128, 2048, 128, 40), (128, 65536, 128, 60), (8, 128, 8, 8)],
+}
+
+
+@pytest.mark.parametrize("op", ["assign", "update", "step", "probe", "scan",
+                                "scan_q8"])
+def test_planned_tiles_within_modeled_budget(op):
+    """Every footprint model: the tiles the planner picks fit the VMEM
+    budget it models (the compile test checks the same tiles build)."""
+    from repro.core.plan import KernelPlanner
+    planner = KernelPlanner(H.TPU_V5E, persist=False)
+    budget = H.vmem_budget(H.TPU_V5E)
+    for shape in _PLANNED[op]:
+        dtype = "int8" if op == "scan_q8" else "float32"
+        p = planner.plan(op, shape, dtype)
+        assert p.vmem_bytes <= budget, (op, shape, p)
+        if op == "step" and p.impl == "fused":
+            bn, bk = p.blocks
+            k_pad = -(-shape[1] // bk) * bk
+            assert H.fused_footprint(bn, bk, shape[2], 4, k_pad) <= budget
+        elif op in ("assign", "update"):
+            fp = H.assign_footprint if op == "assign" else H.update_footprint
+            assert fp(*p.blocks, shape[2], 4) <= budget
+        elif op in ("probe", "scan"):
+            l_pad = -(-shape[3] // 8) * 8
+            fp = H.probe_footprint if op == "probe" else H.scan_footprint
+            assert fp(*p.blocks, l_pad, shape[2], 4) <= budget
+        elif op == "scan_q8":
+            l_pad = -(-shape[3] // 8) * 8
+            assert H.scan_q8_footprint(*p.blocks, l_pad, shape[2]) <= budget

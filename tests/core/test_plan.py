@@ -152,8 +152,9 @@ def test_bad_disk_entry_skipped_not_fatal(tmp_path):
 # ---------------------------------------------------------------------------
 
 class _Dev:
-    def __init__(self, kind):
+    def __init__(self, kind, platform="tpu"):
         self.device_kind = kind
+        self.platform = platform
 
 
 def test_detect_hardware_mapping_and_fallback():
@@ -163,12 +164,27 @@ def test_detect_hardware_mapping_and_fallback():
     assert P.detect_hardware([_Dev("TPU v5")]) is H.TPU_V5P
     assert P.detect_hardware([_Dev("TPU v4")]) is H.TPU_V4
     assert P.detect_hardware([_Dev("TPU v6e")]) is H.TPU_V6E
-    # unknown kinds, empty device lists, CPU backends: explicit fallback
-    assert P.detect_hardware([_Dev("Tesla V100")]) is H.TPU_V5E
-    assert P.detect_hardware([]) is H.TPU_V5E
-    assert P.detect_hardware([_Dev("cpu")]) is H.TPU_V5E
-    # on this machine (whatever it is) detection never fails
-    assert isinstance(P.detect_hardware(), H.Hardware)
+    # only the CPU backend (interpret mode) plans against the v5e row
+    assert P.detect_hardware([_Dev("cpu", "cpu")]) is H.TPU_V5E
+    assert P.detect_hardware() is H.TPU_V5E            # tests force the CPU
+    # an unknown chip, another platform or no device at all is an error
+    with pytest.raises(RuntimeError, match="no HARDWARE_TABLE row"):
+        P.detect_hardware([_Dev("Tesla V100", "gpu")])
+    with pytest.raises(RuntimeError, match="no devices"):
+        P.detect_hardware([])
+
+
+def test_detect_hardware_unknown_tpu_raises():
+    with pytest.raises(RuntimeError, match="TPU v9"):
+        P.detect_hardware([_Dev("TPU v9")])
+
+
+def test_detect_hardware_enumeration_failure_raises(monkeypatch):
+    def broken():
+        raise RuntimeError("backend init failed")
+    monkeypatch.setattr(P.jax, "devices", broken)
+    with pytest.raises(RuntimeError, match="backend init failed"):
+        P.detect_hardware()
 
 
 def test_planner_keys_are_hardware_specific(tmp_path):
@@ -204,7 +220,7 @@ def test_audit_uses_the_plans_hardware():
     from repro.kernels.ops import _audit_blocks
     big = H.TPU_V6E.vmem_bytes                         # 2x v5e
     # pick (bn, d) so the footprint fits v6e but overflows v5e
-    bn, d = 1024, 5120                                 # ~21 MB resident tile
+    bn, d = 512, 2048                                  # ~19 MB working set
     assert H.TPU_V5E.vmem_bytes < H.assign_footprint(bn, 128, d, 4) <= big
     with warnings.catch_warnings():
         warnings.simplefilter("error")                 # any warn -> failure

@@ -19,7 +19,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.core import KMeansConfig, init_centroids, make_kmeans_fn
+from repro.core import (KMeansConfig, build_mesh, init_centroids,
+                        make_kmeans_fn)
 from repro.core.distributed import make_distributed_kmeans
 
 ok = True
@@ -47,7 +48,7 @@ def main():
         c_ref, a_ref, j_ref = lloyd_step(x, c_ref, cfg)
 
     # --- 1. N-sharded over a (2,4) mesh ----------------------------------
-    mesh = jax.make_mesh((2, 4), ("pod", "data"))
+    mesh = build_mesh((2, 4), ("pod", "data"))
     fit = make_distributed_kmeans(mesh, cfg, data_axes=("pod", "data"))
     xs = jax.device_put(x, NamedSharding(mesh, P(("pod", "data"), None)))
     c0r = jax.device_put(c0, NamedSharding(mesh, P(None, None)))
@@ -70,7 +71,7 @@ def main():
           abs(float(jf) - float(j_ref)) / float(j_ref) < 1e-5)
 
     # --- 3. K-sharded (2-D kmeans) ----------------------------------------
-    mesh2 = jax.make_mesh((2, 4), ("data", "model"))
+    mesh2 = build_mesh((2, 4), ("data", "model"))
     fit2 = make_distributed_kmeans(mesh2, cfg, data_axes=("data",),
                                    k_axis="model")
     xs2 = jax.device_put(x, NamedSharding(mesh2, P("data", None)))

@@ -16,7 +16,7 @@ def test_distributed_equivalences():
     env["PYTHONPATH"] = os.pathsep.join(
         [os.path.join(ROOT, "src"), ROOT,
          env.get("PYTHONPATH", "")])
-    env.pop("JAX_PLATFORMS", None)
+    env["JAX_PLATFORMS"] = "cpu"   # the worker's fake devices are CPUs
     r = subprocess.run(
         [sys.executable,
          os.path.join(ROOT, "tests", "distributed", "_dist_worker.py")],

@@ -25,7 +25,7 @@ def test_parallel_layer_equivalences():
     env = dict(os.environ)
     env["PYTHONPATH"] = os.pathsep.join(
         [os.path.join(ROOT, "src"), ROOT, env.get("PYTHONPATH", "")])
-    env.pop("JAX_PLATFORMS", None)
+    env["JAX_PLATFORMS"] = "cpu"   # the worker's fake devices are CPUs
     r = subprocess.run(
         [sys.executable,
          os.path.join(ROOT, "tests", "distributed", "_parallel_worker.py")],
@@ -50,10 +50,10 @@ def _py_sources():
 
 def test_zero_shard_map_call_sites_outside_parallel():
     """The acceptance invariant of the ParallelContext refactor: the raw
-    shard_map mechanism (jax.shard_map / jax.experimental.shard_map /
-    shard_map_compat) is invoked in exactly one module. Drivers compose
+    shard_map mechanism (jax.shard_map / jax.experimental.shard_map) is
+    invoked in exactly one module. Drivers compose
     programs via ``ParallelContext.spmd`` and the ``make_*`` builders."""
-    bare_call = re.compile(r"(?<![.\w])shard_map(?:_compat)?\s*\(")
+    bare_call = re.compile(r"(?<![.\w])shard_map\s*\(")
     offenders = []
     for path in _py_sources():
         rel = os.path.relpath(path, SRC)

@@ -26,6 +26,7 @@ from repro.index import (IVFIndex, Fp32Codec, Int8ResidualCodec,
                          make_quantized_store, recall_at_k)
 from repro.index.store import restore_store
 from repro.optim.compression import quantize_int8, dequantize_int8
+from tests.conftest import assert_topk_match
 
 
 def _blobs(key, n, k, d, spread=6.0, noise=0.3):
@@ -194,23 +195,6 @@ def test_rescore_reservoir_fifo_budget():
 
 # --- two-phase search ------------------------------------------------------
 
-def _assert_topk_match(ids, dists, ids_ref, dists_ref, tol=1e-3):
-    """Same contract as the fp32 acceptance tests (test_ivf.py): result
-    lists may differ from the brute reference only by swaps of numerical
-    near-ties (the two paths accumulate f32 distances differently)."""
-    ids, dists = np.asarray(ids), np.asarray(dists)
-    ids_ref, dists_ref = np.asarray(ids_ref), np.asarray(dists_ref)
-    np.testing.assert_allclose(dists, dists_ref, rtol=1e-4, atol=tol)
-    bad = []
-    for r in range(ids.shape[0]):
-        for j in np.nonzero(ids[r] != ids_ref[r])[0]:
-            if abs(dists[r, j] - dists_ref[r, j]) > tol:
-                bad.append((r, j))
-        if set(ids[r].tolist()) != set(ids_ref[r].tolist()):
-            bad.append((r, "set"))
-    assert not bad, f"{len(bad)} true mismatches, first {bad[:5]}"
-
-
 def test_full_nprobe_reproduces_brute_force_exact(kind):
     """The tentpole guarantee: quantized propose + exact rescore at
     full nprobe (R covering topk) returns brute force's ids exactly —
@@ -247,7 +231,7 @@ def test_full_nprobe_matches_brute_on_clusters(corpus, kind):
     q = x[:64]
     ids_bf, d_bf = idx.search_brute(q, topk=10)
     ids, d = idx.search(q, topk=10, nprobe=16)
-    _assert_topk_match(ids, d, ids_bf, d_bf)
+    assert_topk_match(ids, d, ids_bf, d_bf)
 
 
 def test_recall_vs_rescore_mult(corpus, kind):

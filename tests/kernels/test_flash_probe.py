@@ -1,5 +1,6 @@
 """FlashProbe fused top-L kernel vs the jax.lax.top_k dense oracle:
-bit-exactness on single-K-tile shapes, index-exactness + tight value
+tie-aware parity within a stated f32 tolerance on single-K-tile shapes,
+index-exactness + tight value
 agreement across tiled/ragged shapes, tie-breaking parity, the grouped
 (per-query-candidate) scan variant, and argmin (L=1) equivalence with
 FlashAssign (interpret mode on CPU)."""
@@ -10,6 +11,7 @@ import pytest
 
 from repro.core import heuristics
 from repro.kernels import ops, ref
+from tests.conftest import assert_topk_match, f32_score_tol
 
 
 def _data(n, k, d, dtype=jnp.float32, seed=0):
@@ -19,9 +21,10 @@ def _data(n, k, d, dtype=jnp.float32, seed=0):
     return q, c
 
 
-# one K tile, no shape padding, short d-reduction: the kernel's score
-# computation lowers to the same XLA dot as the dense oracle -> bitwise
-# identical selection
+# one K tile, no shape padding, short d-reduction: the kernel's tile dot
+# (interpret mode) and the oracle's dense dot are different XLA graphs
+# that may round the d-reduction differently, so kernel-level parity is
+# tie-aware within a stated f32 tolerance (8 ulps of the largest term)
 TINY = [(16, 8, 8, 4), (32, 16, 8, 4), (64, 32, 8, 8), (8, 8, 8, 8),
         (24, 16, 4, 4)]
 
@@ -29,12 +32,11 @@ TINY = [(16, 8, 8, 4), (32, 16, 8, 4), (64, 32, 8, 8), (8, 8, 8, 8),
 @pytest.mark.parametrize("n,k,d,l", TINY)
 def test_bit_exact_vs_topk_tiny(n, k, d, l):
     q, c = _data(n, k, d, seed=n + k)
-    # kernel-level scores: bitwise identical to top_k of the dense matrix
+    # kernel-level scores vs top_k of the dense matrix
     idx, v = ops.flash_probe(q, c, l=l, block_n=max(n, 8), block_k=max(k, 8),
                              want_dists=False)
     idx_ref, v_ref = ref.probe_ref(q, c, l, want_dists=False)
-    assert np.array_equal(np.asarray(idx), np.asarray(idx_ref))
-    assert np.array_equal(np.asarray(v), np.asarray(v_ref))
+    assert_topk_match(idx, v, idx_ref, v_ref, tol=f32_score_tol(q, c), rtol=0)
     # true distances: the ||q||^2 re-add lives in two different XLA
     # graphs, so parity is ULP-tight rather than bitwise
     _, dv = ops.flash_probe(q, c, l=l, block_n=max(n, 8), block_k=max(k, 8))
