@@ -109,8 +109,10 @@ def rows() -> list[str]:
     # dispatch/complete p50/p99 come from the overlapped SearchEngine
     # pipeline (pipeline_depth=2): overlapped_units counts units
     # dispatched while an earlier unit's arrays were still in flight.
+    from repro import obs
     from repro.index import ivf as _ivf_mod
     from repro.serve.engine import SearchConfig, SearchEngine
+    obs.enable()   # latency_stats reads the engine's spans
 
     def _count_syncs(idx):
         idx.search(q, topk=topk, nprobe=8)   # warm (compile excluded)

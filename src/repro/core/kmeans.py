@@ -137,14 +137,18 @@ def lloyd_stats(x: Array, c: Array, cfg: KMeansConfig,
     impl = cfg.resolved_step_impl(x.shape[0], x.shape[1], x.dtype.itemsize,
                                   blk=blk)
     if impl == "fused":
-        return ops.flash_lloyd_step(
-            x, c, block_n=blk.fused_block_n, block_k=blk.fused_block_k,
-            interpret=cfg.interpret)
-    a, m = _assign(x, c, cfg, blk)
-    s, cnt = ops.centroid_stats(
-        x, a, k=cfg.k, impl=cfg.update_impl, block_n=blk.update_block_n,
-        block_k=blk.update_block_k, interpret=cfg.interpret)
-    return a, s, cnt, jnp.sum(m)
+        with jax.named_scope("lloyd.fused"):
+            return ops.flash_lloyd_step(
+                x, c, block_n=blk.fused_block_n, block_k=blk.fused_block_k,
+                interpret=cfg.interpret)
+    with jax.named_scope("lloyd.assign"):
+        a, m = _assign(x, c, cfg, blk)
+        inertia = jnp.sum(m)
+    with jax.named_scope("lloyd.update"):
+        s, cnt = ops.centroid_stats(
+            x, a, k=cfg.k, impl=cfg.update_impl, block_n=blk.update_block_n,
+            block_k=blk.update_block_k, interpret=cfg.interpret)
+    return a, s, cnt, inertia
 
 
 def lloyd_step(x: Array, c: Array, cfg: KMeansConfig,
@@ -152,7 +156,8 @@ def lloyd_step(x: Array, c: Array, cfg: KMeansConfig,
                ) -> tuple[Array, Array, Array]:
     """One exact Lloyd iteration. Returns (c_new, assignments, inertia)."""
     a, s, cnt, inertia = lloyd_stats(x, c, cfg, blk)
-    return ops.finalize_centroids(s, cnt, c), a, inertia
+    with jax.named_scope("lloyd.finalize"):
+        return ops.finalize_centroids(s, cnt, c), a, inertia
 
 
 def make_kmeans_fn(cfg: KMeansConfig):

@@ -66,6 +66,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
+from repro import obs
 from repro.core import heuristics as _heur
 from repro.core import plan as _plan
 from repro.core.chunked import ChunkedKMeans
@@ -161,16 +162,19 @@ def _ivf_search(q: Array, centroids: Array, c_sq: Array,
     its own ``nprobe * width`` block with the grouped probe kernel
     (query tiles, one launch for the whole batch).
     """
-    probe, _ = ops.flash_probe(q, centroids.astype(q.dtype), l=nprobe,
-                               block_n=bqn, block_k=bqk,
-                               interpret=interpret, want_dists=False,
-                               c_sq=c_sq)
-    cand_x, cand_ids = _store.gather_global(kind, store_arrays, probe,
-                                            width, ps, nsh)
-    li, dist = ops.flash_probe_grouped(q, cand_x, l=topk,
-                                       block_b=bsb, block_c=bsc,
-                                       interpret=interpret)   # (B, topk)
-    ids = jnp.take_along_axis(cand_ids, li, axis=1)
+    with jax.named_scope("ivf.probe"):
+        probe, _ = ops.flash_probe(q, centroids.astype(q.dtype), l=nprobe,
+                                   block_n=bqn, block_k=bqk,
+                                   interpret=interpret, want_dists=False,
+                                   c_sq=c_sq)
+    with jax.named_scope("ivf.gather"):
+        cand_x, cand_ids = _store.gather_global(kind, store_arrays, probe,
+                                                width, ps, nsh)
+    with jax.named_scope("ivf.scan"):
+        li, dist = ops.flash_probe_grouped(q, cand_x, l=topk,
+                                           block_b=bsb, block_c=bsc,
+                                           interpret=interpret)  # (B, topk)
+        ids = jnp.take_along_axis(cand_ids, li, axis=1)
     return ids, dist
 
 
@@ -226,20 +230,23 @@ def _ivf_search_routed(q: Array, centroids: Array, coarse: Array,
     the bucket gather/scan is byte-identical to ``_ivf_search`` except
     that sentinel cells (``probe == K``) are clamped for the gather and
     masked back to padding rows / ``-1`` ids afterwards."""
-    probe = _route_cells(q, centroids, coarse, coarse_sq, members, k=k,
-                         nprobe=nprobe, npc=npc, leff=leff, bcn=bcn,
-                         bck=bck, bfb=bfb, bfc=bfc, interpret=interpret)
-    safe = jnp.minimum(probe, k - 1)
-    cand_x, cand_ids = _store.gather_global(kind, store_arrays, safe,
-                                            width, ps, nsh)
-    pad = jnp.repeat(probe >= k, width, axis=1)      # (B, nprobe*width)
-    cand_x = jnp.where(pad[:, :, None],
-                       jnp.asarray(_PAD_COORD, cand_x.dtype), cand_x)
-    cand_ids = jnp.where(pad, -1, cand_ids)
-    li, dist = ops.flash_probe_grouped(q, cand_x, l=topk,
-                                       block_b=bsb, block_c=bsc,
-                                       interpret=interpret)
-    ids = jnp.take_along_axis(cand_ids, li, axis=1)
+    with jax.named_scope("ivf.probe"):
+        probe = _route_cells(q, centroids, coarse, coarse_sq, members, k=k,
+                             nprobe=nprobe, npc=npc, leff=leff, bcn=bcn,
+                             bck=bck, bfb=bfb, bfc=bfc, interpret=interpret)
+    with jax.named_scope("ivf.gather"):
+        safe = jnp.minimum(probe, k - 1)
+        cand_x, cand_ids = _store.gather_global(kind, store_arrays, safe,
+                                                width, ps, nsh)
+        pad = jnp.repeat(probe >= k, width, axis=1)  # (B, nprobe*width)
+        cand_x = jnp.where(pad[:, :, None],
+                           jnp.asarray(_PAD_COORD, cand_x.dtype), cand_x)
+        cand_ids = jnp.where(pad, -1, cand_ids)
+    with jax.named_scope("ivf.scan"):
+        li, dist = ops.flash_probe_grouped(q, cand_x, l=topk,
+                                           block_b=bsb, block_c=bsc,
+                                           interpret=interpret)
+        ids = jnp.take_along_axis(cand_ids, li, axis=1)
     return ids, dist
 
 
@@ -295,31 +302,34 @@ def _q8_propose_routed(q: Array, centroids: Array, coarse: Array,
     contract thin cells already use), so the rescore phase needs no
     change. Plain traceable function — jitted standalone (host-rescore
     oracle) and fused with cache lookup + rescore (device path)."""
-    probe = _route_cells(q, centroids, coarse, coarse_sq, members, k=k,
-                         nprobe=nprobe, npc=npc, leff=leff, bcn=bcn,
-                         bck=bck, bfb=bfb, bfc=bfc, interpret=interpret)
+    with jax.named_scope("ivf.probe"):
+        probe = _route_cells(q, centroids, coarse, coarse_sq, members, k=k,
+                             nprobe=nprobe, npc=npc, leff=leff, bcn=bcn,
+                             bck=bck, bfb=bfb, bfc=bfc, interpret=interpret)
     *arrays, anchors = store_arrays
-    safe = jnp.minimum(probe, k - 1)
-    codes, scales, cand_ids = _store.gather_global_q8(
-        kind, tuple(arrays), safe, width, ps, nsh)
-    pad = probe >= k                                     # (B, nprobe)
-    padw = jnp.repeat(pad, width, axis=1)
-    scales = jnp.where(padw, 0.0, scales)
-    cand_ids = jnp.where(padw, -1, cand_ids)
+    with jax.named_scope("ivf.gather"):
+        safe = jnp.minimum(probe, k - 1)
+        codes, scales, cand_ids = _store.gather_global_q8(
+            kind, tuple(arrays), safe, width, ps, nsh)
+        pad = probe >= k                                 # (B, nprobe)
+        padw = jnp.repeat(pad, width, axis=1)
+        scales = jnp.where(padw, 0.0, scales)
+        cand_ids = jnp.where(padw, -1, cand_ids)
     b, d = q.shape
-    anch = jnp.take(anchors, safe, axis=0)               # (B, nprobe, d)
-    anch = jnp.where(pad[:, :, None], 0.0, anch)
-    qp = q.astype(jnp.float32)[:, None, :] - anch
-    li, val = ops.flash_probe_grouped_q8(
-        qp, codes.reshape(b, nprobe, width, d),
-        scales.reshape(b, nprobe, width), l=r,
-        block_b=bsb, block_w=bsw, interpret=interpret)
-    ids = jnp.where(jnp.isfinite(val),
-                    jnp.take_along_axis(cand_ids, li, axis=1), -1)
-    deq = (jnp.take_along_axis(anch, (li // width)[:, :, None], axis=1)
-           + jnp.take_along_axis(codes, li[:, :, None], axis=1
-                                 ).astype(jnp.float32)
-           * jnp.take_along_axis(scales, li, axis=1)[:, :, None])
+    with jax.named_scope("ivf.scan"):
+        anch = jnp.take(anchors, safe, axis=0)           # (B, nprobe, d)
+        anch = jnp.where(pad[:, :, None], 0.0, anch)
+        qp = q.astype(jnp.float32)[:, None, :] - anch
+        li, val = ops.flash_probe_grouped_q8(
+            qp, codes.reshape(b, nprobe, width, d),
+            scales.reshape(b, nprobe, width), l=r,
+            block_b=bsb, block_w=bsw, interpret=interpret)
+        ids = jnp.where(jnp.isfinite(val),
+                        jnp.take_along_axis(cand_ids, li, axis=1), -1)
+        deq = (jnp.take_along_axis(anch, (li // width)[:, :, None], axis=1)
+               + jnp.take_along_axis(codes, li[:, :, None], axis=1
+                                     ).astype(jnp.float32)
+               * jnp.take_along_axis(scales, li, axis=1)[:, :, None])
     return ids, deq
 
 
@@ -346,26 +356,29 @@ def _q8_propose(q: Array, centroids: Array, c_sq: Array,
     traceable body; jitted standalone below and fused on the device
     path.
     """
-    probe, _ = ops.flash_probe(q, centroids.astype(q.dtype), l=nprobe,
-                               block_n=bqn, block_k=bqk,
-                               interpret=interpret, want_dists=False,
-                               c_sq=c_sq)
+    with jax.named_scope("ivf.probe"):
+        probe, _ = ops.flash_probe(q, centroids.astype(q.dtype), l=nprobe,
+                                   block_n=bqn, block_k=bqk,
+                                   interpret=interpret, want_dists=False,
+                                   c_sq=c_sq)
     *arrays, anchors = store_arrays
-    codes, scales, cand_ids = _store.gather_global_q8(
-        kind, tuple(arrays), probe, width, ps, nsh)
+    with jax.named_scope("ivf.gather"):
+        codes, scales, cand_ids = _store.gather_global_q8(
+            kind, tuple(arrays), probe, width, ps, nsh)
     b, d = q.shape
-    anch = jnp.take(anchors, probe, axis=0)          # (B, nprobe, d)
-    qp = q.astype(jnp.float32)[:, None, :] - anch
-    li, val = ops.flash_probe_grouped_q8(
-        qp, codes.reshape(b, nprobe, width, d),
-        scales.reshape(b, nprobe, width), l=r,
-        block_b=bsb, block_w=bsw, interpret=interpret)   # (B, r)
-    ids = jnp.where(jnp.isfinite(val),
-                    jnp.take_along_axis(cand_ids, li, axis=1), -1)
-    deq = (jnp.take_along_axis(anch, (li // width)[:, :, None], axis=1)
-           + jnp.take_along_axis(codes, li[:, :, None], axis=1
-                                 ).astype(jnp.float32)
-           * jnp.take_along_axis(scales, li, axis=1)[:, :, None])
+    with jax.named_scope("ivf.scan"):
+        anch = jnp.take(anchors, probe, axis=0)      # (B, nprobe, d)
+        qp = q.astype(jnp.float32)[:, None, :] - anch
+        li, val = ops.flash_probe_grouped_q8(
+            qp, codes.reshape(b, nprobe, width, d),
+            scales.reshape(b, nprobe, width), l=r,
+            block_b=bsb, block_w=bsw, interpret=interpret)   # (B, r)
+        ids = jnp.where(jnp.isfinite(val),
+                        jnp.take_along_axis(cand_ids, li, axis=1), -1)
+        deq = (jnp.take_along_axis(anch, (li // width)[:, :, None], axis=1)
+               + jnp.take_along_axis(codes, li[:, :, None], axis=1
+                                     ).astype(jnp.float32)
+               * jnp.take_along_axis(scales, li, axis=1)[:, :, None])
     return ids, deq
 
 
@@ -383,12 +396,13 @@ def _rescore_body(q: Array, cand: Array, ids: Array, res_rows: Array,
     dequantized codes otherwise (same overlay ``dense()`` applies, so
     two-phase and brute-force score literally identical rows) — and
     keep the true top-k. Dead proposals (id -1) become padding rows."""
-    cand = jnp.where(found[:, :, None], res_rows, cand)
-    cand = jnp.where((ids < 0)[:, :, None], _PAD_COORD, cand)
-    li, dist = ops.flash_probe_grouped(q.astype(cand.dtype), cand, l=topk,
-                                       block_b=bsb, block_c=bsc,
-                                       interpret=interpret)
-    return jnp.take_along_axis(ids, li, axis=1), dist
+    with jax.named_scope("ivf.rescore"):
+        cand = jnp.where(found[:, :, None], res_rows, cand)
+        cand = jnp.where((ids < 0)[:, :, None], _PAD_COORD, cand)
+        li, dist = ops.flash_probe_grouped(q.astype(cand.dtype), cand,
+                                           l=topk, block_b=bsb, block_c=bsc,
+                                           interpret=interpret)
+        return jnp.take_along_axis(ids, li, axis=1), dist
 
 
 _ivf_rescore = functools.partial(
@@ -419,7 +433,8 @@ def _ivf_search_q8_device(q: Array, centroids: Array, c_sq: Array,
                            r=r, nprobe=nprobe, width=width, ps=ps,
                            nsh=nsh, bqn=bqn, bqk=bqk, bsb=bsb, bsw=bsw,
                            interpret=interpret)
-    rows, found = _rcache.cache_lookup(ckeys, crows, ids)
+    with jax.named_scope("ivf.rescore"):
+        rows, found = _rcache.cache_lookup(ckeys, crows, ids)
     return ids, deq, rows, found
 
 
@@ -443,7 +458,8 @@ def _ivf_search_q8_routed_device(q: Array, centroids: Array,
         k=k, r=r, nprobe=nprobe, npc=npc, leff=leff, width=width, ps=ps,
         nsh=nsh, bcn=bcn, bck=bck, bfb=bfb, bfc=bfc, bsb=bsb, bsw=bsw,
         interpret=interpret)
-    rows, found = _rcache.cache_lookup(ckeys, crows, ids)
+    with jax.named_scope("ivf.rescore"):
+        rows, found = _rcache.cache_lookup(ckeys, crows, ids)
     return ids, deq, rows, found
 
 
@@ -1169,6 +1185,10 @@ class IVFIndex:
                     else:   # one replica == the whole index: hard fail
                         raise InjectedFault(
                             f"injected replica death ({ev})")
+        if obs.enabled():
+            obs.count("ivf.units")
+            obs.count("ivf.gathered_rows", self._gathered_rows(
+                q.shape[0], nprobe, self._gather_width(topk, nprobe)))
         if self.store.codec_kind != "fp32":
             return self._search_q8(q, topk, nprobe, shard_ok=shard_ok,
                                    nprobe_c=nprobe_c)
@@ -1199,6 +1219,19 @@ class IVFIndex:
                            ps=st.page_param, nsh=st.n_shards,
                            bqn=bqn, bqk=bqk, bsb=bsb, bsc=bsc,
                            interpret=self.interpret)
+
+    def _gathered_rows(self, b: int, nprobe: int, width: int) -> int:
+        """Candidate rows one search call of ``b`` queries gathers, over
+        all devices: ``nprobe`` lists of ``width`` slots per query, or,
+        on a cells-sharded mesh, the ``min(nprobe, K_local)`` owned lists
+        each K-shard gathers for every query of the data-padded batch."""
+        if not self._k_sharded:
+            return b * nprobe * width
+        pctx = self.pctx
+        pd = pctx.n_data_shards
+        b_pad = -(-b // pd) * pd
+        ll = min(nprobe, pctx.k_local(self.k))
+        return b_pad * ll * width * pctx.n_k_shards
 
     def _search_q8(self, q: Array, topk: int, nprobe: int,
                    shard_ok=None, nprobe_c: int | None = None
@@ -1368,72 +1401,75 @@ class IVFIndex:
             bl = q.shape[0]
             alive = shard_ok[jax.lax.axis_index(ka)]
             lo = jax.lax.axis_index(ka) * k_local
-            if routed:
-                gcell = _route_cells_sharded(
-                    pctx, ka, q, c_local, coarse, coarse_sq, members,
-                    k=k, k_local=k_local, nprobe=nprobe, npc=npc,
-                    leff=leff, bcn=bcn, bck=bck, bfb=bfb, bfc=bfc,
-                    alive=alive, interpret=interpret)
-            else:
-                idx, val = ops.flash_probe(q, c_local.astype(q.dtype),
-                                           l=ll, block_n=bqn,
-                                           block_k=bqk,
-                                           interpret=interpret,
-                                           want_dists=False,
-                                           c_sq=csq_local)
-                gcell, _ = pctx.merge_topl(idx + lo, val, nprobe,
-                                           valid=alive)   # (bl, nprobe)
-            rel = gcell - lo
-            owned = jnp.logical_and(rel >= 0, rel < k_local)
-            pos = jax.lax.broadcasted_iota(jnp.int32, (bl, nprobe), 1)
-            order = jnp.argsort(jnp.where(owned, pos, nprobe),
-                                axis=1)[:, :ll]
-            cell = jnp.take_along_axis(rel, order, axis=1)
-            ok = jnp.take_along_axis(owned, order, axis=1)
-            cell = jnp.where(ok, cell, k_local)
-            codes, scales, cand_ids = _store.gather_cells_q8(
-                kind, tuple(arrays), cell, width, ps)
-            # residual-frame queries: the padding cell k_local maps to a
-            # zero anchor row — its slots carry scale 0.0 and mask out
-            anch = jnp.take(
-                jnp.concatenate([anchors_l.astype(jnp.float32),
-                                 jnp.zeros((1, d), jnp.float32)], axis=0),
-                cell, axis=0)                        # (bl, ll, d)
-            qp = q.astype(jnp.float32)[:, None, :] - anch
-            lidx, lval = ops.flash_probe_grouped_q8(
-                qp, codes.reshape(bl, ll, width, d),
-                scales.reshape(bl, ll, width), l=rl,
-                block_b=bsb, block_w=bsw, interpret=interpret)
-            ids_loc = jnp.where(
-                jnp.isfinite(lval),
-                jnp.take_along_axis(cand_ids, lidx, axis=1), -1)
-            # same global probe-rank-major tie key as the fp32 merge
-            gpos = (jnp.take_along_axis(order, lidx // width, axis=1)
-                    * width + lidx % width)
-            gids, _ = pctx.merge_topl(ids_loc, lval, r, tie=gpos,
-                                      valid=alive)   # (bl, r)
-            # row exchange: dequantize the local proposals, match them
-            # against the merged id list, and psum — O(b·r·d) wire bytes
-            deq_loc = (jnp.take_along_axis(anch, (lidx // width)[:, :, None],
-                                           axis=1)
-                       + jnp.take_along_axis(codes, lidx[:, :, None], axis=1
-                                             ).astype(jnp.float32)
-                       * jnp.take_along_axis(scales, lidx,
-                                             axis=1)[:, :, None])
-            if cached:
-                # the local cache slice holds exactly the ids this
-                # shard owns (home cell fixed at append time): swap
-                # original fp32 rows in for the dequantized local
-                # proposals, then let the existing exchange carry them
-                crs, cfound = _rcache.cache_lookup(ckeys, crows, ids_loc)
-                deq_loc = jnp.where(cfound[:, :, None], crs, deq_loc)
-            match = jnp.logical_and(
-                gids[:, :, None] == ids_loc[:, None, :],
-                (ids_loc >= 0)[:, None, :]).astype(jnp.float32)
-            rows = jax.lax.psum(jnp.einsum("brl,bld->brd", match, deq_loc),
-                                ka)
-            hit = jax.lax.psum(jnp.sum(match, axis=-1), ka)
-            rows = jnp.where((hit > 0.0)[:, :, None], rows, _PAD_COORD)
+            with jax.named_scope("ivf.probe"):
+                if routed:
+                    gcell = _route_cells_sharded(
+                        pctx, ka, q, c_local, coarse, coarse_sq, members,
+                        k=k, k_local=k_local, nprobe=nprobe, npc=npc,
+                        leff=leff, bcn=bcn, bck=bck, bfb=bfb, bfc=bfc,
+                        alive=alive, interpret=interpret)
+                else:
+                    idx, val = ops.flash_probe(q, c_local.astype(q.dtype),
+                                               l=ll, block_n=bqn,
+                                               block_k=bqk,
+                                               interpret=interpret,
+                                               want_dists=False,
+                                               c_sq=csq_local)
+                    gcell, _ = pctx.merge_topl(idx + lo, val, nprobe,
+                                               valid=alive)   # (bl, nprobe)
+            with jax.named_scope("ivf.gather"):
+                rel = gcell - lo
+                owned = jnp.logical_and(rel >= 0, rel < k_local)
+                pos = jax.lax.broadcasted_iota(jnp.int32, (bl, nprobe), 1)
+                order = jnp.argsort(jnp.where(owned, pos, nprobe),
+                                    axis=1)[:, :ll]
+                cell = jnp.take_along_axis(rel, order, axis=1)
+                ok = jnp.take_along_axis(owned, order, axis=1)
+                cell = jnp.where(ok, cell, k_local)
+                codes, scales, cand_ids = _store.gather_cells_q8(
+                    kind, tuple(arrays), cell, width, ps)
+            with jax.named_scope("ivf.scan"):
+                # residual-frame queries: the padding cell k_local maps to a
+                # zero anchor row — its slots carry scale 0.0 and mask out
+                anch = jnp.take(
+                    jnp.concatenate([anchors_l.astype(jnp.float32),
+                                     jnp.zeros((1, d), jnp.float32)], axis=0),
+                    cell, axis=0)                        # (bl, ll, d)
+                qp = q.astype(jnp.float32)[:, None, :] - anch
+                lidx, lval = ops.flash_probe_grouped_q8(
+                    qp, codes.reshape(bl, ll, width, d),
+                    scales.reshape(bl, ll, width), l=rl,
+                    block_b=bsb, block_w=bsw, interpret=interpret)
+                ids_loc = jnp.where(
+                    jnp.isfinite(lval),
+                    jnp.take_along_axis(cand_ids, lidx, axis=1), -1)
+                # same global probe-rank-major tie key as the fp32 merge
+                gpos = (jnp.take_along_axis(order, lidx // width, axis=1)
+                        * width + lidx % width)
+                gids, _ = pctx.merge_topl(ids_loc, lval, r, tie=gpos,
+                                          valid=alive)   # (bl, r)
+                # row exchange: dequantize the local proposals, match them
+                # against the merged id list, and psum — O(b·r·d) wire bytes
+                deq_loc = (
+                    jnp.take_along_axis(anch, (lidx // width)[:, :, None],
+                                        axis=1)
+                    + jnp.take_along_axis(codes, lidx[:, :, None], axis=1
+                                          ).astype(jnp.float32)
+                    * jnp.take_along_axis(scales, lidx, axis=1)[:, :, None])
+                if cached:
+                    # the local cache slice holds exactly the ids this
+                    # shard owns (home cell fixed at append time): swap
+                    # original fp32 rows in for the dequantized local
+                    # proposals, then let the existing exchange carry them
+                    crs, cfound = _rcache.cache_lookup(ckeys, crows, ids_loc)
+                    deq_loc = jnp.where(cfound[:, :, None], crs, deq_loc)
+                match = jnp.logical_and(
+                    gids[:, :, None] == ids_loc[:, None, :],
+                    (ids_loc >= 0)[:, None, :]).astype(jnp.float32)
+                rows = jax.lax.psum(jnp.einsum("brl,bld->brd", match, deq_loc),
+                                    ka)
+                hit = jax.lax.psum(jnp.sum(match, axis=-1), ka)
+                rows = jnp.where((hit > 0.0)[:, :, None], rows, _PAD_COORD)
             return gids, rows
 
         router_specs = ((P(None, None), P(None), P(None, None))
@@ -1516,60 +1552,63 @@ class IVFIndex:
             # a dead shard (reliability seam) contributes to neither merge
             alive = shard_ok[jax.lax.axis_index(ka)]
             lo = jax.lax.axis_index(ka) * k_local
-            if routed:
-                # stage 1': replicated coarse probe + owned-candidate
-                # fine scoring + the same cross-shard top-L merge
-                gcell = _route_cells_sharded(
-                    pctx, ka, q, c_local, coarse, coarse_sq, members,
-                    k=k, k_local=k_local, nprobe=nprobe, npc=npc,
-                    leff=leff, bcn=bcn, bck=bck, bfb=bfb, bfc=bfc,
-                    alive=alive, interpret=interpret)
-            else:
-                # stage 1: local top-ll probe over the owned centroids,
-                # then the cross-shard top-nprobe merge — O(b·ll) wire
-                idx, val = ops.flash_probe(q, c_local.astype(q.dtype),
-                                           l=ll, block_n=bqn,
-                                           block_k=bqk,
-                                           interpret=interpret,
-                                           want_dists=False,
-                                           c_sq=csq_local)
-                gcell, _ = pctx.merge_topl(idx + lo, val, nprobe,
-                                           valid=alive)   # (bl, nprobe)
-            # stage 2: compact this shard's owned probed cells (stable:
-            # global probe order preserved) into a fixed (bl, ll) block;
-            # non-owned slots point at the padding cell k_local, which
-            # the store's gather maps onto padding slots
-            rel = gcell - lo
-            owned = jnp.logical_and(rel >= 0, rel < k_local)
-            pos = jax.lax.broadcasted_iota(jnp.int32, (bl, nprobe), 1)
-            order = jnp.argsort(jnp.where(owned, pos, nprobe),
-                                axis=1)[:, :ll]
-            cell = jnp.take_along_axis(rel, order, axis=1)
-            ok = jnp.take_along_axis(owned, order, axis=1)
-            cell = jnp.where(ok, cell, k_local)
-            cand_x, cand_ids = _store.gather_cells(kind, tuple(arrays),
-                                                   cell, width, ps)
-            # stage 3: local grouped scan of the owned buckets (payloads
-            # stay on-shard), then the global top-k merge — O(b·topk).
-            # The tie key is each candidate's *global probe-rank-major*
-            # position — exactly the candidate-axis position the
-            # single-device scan sees it at — so equal distances break
-            # identically to `jax.lax.top_k` over the reference
-            # candidate block, not toward the lower shard rank.
-            lidx, lval = ops.flash_probe_grouped(
-                q, cand_x, l=li, block_b=bsb, block_c=bsc,
-                interpret=interpret, want_dists=False)
-            ids_loc = jnp.take_along_axis(cand_ids, lidx, axis=1)
-            gpos = (jnp.take_along_axis(order, lidx // width, axis=1)
-                    * width + lidx % width)
-            gids, gval = pctx.merge_topl(ids_loc, lval, topk, tie=gpos,
-                                         valid=alive)
-            q32 = q.astype(jnp.float32)
-            gval = gval + jnp.sum(q32 * q32, axis=-1, keepdims=True)
-            # blanked (dead-shard) slots carry inf: report them as honest
-            # empty results, never a non-finite distance
-            gval = jnp.where(jnp.isfinite(gval), jnp.maximum(gval, 0.0),
-                             0.0)
+            with jax.named_scope("ivf.probe"):
+                if routed:
+                    # stage 1': replicated coarse probe + owned-candidate
+                    # fine scoring + the same cross-shard top-L merge
+                    gcell = _route_cells_sharded(
+                        pctx, ka, q, c_local, coarse, coarse_sq, members,
+                        k=k, k_local=k_local, nprobe=nprobe, npc=npc,
+                        leff=leff, bcn=bcn, bck=bck, bfb=bfb, bfc=bfc,
+                        alive=alive, interpret=interpret)
+                else:
+                    # stage 1: local top-ll probe over the owned centroids,
+                    # then the cross-shard top-nprobe merge — O(b·ll) wire
+                    idx, val = ops.flash_probe(q, c_local.astype(q.dtype),
+                                               l=ll, block_n=bqn,
+                                               block_k=bqk,
+                                               interpret=interpret,
+                                               want_dists=False,
+                                               c_sq=csq_local)
+                    gcell, _ = pctx.merge_topl(idx + lo, val, nprobe,
+                                               valid=alive)   # (bl, nprobe)
+            with jax.named_scope("ivf.gather"):
+                # stage 2: compact this shard's owned probed cells (stable:
+                # global probe order preserved) into a fixed (bl, ll) block;
+                # non-owned slots point at the padding cell k_local, which
+                # the store's gather maps onto padding slots
+                rel = gcell - lo
+                owned = jnp.logical_and(rel >= 0, rel < k_local)
+                pos = jax.lax.broadcasted_iota(jnp.int32, (bl, nprobe), 1)
+                order = jnp.argsort(jnp.where(owned, pos, nprobe),
+                                    axis=1)[:, :ll]
+                cell = jnp.take_along_axis(rel, order, axis=1)
+                ok = jnp.take_along_axis(owned, order, axis=1)
+                cell = jnp.where(ok, cell, k_local)
+                cand_x, cand_ids = _store.gather_cells(kind, tuple(arrays),
+                                                       cell, width, ps)
+            with jax.named_scope("ivf.scan"):
+                # stage 3: local grouped scan of the owned buckets (payloads
+                # stay on-shard), then the global top-k merge — O(b·topk).
+                # The tie key is each candidate's *global probe-rank-major*
+                # position — exactly the candidate-axis position the
+                # single-device scan sees it at — so equal distances break
+                # identically to `jax.lax.top_k` over the reference
+                # candidate block, not toward the lower shard rank.
+                lidx, lval = ops.flash_probe_grouped(
+                    q, cand_x, l=li, block_b=bsb, block_c=bsc,
+                    interpret=interpret, want_dists=False)
+                ids_loc = jnp.take_along_axis(cand_ids, lidx, axis=1)
+                gpos = (jnp.take_along_axis(order, lidx // width, axis=1)
+                        * width + lidx % width)
+                gids, gval = pctx.merge_topl(ids_loc, lval, topk, tie=gpos,
+                                             valid=alive)
+                q32 = q.astype(jnp.float32)
+                gval = gval + jnp.sum(q32 * q32, axis=-1, keepdims=True)
+                # blanked (dead-shard) slots carry inf: report them as honest
+                # empty results, never a non-finite distance
+                gval = jnp.where(jnp.isfinite(gval), jnp.maximum(gval, 0.0),
+                                 0.0)
             return gids, gval
 
         router_specs = ((P(None, None), P(None), P(None, None))

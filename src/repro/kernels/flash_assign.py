@@ -128,6 +128,7 @@ def flash_assign_raw(x: Array, c: Array, *, block_n: int, block_k: int,
 
     return pl.pallas_call(
         kernel,
+        name="flash_assign",
         grid=grid,
         in_specs=[
             pl.BlockSpec((block_n, d), lambda i, k: (i, 0)),

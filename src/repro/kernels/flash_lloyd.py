@@ -151,6 +151,7 @@ def flash_lloyd_raw(x: Array, c: Array, *, block_n: int, block_k: int,
 
     return pl.pallas_call(
         kernel,
+        name="flash_lloyd_step",
         grid=(n_tiles,),
         in_specs=[
             pl.BlockSpec((block_n, d), lambda i: (i, 0)),

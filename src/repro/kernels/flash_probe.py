@@ -248,6 +248,7 @@ def flash_probe_grouped_q8_raw(qp: Array, codes: Array, scales: Array, *,
 
     return pl.pallas_call(
         kernel,
+        name="flash_probe_grouped_q8",
         grid=grid,
         in_specs=[
             pl.BlockSpec((None, block_b, d), lambda i, p, w: (p, i, 0)),
@@ -292,6 +293,7 @@ def flash_probe_grouped_raw(q: Array, c: Array, *, l: int, block_b: int,
 
     return pl.pallas_call(
         kernel,
+        name="flash_probe_grouped",
         grid=grid,
         in_specs=[
             pl.BlockSpec((block_b, d), lambda i, c: (i, 0)),
@@ -338,6 +340,7 @@ def flash_probe_raw(q: Array, c: Array, *, l: int, block_n: int,
 
     return pl.pallas_call(
         kernel,
+        name="flash_probe",
         grid=grid,
         in_specs=[
             pl.BlockSpec((block_n, d), lambda i, k: (i, 0)),

@@ -140,6 +140,7 @@ def sort_inverse_update_raw(x_sorted: Array, a_sorted: Array,
 
     return pl.pallas_call(
         kernel,
+        name="sort_inverse_update",
         grid_spec=grid_spec,
         out_shape=[
             jax.ShapeDtypeStruct((k_rows, d), jnp.float32),

@@ -22,6 +22,7 @@ import time
 
 import jax
 
+from repro import obs
 from repro.configs.base import get_config
 from repro.core.parallel import ParallelContext, parse_mesh_flag
 from repro.models import model as M
@@ -73,6 +74,7 @@ def _serve_search(args) -> None:
     the modeled cross-shard bytes per batch are reported alongside QPS.
     """
     from repro.index import IVFIndex, recall_at_k
+    obs.enable()   # latency_stats reads the engine's spans
 
     from repro.reliability import FaultInjector, FaultPlan, HealthPolicy
 

@@ -23,6 +23,7 @@ from __future__ import annotations
 import collections
 import dataclasses
 import functools
+import itertools
 import time
 from typing import Any
 
@@ -30,6 +31,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.configs.base import ArchConfig
 from repro.models import kmeans_attention as kma
 from repro.models import model as M
@@ -41,6 +43,8 @@ from repro.reliability.validate import guard_batch
 from repro.reliability.wal import AddLog
 
 Array = jax.Array
+
+_ENGINE_IDS = itertools.count(1)   # tells engines apart in the spans
 
 
 @dataclasses.dataclass
@@ -222,7 +226,15 @@ class SearchEngine:
     (double-buffered by default, the same discipline as
     ``ChunkedKMeans``). Completion happens at ``take`` (oldest-first)
     or on depth overflow, and ``latency_stats`` reports the honest
-    dispatch-vs-complete split (p50/p99 over warm shape buckets).
+    dispatch-vs-complete split (p50/p99 over warm shape buckets) from
+    the engine's spans.
+
+    With tracing on (``repro.obs.enable()``) the engine records spans
+    ``engine.submit`` and ``engine.take`` (``rid``), ``engine.pump``,
+    and per unit ``engine.form`` (``unit``, ``rids``, ``rows``,
+    ``bucket``), ``engine.dispatch``, ``engine.settle`` and
+    ``engine.complete`` (``unit``); ``unit`` is the unit's number in
+    ``batches_formed`` and ``engine`` tells engines apart.
 
     The engine is sharding-transparent: over an ``IVFIndex`` built with
     a ``ParallelContext`` (cells + posting lists partitioned over the
@@ -270,15 +282,9 @@ class SearchEngine:
         # pipeline depth overflows. ``overlap_hits`` counts dispatches
         # issued while an earlier unit was still un-synced — structural
         # overlap, the property the async hot path exists to create.
-        # Timing discipline mirrors ChunkedStats: first-seen shape
-        # buckets pay compile and are never sampled; dispatch_ms is the
-        # Python-side enqueue cost, complete_ms is enqueue -> arrays
-        # ready (block_until_ready before reading the clock).
         self.overlap_hits = 0
         self._inflight: collections.deque = collections.deque()
-        self._dispatch_ms: list[float] = []
-        self._complete_ms: list[float] = []
-        self._seen_buckets: set[int] = set()
+        self._eid = next(_ENGINE_IDS)
         # unit shape buckets: powers of two up to query_batch (the same
         # snapping rule as KernelPlanner.bucket_dim, floored at 8)
         qb = self.scfg.query_batch
@@ -329,14 +335,15 @@ class SearchEngine:
         """Enqueue a search request (any row count, including 0);
         returns a request id for ``take``. Sanitization happens at
         admission so the queue only holds servable rows."""
-        q = jnp.asarray(q)
-        if self.health is not None:
-            qh, rep = guard_batch(np.asarray(q), self.index.d,
-                                  policy=self.health.query_policy,
-                                  name="query batch")
-            self.counters.queries_sanitized += rep.bad_rows
-            q = jnp.asarray(qh, q.dtype)
-        return self._admit("search", q)
+        with obs.span("engine.submit", rid=self._next_rid + 1):
+            q = jnp.asarray(q)
+            if self.health is not None:
+                qh, rep = guard_batch(np.asarray(q), self.index.d,
+                                      policy=self.health.query_policy,
+                                      name="query batch")
+                self.counters.queries_sanitized += rep.bad_rows
+                q = jnp.asarray(qh, q.dtype)
+            return self._admit("search", q)
 
     def submit_add(self, x) -> int:
         """Enqueue an insert; it is applied in FIFO position between
@@ -348,38 +355,62 @@ class SearchEngine:
         """Block (pump) until request ``rid`` completes; return its
         result — ``(ids, dists)`` for a search, assigned cells for an
         add."""
-        while rid not in self._results:
-            if not self.pump(1):
-                raise KeyError(f"unknown or lost request id {rid}")
-        # sync discipline: the dispatch path never blocks on device
-        # arrays — completion happens here, oldest-first, until no
-        # in-flight unit still carries this request's rows
-        while any(rid in rids for rids, _arrs, _t0, _w in self._inflight):
-            self._complete_oldest()
-        return self._results.pop(rid)
+        with obs.span("engine.take", rid=rid):
+            while rid not in self._results:
+                if not self.pump(1):
+                    raise KeyError(f"unknown or lost request id {rid}")
+            # sync discipline: the dispatch path never blocks on device
+            # arrays — completion happens here, oldest-first, until no
+            # in-flight unit still carries this request's rows
+            while any(rid in rids for rids, _arrs, _u in self._inflight):
+                self._complete_oldest()
+            return self._results.pop(rid)
 
     def _complete_oldest(self) -> None:
         """Retire the oldest in-flight search unit: block until its
-        arrays are ready and record honest completion latency (warm
-        shape buckets only — first-seen buckets pay compile)."""
+        arrays are ready."""
         if not self._inflight:
             return
-        _rids, arrs, t0, warm = self._inflight.popleft()
-        jax.block_until_ready(arrs)
-        if warm:
-            self._complete_ms.append((time.perf_counter() - t0) * 1e3)
+        _rids, arrs, unit = self._inflight.popleft()
+        with obs.span("engine.complete", unit=unit, engine=self._eid):
+            jax.block_until_ready(arrs)
 
     def latency_stats(self) -> dict:
-        """Dispatch-vs-complete latency split (ms, warm buckets only):
-        ``dispatch_*`` is the Python enqueue cost per unit, ``complete_*``
-        is enqueue -> ``block_until_ready``. ``overlap_hits`` counts units
-        dispatched while an earlier unit was still in flight."""
+        """Dispatch-vs-complete latency split (ms), read from this
+        engine's ``engine.dispatch`` and ``engine.complete`` spans:
+        ``dispatch_*`` is the host's enqueue cost per unit, ``complete_*``
+        is enqueue -> ``block_until_ready``. The first unit of each shape
+        bucket pays compile and is left out. ``overlap_hits`` counts units
+        dispatched while an earlier unit was still in flight.
+
+        The percentiles need tracing on (``repro.obs.enable()``); with it
+        off there are no spans and they read 0. They cover the units
+        whose spans the record still holds: ``obs.reset()``, or the
+        record's bound (``obs.MAX_SPANS``, newest kept), drops the older
+        ones, and the first unit of each bucket after the drop is then
+        left out as if it were that bucket's first."""
+        mine = [(n, t0, t1, a) for n, t0, t1, _p, a in obs.snapshot()["spans"]
+                if a.get("engine") == self._eid]
+        bucket = {a["unit"]: a["bucket"] for n, _t0, _t1, a in mine
+                  if n == "engine.form" and "bucket" in a}
+        first = {}                      # bucket -> its first unit
+        for unit in sorted(bucket):
+            first.setdefault(bucket[unit], unit)
+        warm = set(bucket) - set(first.values())
+        start = {a["unit"]: t0 for n, t0, _t1, a in mine
+                 if n == "engine.dispatch"}
+        dispatch = [(t1 - t0) / 1e6 for n, t0, t1, a in mine
+                    if n == "engine.dispatch" and a["unit"] in warm]
+        complete = [(t1 - start[a["unit"]]) / 1e6 for n, _t0, t1, a in mine
+                    if n == "engine.complete" and a["unit"] in warm
+                    and a["unit"] in start]
+
         def pct(xs: list[float], p: float) -> float:
             return float(np.percentile(np.asarray(xs), p)) if xs else 0.0
-        return {"dispatch_p50_ms": pct(self._dispatch_ms, 50),
-                "dispatch_p99_ms": pct(self._dispatch_ms, 99),
-                "complete_p50_ms": pct(self._complete_ms, 50),
-                "complete_p99_ms": pct(self._complete_ms, 99),
+        return {"dispatch_p50_ms": pct(dispatch, 50),
+                "dispatch_p99_ms": pct(dispatch, 99),
+                "complete_p50_ms": pct(complete, 50),
+                "complete_p99_ms": pct(complete, 99),
                 "overlap_hits": self.overlap_hits,
                 "inflight": len(self._inflight)}
 
@@ -388,14 +419,15 @@ class SearchEngine:
         padded search batch or one interleaved add. Returns the number
         of units executed (0 = queue empty)."""
         done = 0
-        while self._queue and (max_units is None or done < max_units):
-            if self._queue[0][0] == "add":
-                _, rid, x = self._queue.popleft()
-                self._results[rid] = self.add(x)
-                self.interleaved_adds += 1
-            else:
-                self._run_search_unit()
-            done += 1
+        with obs.span("engine.pump"):
+            while self._queue and (max_units is None or done < max_units):
+                if self._queue[0][0] == "add":
+                    _, rid, x = self._queue.popleft()
+                    self._results[rid] = self.add(x)
+                    self.interleaved_adds += 1
+                else:
+                    self._run_search_unit()
+                done += 1
         return done
 
     def _run_search_unit(self) -> None:
@@ -404,6 +436,41 @@ class SearchEngine:
         oversized request — its tail stays at the head of the line),
         snap the unit to its power-of-two shape bucket, run it through
         the health ladder, and scatter results back per request."""
+        unit_no = self.batches_formed + 1
+        with obs.span("engine.form", unit=unit_no, engine=self._eid) as sp:
+            formed = self._form_unit()
+            if formed is None:
+                return
+            parts, rows, bucket, unit = formed
+            if obs.enabled():
+                sp.set(rids=tuple(p[0] for p in parts), rows=rows,
+                       bucket=bucket)
+        # overlapped dispatch: start this unit while earlier units'
+        # arrays may still be in flight (jax arrays are async; adds
+        # interleave safely because dispatch captured its operands)
+        if self._inflight:
+            self.overlap_hits += 1
+        with obs.span("engine.dispatch", unit=unit_no, engine=self._eid):
+            ids, dists = self._search_padded(unit)
+        self.batches_formed += 1
+        self.queries_served += rows
+        with obs.span("engine.settle", unit=unit_no, engine=self._eid):
+            lo = 0
+            for rid, qpart, has_tail in parts:
+                n = qpart.shape[0]
+                self._settle(rid, ids[lo:lo + n], dists[lo:lo + n],
+                             has_tail=has_tail)
+                lo += n
+        self._inflight.append(
+            ({rid for rid, _q, _t in parts}, (ids, dists), unit_no))
+        while len(self._inflight) > max(1, self.scfg.pipeline_depth):
+            self._complete_oldest()
+
+    def _form_unit(self):
+        """Pop the next unit's requests off the queue: ``(parts, rows,
+        bucket, unit)`` with ``parts`` the ``(rid, rows, has_tail)``
+        slices and ``unit`` the padded ``(bucket, d)`` query block; None
+        when the head of the queue held only empty requests."""
         qb = self.scfg.query_batch
         parts: list[tuple[int, Array, bool]] = []   # (rid, rows, has_tail)
         rows = 0
@@ -423,7 +490,7 @@ class SearchEngine:
             if n > tk:
                 break
         if not parts:
-            return
+            return None
         if len(parts) > 1:
             self.coalesced_requests += len(parts)
         unit = parts[0][1] if len(parts) == 1 else \
@@ -440,29 +507,7 @@ class SearchEngine:
                                               self.scfg.nprobe_c)
             if geom != self._pinned_geom:
                 self._pin_plans()
-        # overlapped dispatch: issue this unit while earlier units'
-        # arrays may still be in flight (jax arrays are async; adds
-        # interleave safely because dispatch captured its operands)
-        warm = bucket in self._seen_buckets
-        self._seen_buckets.add(bucket)
-        if self._inflight:
-            self.overlap_hits += 1
-        t0 = time.perf_counter()
-        ids, dists = self._search_padded(unit)
-        if warm:
-            self._dispatch_ms.append((time.perf_counter() - t0) * 1e3)
-        self.batches_formed += 1
-        self.queries_served += rows
-        lo = 0
-        for rid, qpart, has_tail in parts:
-            n = qpart.shape[0]
-            self._settle(rid, ids[lo:lo + n], dists[lo:lo + n],
-                         has_tail=has_tail)
-            lo += n
-        self._inflight.append(
-            ({rid for rid, _q, _t in parts}, (ids, dists), t0, warm))
-        while len(self._inflight) > max(1, self.scfg.pipeline_depth):
-            self._complete_oldest()
+        return parts, rows, bucket, unit
 
     def _settle(self, rid: int, ids: Array, dists: Array, *,
                 has_tail: bool) -> None:

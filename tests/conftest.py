@@ -25,6 +25,20 @@ def key():
     return jax.random.PRNGKey(0)
 
 
+@pytest.fixture()
+def tracing():
+    """Program tracing (``repro.obs``) on and empty for one test, then
+    off and empty again."""
+    from repro import obs
+    obs.reset()
+    obs.enable()
+    try:
+        yield obs
+    finally:
+        obs.disable()
+        obs.reset()
+
+
 def assert_assignments_match(x, c, a_test, a_ref, tol=1e-3):
     """Assignments may differ only on numerical near-ties."""
     import jax.numpy as jnp

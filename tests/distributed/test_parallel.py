@@ -192,6 +192,27 @@ def test_inprocess_sharded_search_ids_identical():
             f"nprobe={nprobe}"
 
 
+def test_inprocess_sharded_gathered_rows(tracing):
+    """On a (2 data x 4 cells) mesh each K-shard gathers min(nprobe,
+    K_local) owned lists of the gather width for every query of the
+    data-padded batch: the ``ivf.gathered_rows`` counter counts them all."""
+    _require_devices(8)
+    import jax
+    from repro.core.parallel import ParallelContext, build_mesh
+    from repro.index import IVFIndex
+    k, d, n = 16, 8, 1024
+    x = jax.random.normal(jax.random.PRNGKey(5), (n, d))
+    pctx = ParallelContext.for_mesh(build_mesh((2, 4), ("data", "model")))
+    idx = IVFIndex.build(x, k=k, max_iters=3, pctx=pctx)
+    for nprobe in (2, k):
+        tracing.reset()
+        idx.search(x[:63], topk=10, nprobe=nprobe)   # b_pad = 64
+        width = idx._gather_width(10, nprobe)
+        assert tracing.snapshot()["counters"] == {
+            "ivf.units": 1,
+            "ivf.gathered_rows": 64 * min(nprobe, k // 4) * width * 4}
+
+
 def test_inprocess_paged_store_sharded_ids_identical():
     """Paged bucket store on a (2 data x 4 cells) mesh: the page pool and
     page tables are sharded over the cells axis, yet search results stay

@@ -12,7 +12,12 @@ runs.
 
 The topology is described inside module-scoped fixtures (never at import)
 and the tests skip where it cannot be described.
+
+The compiled HLO also carries the names a device trace is read by: each
+kernel's explicit ``pallas_call`` name, and the ``ivf.probe``,
+``ivf.gather`` and ``ivf.scan`` scopes of the search program.
 """
+import re
 import warnings
 
 import jax
@@ -111,3 +116,71 @@ def test_kernel_compiles_for_v5e(case, one_chip, no_compile_cache):
         lowered = getattr(ops, fn).lower(*args, interpret=False, **kw)
     compiled = lowered.compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+# one small case per kernel: its pallas_call name, the ops wrapper and args
+NAMED = {
+    "flash_assign": ("flash_assign",
+                     [((4096, 128), F32), ((256, 128), F32)], {}),
+    "sort_inverse_update": ("sort_inverse_update",
+                            [((4096, 128), F32), ((4096,), I32)],
+                            {"k": 256}),
+    "flash_lloyd_step": ("flash_lloyd_step",
+                         [((4096, 128), F32), ((256, 128), F32)], {}),
+    "flash_probe": ("flash_probe",
+                    [((128, 128), F32), ((1024, 128), F32)], {"l": 32}),
+    "flash_probe_grouped": ("flash_probe_grouped",
+                            [((128, 128), F32), ((128, 2048, 128), F32)],
+                            {"l": 10}),
+    "flash_probe_grouped_q8": (
+        "flash_probe_grouped_q8",
+        [((128, 32, 128), F32), ((128, 32, 64, 128), I8),
+         ((128, 32, 64), F32)], {"l": 40}),
+}
+
+
+def _custom_call_names(hlo: str) -> list[str]:
+    """Instruction names of the compiled module's Mosaic kernels."""
+    return [m.group(1) for m in re.finditer(
+        r"%([\w.\-]+) = [^\n]*custom_call_target=\"tpu_custom_call\"",
+        hlo)]
+
+
+@pytest.mark.parametrize("kernel", list(NAMED))
+def test_kernel_hlo_carries_its_name(kernel, one_chip, no_compile_cache):
+    fn, specs, kw = NAMED[kernel]
+    args = [jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
+            for s, dt in specs]
+
+    @jax.jit
+    def caller(*a):
+        # the wrapper's body traced inside a jit of another name: an
+        # unnamed pallas_call would take the caller's name
+        return getattr(ops, fn).__wrapped__(*a, interpret=False, **kw)
+
+    hlo = caller.lower(*args).compile().as_text()
+    names = _custom_call_names(hlo)
+    assert names and all(n.split(".")[0] == kernel for n in names), names
+
+
+def test_ivf_search_hlo_carries_stage_scopes(one_chip, no_compile_cache):
+    from repro.core import plan as _plan
+    from repro.index import ivf
+    b, k, d, cap, nprobe, topk = 128, 1024, 128, 256, 32, 10
+    planner = _plan.default_planner()
+    bqn, bqk = planner.plan("probe", (b, k, d, nprobe), F32).blocks
+    bsb, bsc = planner.plan("scan", (b, nprobe * cap, d, topk), F32).blocks
+    args = [jax.ShapeDtypeStruct(s, dt, sharding=one_chip) for s, dt in
+            [((b, d), F32), ((k, d), F32), ((k,), F32)]]
+    store = tuple(jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
+                  for s, dt in [((k, cap, d), F32), ((k, cap), I32)])
+    hlo = ivf._ivf_search.lower(
+        *args, store, kind="padded", topk=topk, nprobe=nprobe, width=cap,
+        ps=0, nsh=1, bqn=bqn, bqk=bqk, bsb=bsb, bsc=bsc,
+        interpret=False).compile().as_text()
+    op_names = re.findall(r'op_name="([^"]*)"', hlo)
+    for stage in ("ivf.probe", "ivf.gather", "ivf.scan"):
+        assert any(f"/{stage}/" in n for n in op_names), stage
+    names = _custom_call_names(hlo)
+    assert sorted(n.split(".")[0] for n in names) == [
+        "flash_probe", "flash_probe_grouped"], names

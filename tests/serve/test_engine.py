@@ -206,19 +206,52 @@ def test_take_settles_inflight_before_returning(corpus):
     assert eng.latency_stats()["inflight"] == 0
 
 
-def test_latency_stats_honest_timing(corpus):
-    """dispatch/complete percentiles sample only warm shape buckets
-    (first-seen buckets pay compile and are excluded), and completion
-    latency can never be below dispatch latency."""
+def test_latency_stats_honest_timing(corpus, tracing):
+    """dispatch/complete percentiles are read from the engine's
+    ``engine.dispatch`` and ``engine.complete`` spans and sample only warm
+    shape buckets (the first unit of a bucket pays compile and is left
+    out), and completion latency can never be below dispatch latency."""
     x, q = corpus
     eng = _engine(x)
     lat0 = eng.latency_stats()
     assert lat0["dispatch_p50_ms"] == 0.0 and lat0["overlap_hits"] == 0
-    for _ in range(3):                  # rep 1 compiles, 2-3 sample
+    for _ in range(3):                  # unit 1 compiles, 2-3 sample
         eng.search(q[:32])
+    mine = {}
+    for name, t0, t1, _parent, attrs in tracing.snapshot()["spans"]:
+        if attrs.get("engine") == eng._eid:
+            mine.setdefault(name, {})[attrs["unit"]] = (t0, t1)
+    assert sorted(mine["engine.dispatch"]) == [1, 2, 3]
+    assert sorted(mine["engine.complete"]) == [1, 2, 3]
+    warm_dispatch = [(t1 - t0) / 1e6 for u, (t0, t1)
+                     in mine["engine.dispatch"].items() if u > 1]
+    warm_complete = [(mine["engine.complete"][u][1] - t0) / 1e6
+                     for u, (t0, _t1) in mine["engine.dispatch"].items()
+                     if u > 1]
     lat = eng.latency_stats()
-    assert len(eng._dispatch_ms) == 2 and len(eng._complete_ms) == 2
-    assert lat["dispatch_p50_ms"] >= 0.0
+    assert lat["dispatch_p50_ms"] == pytest.approx(
+        np.percentile(warm_dispatch, 50))
+    assert lat["complete_p99_ms"] == pytest.approx(
+        np.percentile(warm_complete, 99))
+    assert lat["dispatch_p50_ms"] > 0.0
     assert lat["complete_p50_ms"] >= lat["dispatch_p50_ms"]
     assert lat["complete_p99_ms"] >= lat["complete_p50_ms"]
     assert lat["inflight"] == 0
+
+
+def test_latency_stats_after_obs_reset(corpus, tracing):
+    """``obs.reset()`` between units drops the units recorded before it:
+    the next unit of the bucket is then left out as if it paid compile,
+    and the units after it are sampled again."""
+    x, q = corpus
+    eng = _engine(x)
+    for _ in range(3):
+        eng.search(q[:32])
+    tracing.reset()
+    assert eng.latency_stats()["dispatch_p50_ms"] == 0.0
+    eng.search(q[:32])                  # taken as the bucket's first unit
+    assert eng.latency_stats()["complete_p50_ms"] == 0.0
+    eng.search(q[:32])
+    lat = eng.latency_stats()
+    assert lat["dispatch_p50_ms"] > 0.0
+    assert lat["complete_p50_ms"] >= lat["dispatch_p50_ms"]
