@@ -220,6 +220,70 @@ def scan_footprint(bb: int, bc: int, l: int, d: int, bytes_in: int) -> int:
     return q_tiles + c_tiles + prods + score + select + state + out
 
 
+def list_scan_footprint(g: int, bw: int, l: int, d: int,
+                        bytes_in: int) -> int:
+    """VMEM bytes held live by one list-major scan grid step: a ``(G, d)``
+    query group and a ``(B_W, d)`` list tile (both double-buffered), the
+    tile's f32 transpose and its square for ``||c||^2``, the MXU's
+    operand split, and the ``(G, B_W)`` score and selection carry."""
+    q_tiles = 2 * g * d * bytes_in
+    c_tiles = 2 * bw * d * bytes_in
+    ct = 2 * bw * d * 4                 # f32 transpose + its square
+    mxu = _mxu_split((g + bw) * d, 4)
+    score = 2 * g * bw * 4              # cross term + masked score
+    select = 2 * g * (l + bw) * 4
+    state = g * l * (4 + 4)
+    out = 2 * g * l * (4 + 4)
+    return q_tiles + c_tiles + ct + mxu + score + select + state + out
+
+
+# The list-major scan's cost model (``choose_list_scan_blocks``), fitted
+# to a (G, B_W) sweep of the search_backlog unit on a v5e (PERF.md,
+# section 6): a list tile costs one selection round's latency per kept
+# result (the rounds' dependent lane reductions, whatever the tile's
+# size) plus a share per (query, row) element once the tile is large.
+# The tile's copy and MXU product hide under the rounds.
+_LIST_ROUND_S = 0.29e-6
+_LIST_ELEM_S = 2.9e-12
+
+
+def choose_list_scan_blocks(pairs: int, k: int, width: int, d: int, l: int,
+                            *, dtype_bytes: int = 4,
+                            hw: Hardware = TPU_V5E) -> tuple[int, int]:
+    """Closed-form ``(G, B_W)`` for the list-major scan: the pair of least
+    modeled time that fits the VMEM budget.
+
+    The model counts segments as at most one per probed list plus one
+    per ``G`` (query, probe) pairs (``min(k, pairs) + pairs / G``: a
+    larger group re-streams hot lists less), tiles per segment as a list
+    of half the occupied ``width`` in ``B_W``-row tiles plus a ragged last
+    one, and each tile as ``l`` selection rounds of latency plus a cost
+    per element of the ``(G, B_W)`` tile. ``G`` is a sublane multiple up
+    to one MXU width (and no more than the pairs), ``B_W`` a candidate
+    tile no wider than the width; ties go to the smaller tiles.
+    """
+    budget = vmem_budget(hw)
+    l_pad = _round_up(max(1, l), hw.sublane)
+    w_lim = _round_up(max(1, width), hw.sublane)
+    pairs = max(1, int(pairs))
+    lists = min(max(1, int(k)), pairs)
+    best, best_cost = (hw.sublane, hw.sublane), None
+    g = hw.sublane
+    while g <= min(hw.mxu, _round_up(pairs, hw.sublane)):
+        segs = lists + pairs / g
+        for bw in (hw.sublane,) + _CANDIDATE_TILES:
+            if bw > w_lim and bw > hw.sublane:
+                continue
+            if list_scan_footprint(g, bw, l_pad, d, dtype_bytes) > budget:
+                continue
+            cost = (segs * (w_lim / 2 / bw + 0.5) * max(1, l)
+                    * (_LIST_ROUND_S + _LIST_ELEM_S * g * bw))
+            if best_cost is None or cost < best_cost:
+                best, best_cost = (g, bw), cost
+        g *= 2
+    return best
+
+
 def scan_q8_footprint(bb: int, bw: int, l: int, d: int) -> int:
     """VMEM bytes held live by one quantized grouped-scan grid step.
 

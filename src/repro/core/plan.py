@@ -48,16 +48,16 @@ from repro.kernels.ops import BlockConfig
 CACHE_VERSION = 2
 
 OPS = ("assign", "update", "step", "probe", "scan", "scan_q8", "route",
-       "rescore")
+       "rescore", "list_scan")
 
 _SHAPE_ARITY = {"assign": 3, "update": 3, "step": 3, "probe": 4, "scan": 4,
-                "scan_q8": 4, "route": 4, "rescore": 4}
+                "scan_q8": 4, "route": 4, "rescore": 4, "list_scan": 5}
 
 # which shape positions are batch-like (bucketed to the next power of
 # two); geometry dims (k, d, l) stay exact — they pin the VMEM footprint
 _BUCKET_DIMS = {"assign": (0,), "update": (0,), "step": (0,),
                 "probe": (0,), "scan": (0, 1), "scan_q8": (0, 1),
-                "route": (0,), "rescore": (0, 1)}
+                "route": (0,), "rescore": (0, 1), "list_scan": (0,)}
 
 _ITEMSIZE_DTYPE = {2: jnp.bfloat16, 4: jnp.float32, 8: jnp.float64}
 
@@ -121,7 +121,8 @@ class KernelPlan:
     """The planner's answer for one (op, shape bucket, dtype, hardware).
 
     ``blocks`` are the op's own two tile dims — ``(B_N, B_K)`` for the
-    shared-centroid kernels, ``(B_B, B_C)`` for the grouped scan. ``block``
+    shared-centroid kernels, ``(B_B, B_C)`` for the grouped scan,
+    ``(G, B_W)`` for the list-major scan. ``block``
     is the full ``BlockConfig`` (all three kmeans legs) for the ops that
     have one (``assign``/``update``/``step``); ``None`` for probe/scan.
     ``vmem_bytes`` is the audited working-set footprint at ``blocks`` and
@@ -217,7 +218,8 @@ class KernelPlanner:
         """Plan one kernel dispatch.
 
         ``shape``: ``(n, k, d)`` for assign/update/step, ``(n, k, d, l)``
-        for probe, ``(b, c, d, l)`` for scan. ``dtype`` may be a dtype or
+        for probe, ``(b, c, d, l)`` for scan, ``(b·nprobe, k, width, d,
+        l)`` for list_scan. ``dtype`` may be a dtype or
         a raw itemsize. ``blk`` pins an explicit ``BlockConfig`` (the
         plan is then judged — and cached — for those tiles, e.g. the
         fused-feasibility check at user-forced blocks). ``refine`` in
@@ -410,6 +412,22 @@ class KernelPlanner:
             return mk(impl="grouped_scan_q8", blocks=(bb, bw), block=None,
                       vmem_bytes=H.scan_q8_footprint(bb, bw, l_pad, d),
                       hbm_bytes=hbm)
+        if op == "list_scan":
+            # shape (b·nprobe, k, width, d, topk): the list-major scan's
+            # (G, B_W) tiles. Traffic: each probed list's rows once per
+            # segment, at most every list once plus one re-stream per
+            # extra segment, the (S, G, d) query groups in and the
+            # (S, G, L) slot/score pair out
+            p, k, w, d, l = s
+            g, bw = H.choose_list_scan_blocks(p, k, w, d, l, dtype_bytes=b,
+                                              hw=hw)
+            l_pad = _round_up(max(1, l), hw.sublane)
+            segs = -(-p // g) + min(k, p)
+            hbm = (min(k, p) + -(-p // g)) * w * d * b \
+                + segs * g * (d * b + 2 * l_pad * 4)
+            return mk(impl="list_major_scan", blocks=(g, bw), block=None,
+                      vmem_bytes=H.list_scan_footprint(g, bw, l_pad, d, b),
+                      hbm_bytes=float(hbm))
         if op == "rescore":
             # phase-2 exact verify fed by the device rescore cache:
             # shape (b, R, d, topk). Tiles are the plain grouped scan's

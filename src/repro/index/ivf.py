@@ -15,10 +15,17 @@ this repo already has:
   trick as ``kernels/sort_inverse_update.py`` (see DESIGN.md,
   "FlashIVF dataflow");
 - **probe** — ``ops.flash_probe`` (fused distance + online top-L) picks
-  the ``nprobe`` nearest coarse cells per query, and its grouped variant
-  ``ops.flash_probe_grouped`` scans each query tile against its own
-  gathered candidate blocks — the score matrix never exists in HBM at
-  either stage;
+  the ``nprobe`` nearest coarse cells per query;
+- **scan** — the flat fp32 search inverts the unit's (query, probe)
+  pairs into per-list query groups (the sort-inverse mapping once more:
+  a stable argsort by list id, runs cut into segments of ``G`` queries)
+  and ``ops.flash_scan_lists`` streams each probed list from the store
+  once per group, in place, through scalar-prefetched block indices; a
+  per-query top-k over the ``nprobe`` per-list results finishes it. No
+  ``(B, nprobe·width, d)`` candidate copy is written. The routed,
+  quantized, rescore and sharded searches still gather candidates and
+  scan them with the grouped variant ``ops.flash_probe_grouped``. The
+  score matrix never exists in HBM at any stage;
 - **online** — ``add`` assigns new vectors with FlashAssign, appends
   them to their lists in CSR batch order, and folds their sufficient
   statistics into the running per-cluster ``SufficientStats``
@@ -36,10 +43,11 @@ LRU eviction under a byte budget (resident memory ~ occupied pages, not
 sentinel coordinate so their distances are astronomically large but
 never NaN/inf inside the kernel's crossterm — they can only surface when
 a query probes fewer valid candidates than ``topk``, in which case the
-returned id is an honest ``-1``. Search gathers are capped at the
-store's *occupied* width (``gather_width``, a power-of-two bucket), so
-the candidate block — and the plan-cache key — track occupancy instead
-of physical capacity.
+returned id is an honest ``-1``. The list-major scan reads only each
+list's ``counts`` rows and reports such a slot the same way. Search
+gathers and the scan's tile count are capped at the store's *occupied*
+width (``gather_width``, a power-of-two bucket), so the work — and the
+plan-cache key — track occupancy instead of physical capacity.
 
 **Sharded FlashIVF** (``pctx`` — a ``core.parallel.ParallelContext``):
 cells are partitioned over the mesh's ``cells`` axis — each shard owns
@@ -142,40 +150,108 @@ def _train_sharded(pctx, cfg: KMeansConfig, key, x: Array
     return c, a[:n], m[:n]
 
 
+def _list_segments(probe: Array, g: int, k: int
+                  ) -> tuple[Array, Array, Array, Array]:
+    """Invert a unit's ``(B, nprobe)`` probe lists into per-list query
+    groups — the sort-inverse mapping applied to the scan.
+
+    The ``B·nprobe`` (query, probe) pairs are stably sorted by list id
+    and each list's run is cut into segments of at most ``g`` queries.
+    The segment count is static, ``ceil(B·nprobe/g) + min(k, B·nprobe)``
+    (every list run adds at most one partial segment); segments past the
+    real ones are padding. Returns ``(seg_list (S,), n_seg, qrow (S·g,),
+    slot (B·nprobe,))``: each segment's list id (0 on padding), the
+    number of real segments, the query each group row holds (query 0 on
+    padding rows, whose results are never read) and, per pair in
+    query-major order, its flat (segment, row) position.
+    """
+    b, nprobe = probe.shape
+    p = b * nprobe
+    s_max = -(-p // g) + min(k, p)
+    flat = probe.reshape(p)
+    order = jnp.argsort(flat, stable=True).astype(jnp.int32)
+    lists = flat[order]
+    pos = jnp.arange(p, dtype=jnp.int32)
+    new_run = jnp.concatenate([jnp.ones((1,), bool), lists[1:] != lists[:-1]])
+    in_run = pos - jax.lax.cummax(jnp.where(new_run, pos, 0))
+    seg = jnp.cumsum((in_run % g == 0).astype(jnp.int32)) - 1
+    row = seg * g + in_run % g
+    n_seg = seg[-1] + 1
+    seg_list = jnp.zeros((s_max,), jnp.int32).at[seg].set(lists)
+    qrow = jnp.zeros((s_max * g,), jnp.int32).at[row].set(order // nprobe)
+    slot = jnp.zeros((p,), jnp.int32).at[order].set(row)
+    return seg_list, n_seg, qrow, slot
+
+
+def _scan_lists(q: Array, probe: Array, counts: Array, store_arrays: tuple,
+                *, kind: str, topk: int, width: int, ps: int, nsh: int,
+                g: int, bw: int, interpret: bool | None
+                ) -> tuple[Array, Array]:
+    """List-major scan of a flat fp32 store: every probed list streams
+    from the store once per query group (``flash_scan_lists``) instead of
+    once per query through a gathered ``(B, nprobe·width, d)`` copy.
+
+    Each query then keeps its best ``topk`` of its ``nprobe`` per-list
+    results, in probe-rank order, so an exact tie goes to the lower
+    (rank, slot) position — the candidate-axis order of the gathered
+    block. Where a query's lists hold fewer than ``topk`` rows the slot
+    is id -1 at a padding row's (finite, astronomically large) distance.
+    """
+    b, nprobe = probe.shape
+    k = counts.shape[0]
+    with jax.named_scope("ivf.gather"):
+        seg_list, n_seg, qrow, slot = _list_segments(probe, g, k)
+        s_max = seg_list.shape[0]
+        seg_count = jnp.where(jnp.arange(s_max) < n_seg, counts[seg_list], 0)
+        qg = jnp.take(q, qrow, axis=0).reshape(s_max, g, q.shape[1])
+        payload, blocks, bw = _store.list_blocks(
+            kind, store_arrays, seg_list, bw, width, ps, nsh)
+    with jax.named_scope("ivf.scan"):
+        slots, score = ops.flash_scan_lists(qg, payload, seg_count, blocks,
+                                            l=topk, block_w=bw,
+                                            interpret=interpret)
+        l = score.shape[-1]
+        score = score.reshape(s_max * g, l)[slot].reshape(b, nprobe * l)
+        slots = slots.reshape(s_max * g, l)[slot].reshape(b, nprobe * l)
+        neg, pick = jax.lax.top_k(-score, topk)       # ties: lower position
+        lst = jnp.take_along_axis(probe, pick // l, axis=1)
+        ids = _store.slot_ids(kind, store_arrays, lst,
+                              jnp.take_along_axis(slots, pick, axis=1),
+                              ps, nsh)
+        filled = jnp.isfinite(neg)
+        q32 = q.astype(jnp.float32)
+        dist = jnp.maximum(
+            jnp.sum(q32 * q32, axis=-1, keepdims=True) - neg, 0.0)
+        pad = jnp.sum(jnp.square(q32 - _PAD_COORD), axis=-1, keepdims=True)
+        return jnp.where(filled, ids, -1), jnp.where(filled, dist, pad)
+
+
 @functools.partial(jax.jit, static_argnames=("kind", "topk", "nprobe",
                                              "width", "ps", "nsh", "bqn",
-                                             "bqk", "bsb", "bsc",
-                                             "interpret"))
-def _ivf_search(q: Array, centroids: Array, c_sq: Array,
+                                             "bqk", "g", "bw", "interpret"))
+def _ivf_search(q: Array, centroids: Array, c_sq: Array, counts: Array,
                 store_arrays: tuple, *,
                 kind: str, topk: int, nprobe: int, width: int, ps: int,
-                nsh: int, bqn: int, bqk: int, bsb: int, bsc: int,
+                nsh: int, bqn: int, bqk: int, g: int, bw: int,
                 interpret: bool | None) -> tuple[Array, Array]:
     """Batched two-stage IVF search, fully fused (one jit per geometry).
 
     Stage 1: FlashProbe over the coarse centroids -> (B, nprobe) cells
     (``c_sq`` is the index's cached ``||c||^2`` strip — no per-call
     norm recompute).
-    Stage 2: gather each probed cell's candidates through the store
-    (``gather_global`` — padded slice or page-table indirection), capped
-    at ``width`` occupied slots per cell, and scan each query against
-    its own ``nprobe * width`` block with the grouped probe kernel
-    (query tiles, one launch for the whole batch).
+    Stage 2: the list-major scan (``_scan_lists``): the unit's (query,
+    probe) pairs inverted into per-list query groups, each probed list
+    read in place from the store (padded tiles or pages, up to its
+    ``counts`` rows) once per group, then a per-query top-k merge.
     """
     with jax.named_scope("ivf.probe"):
         probe, _ = ops.flash_probe(q, centroids.astype(q.dtype), l=nprobe,
                                    block_n=bqn, block_k=bqk,
                                    interpret=interpret, want_dists=False,
                                    c_sq=c_sq)
-    with jax.named_scope("ivf.gather"):
-        cand_x, cand_ids = _store.gather_global(kind, store_arrays, probe,
-                                                width, ps, nsh)
-    with jax.named_scope("ivf.scan"):
-        li, dist = ops.flash_probe_grouped(q, cand_x, l=topk,
-                                           block_b=bsb, block_c=bsc,
-                                           interpret=interpret)  # (B, topk)
-        ids = jnp.take_along_axis(cand_ids, li, axis=1)
-    return ids, dist
+    return _scan_lists(q, probe, counts, store_arrays, kind=kind, topk=topk,
+                       width=width, ps=ps, nsh=nsh, g=g, bw=bw,
+                       interpret=interpret)
 
 
 def _route_cells(q: Array, centroids: Array, coarse: Array,
@@ -1064,14 +1140,16 @@ class IVFIndex:
                     nprobe_c: int | None = None) -> tuple[int, ...]:
         """Plan (and cache) the search-stage kernels for a geometry.
 
-        Returns ``(bqn, bqk, bsb, bsc)`` — probe and scan tiles for a
-        ``(b, d)`` query batch at this index's current ``(k, width)``,
-        where ``width`` is the store's occupied gather width (a
-        power-of-two bucket — occupancy growth changes the candidate
-        block and naturally re-keys). The plan is cached on the index per
-        ``(b, nprobe, topk, width)``, so the per-call chooser recompute
-        this method replaces can never return to the hot path. Serving
-        layers with a fixed padded batch shape
+        Returns ``(bqn, bqk, g, bw)`` — probe tiles and the list-major
+        scan's ``(G, B_W)`` for a ``(b, d)`` query batch at this index's
+        current ``(k, width)``, where ``width`` is the store's occupied
+        gather width (a power-of-two bucket — occupancy growth changes
+        the scan's tile count and naturally re-keys); the paths that
+        still gather candidates (routed, q8, sharded) take the grouped
+        scan's ``(bsb, bsc)`` in its place. The plan is cached on the
+        index per ``(b, nprobe, topk, width)``, so the per-call chooser
+        recompute this method replaces can never return to the hot path.
+        Serving layers with a fixed padded batch shape
         (``serve.engine.SearchEngine``) call this once at config time.
 
         Under the two-level router the flat probe gives way to a
@@ -1145,6 +1223,11 @@ class IVFIndex:
                 rescore = self.planner.plan(
                     rop, (int(b), r, self.d, min(topk, r)), jnp.float32)
                 plans = (*head, *q8.blocks, *rescore.blocks)
+            elif self._list_major():
+                scan = self.planner.plan(
+                    "list_scan", (b * nprobe, self.k, width, self.d, topk),
+                    dt)
+                plans = (*head, *scan.blocks)
             else:
                 scan = self.planner.plan("scan", scan_shape, dt)
                 plans = (*head, *scan.blocks)
@@ -1187,8 +1270,10 @@ class IVFIndex:
                             f"injected replica death ({ev})")
         if obs.enabled():
             obs.count("ivf.units")
+            if self._list_major():
+                obs.count("ivf.list_scan_units")
             obs.count("ivf.gathered_rows", self._gathered_rows(
-                q.shape[0], nprobe, self._gather_width(topk, nprobe)))
+                q.shape[0], topk, nprobe))
         if self.store.codec_kind != "fp32":
             return self._search_q8(q, topk, nprobe, shard_ok=shard_ok,
                                    nprobe_c=nprobe_c)
@@ -1211,20 +1296,37 @@ class IVFIndex:
                 ps=st.page_param, nsh=st.n_shards, bcn=bcn, bck=bck,
                 bfb=bfb, bfc=bfc, bsb=bsb, bsc=bsc,
                 interpret=self.interpret)
-        bqn, bqk, bsb, bsc = self.plan_search(q.shape[0], topk, nprobe)
+        bqn, bqk, g, bw = self.plan_search(q.shape[0], topk, nprobe)
         return _ivf_search(q, self.centroids, self._centroid_norms(),
-                           st.device_arrays(),
+                           st.counts, st.device_arrays(),
                            kind=st.kind, topk=topk, nprobe=nprobe,
                            width=width,
                            ps=st.page_param, nsh=st.n_shards,
-                           bqn=bqn, bqk=bqk, bsb=bsb, bsc=bsc,
+                           bqn=bqn, bqk=bqk, g=g, bw=bw,
                            interpret=self.interpret)
 
-    def _gathered_rows(self, b: int, nprobe: int, width: int) -> int:
+    def _list_major(self) -> bool:
+        """Whether search takes the list-major scan (flat router, fp32
+        payload, one device); the other paths gather candidates."""
+        return (self.store.codec_kind == "fp32" and not self._k_sharded
+                and self.router.kind != "two_level")
+
+    def _gathered_rows(self, b: int, topk: int, nprobe: int) -> int:
         """Candidate rows one search call of ``b`` queries gathers, over
-        all devices: ``nprobe`` lists of ``width`` slots per query, or,
+        all devices: ``nprobe`` lists of the gather width per query, or,
         on a cells-sharded mesh, the ``min(nprobe, K_local)`` owned lists
-        each K-shard gathers for every query of the data-padded batch."""
+        each K-shard gathers for every query of the data-padded batch.
+        The list-major scan gathers no candidates; its count is a bound on
+        the store rows it reads, every tile of the width for every
+        segment: it reads only each list's rows, and nothing for padding
+        segments."""
+        width = self._gather_width(topk, nprobe)
+        if self._list_major():
+            _, _, g, bw = self.plan_search(b, topk, nprobe)
+            bw = self.store.page_param or min(bw, self.store.capacity)
+            p = b * nprobe
+            segs = -(-p // g) + min(self.k, p)
+            return segs * -(-width // bw) * bw
         if not self._k_sharded:
             return b * nprobe * width
         pctx = self.pctx

@@ -273,6 +273,40 @@ def gather_cells_q8(kind: str, arrays, cell: Array, width: int,
             pool_ids[pid].reshape(bl, w))
 
 
+def list_blocks(kind: str, arrays, seg_list: Array, block_w: int,
+                width: int, page_size: int, n_shards: int
+                ) -> tuple[Array, Array, int]:
+    """The list-major scan's view of a whole (unsharded) fp32 store: where
+    each segment's list ``seg_list[s]`` lives. Returns ``(payload,
+    blocks, block_w)`` for ``ops.flash_scan_lists``. Padded: ``payload``
+    is the ``(K, cap, d)`` tensor, ``blocks`` the lists themselves (a
+    list is contiguous rows), read in tiles of ``block_w`` rows (at most
+    ``cap``). Paged: a tile is one page, ``blocks (S, W)`` the pool pages
+    of each list's first ``W = width / page_size`` table entries."""
+    if kind == "padded":
+        payload = arrays[0]
+        return payload, seg_list, min(block_w, payload.shape[1])
+    payload, _, tables = arrays
+    pps = payload.shape[0] // n_shards
+    cps = tables.shape[0] // n_shards
+    lst = seg_list[:, None]
+    page = (lst // cps) * pps + tables[lst, jnp.arange(width // page_size)]
+    return payload, page, page_size
+
+
+def slot_ids(kind: str, arrays, lists: Array, slots: Array,
+             page_size: int, n_shards: int) -> Array:
+    """Global ids of the rows at ``slots`` of ``lists`` (same shapes) on a
+    whole (unsharded) store."""
+    if kind == "padded":
+        return arrays[1][lists, slots]
+    pool, pool_ids, tables = arrays[:3]
+    pps = pool.shape[0] // n_shards
+    cps = tables.shape[0] // n_shards
+    pid = (lists // cps) * pps + tables[lists, slots // page_size]
+    return pool_ids[pid, slots % page_size]
+
+
 # ---------------------------------------------------------------------------
 # the store contract
 # ---------------------------------------------------------------------------

@@ -1,14 +1,18 @@
 """FlashProbe — fused distance + online top-L (Pallas TPU).
 
 FlashAssign generalized from the online *argmin* to an online *L-best*
-selection: the IVF search primitive. Two call sites in the index
-subsystem share this one kernel:
+selection: the IVF search primitive. The index subsystem calls it in
+three forms, all sharing one selection routine:
 
 - **nprobe centroid selection** — queries against the (K, d) coarse
   centroid set, L = nprobe;
-- **batched posting-list scan** — the grouped variant below: query
-  tiles, each query scored against its own gathered (nprobe·cap, d)
-  candidate block, L = topk.
+- **list-major posting-list scan** (``flash_scan_lists``) — a group of
+  queries that probe the same list against that list's rows, streamed
+  in place from the store through scalar-prefetched block indices,
+  L = topk (the flat fp32 search);
+- **gathered posting-list scan** — the grouped variants: query tiles,
+  each query scored against its own gathered (nprobe·cap, d) candidate
+  block, L = topk (routed, quantized, rescore and sharded searches).
 
 Structure mirrors FlashAssign: grid ``(Q_tiles, K_tiles)`` with K
 minor-most, so the running ``(vals, idxs)`` L-best state lives in VMEM
@@ -221,6 +225,152 @@ def _flash_probe_grouped_q8_kernel(q_ref, c_ref, s_ref, i_ref, v_ref,
     def _flush():
         i_ref[...] = i_scr[...]
         v_ref[...] = v_scr[...]
+
+
+def _flash_scan_lists_kernel(cnt_ref, base_ref, nxt_ref, blk_ref, q_ref,
+                             c_hbm, i_ref, v_ref, buf, sem, *, block_w: int,
+                             n_w: int, l: int, paged: bool, mxu: bool):
+    """One segment of the list-major scan: one grid step.
+
+    A segment is up to ``G`` queries that probe the same posting list.
+    The step walks the list's ``ceil(cnt/B_W)`` tiles in slot order, each
+    copied once from the store (``c_hbm``, left in HBM) for the whole
+    group, so a padding segment (``cnt`` 0) reads and computes nothing.
+    Copies are double-buffered through ``buf`` across tiles *and*
+    segments: the last tile of a segment starts the copy of the first
+    tile of the next segment that has rows (``nxt_ref``), into the slot
+    that segment's running tile count (``base_ref``) gives.
+
+    Where tile ``t`` lives: ``paged``, it is the whole page
+    ``c_hbm[blk[s·W + t]]``; otherwise the list is contiguous rows of
+    ``c_hbm[blk[s]]`` and the tile is rows ``[t·B_W, (t+1)·B_W)``, drawn
+    back to end at the last row where they would run past it (its
+    leading rows, already scanned, are masked). Rows past ``cnt`` are
+    masked to ``+inf``.
+
+    On the chip (``mxu``) the cross term is one ``(G, d)·(d, B_W)`` MXU
+    product at full f32 precision and ``||c||^2`` is reduced once per
+    tile from the same transposed tile. The interpreter computes both as
+    per-element f32 multiply-reduces over ``d``, the grouped scan's own
+    arithmetic: XLA's CPU dot rounds differently with the tile width,
+    this form does not, so CPU results are independent of ``B_W``
+    (padded tiles and pages agree) and equal the gathered scan's bit for
+    bit. Selection and tie rules are the shared kernel's: within a
+    segment the lower slot wins.
+    """
+    s = pl.program_id(0)
+    n_seg = pl.num_programs(0)
+    rows = c_hbm.shape[1]
+    n = cnt_ref[s]
+    nt = (n + block_w - 1) // block_w
+    base = base_ref[s]
+    nxt = nxt_ref[s]
+
+    def source(seg, t):
+        """(payload block, first row copied, rows of it already seen)."""
+        if paged:
+            return blk_ref[seg * n_w + t], 0, 0
+        start = pl.multiple_of(jnp.minimum(t * block_w, rows - block_w), 8)
+        return blk_ref[seg], start, t * block_w - start
+
+    def copy(seg, t, slot):
+        blk, start, _ = source(seg, t)
+        return pltpu.make_async_copy(c_hbm.at[blk, pl.ds(start, block_w)],
+                                     buf.at[slot], sem.at[slot])
+
+    @pl.when((nt > 0) & (base == 0))      # no earlier segment prefetched it
+    def _first():
+        copy(s, 0, 0).start()
+
+    q = q_ref[...].astype(jnp.float32)               # (G, d)
+    g = q.shape[0]
+    lane = jax.lax.broadcasted_iota(jnp.int32, (g, block_w), 1)
+
+    def tile(t, carry):
+        pv, pi = carry
+        slot = (base + t) % 2
+        copy(s, t, slot).wait()
+
+        @pl.when(t + 1 < nt)
+        def _next_tile():
+            copy(s, t + 1, 1 - slot).start()
+
+        @pl.when((t + 1 == nt) & (nxt < n_seg))
+        def _next_segment():
+            copy(nxt, 0, 1 - slot).start()
+
+        c = buf[slot].astype(jnp.float32)            # (bw, d)
+        if mxu:
+            ct = c.T                                 # (d, bw)
+            cross = jax.lax.dot_general(
+                q, ct, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32,
+                precision=matmul_precision(jnp.float32))
+            csq = jnp.sum(ct * ct, axis=0, keepdims=True)   # (1, bw)
+        else:
+            cross = jnp.sum(q[:, None, :] * c[None, :, :], axis=-1)
+            csq = jnp.sum(c * c, axis=-1)[None, :]
+        seen = source(s, t)[2]
+        slots = t * block_w - seen + lane
+        keep = (lane >= seen) & (slots < n)
+        score = jnp.where(keep, csq - 2.0 * cross, _INF)
+        return _select_l_best(pv, pi, score, slots, l)
+
+    pv, pi = jax.lax.fori_loop(
+        0, nt, tile, (jnp.full((g, l), _INF, jnp.float32),
+                      jnp.zeros((g, l), jnp.int32)))
+    v_ref[...] = pv
+    i_ref[...] = pi
+
+
+def flash_scan_lists_raw(qg: Array, payload: Array, seg_count: Array,
+                         seg_base: Array, seg_next: Array, blocks: Array, *,
+                         l: int, block_w: int, paged: bool,
+                         interpret: bool = False) -> tuple[Array, Array]:
+    """Pallas call of the list-major scan, one grid step per segment.
+
+    qg: (S, G, d) query groups; payload: the store's row blocks
+    ``(A, R, d)``, ``B_W <= R``, left in HBM and copied tile by tile;
+    seg_count: (S,) int32 real rows of each segment's list (0 on padding
+    segments); seg_base: (S,) int32 tiles of all earlier segments;
+    seg_next: (S,) int32 the next segment with rows (``S`` if none);
+    blocks: int32, ``(S,)`` the payload block holding each segment's
+    list as contiguous rows, or with ``paged`` ``(S·W,)`` the payload
+    block (one page, ``B_W = R``) of each (segment, tile). Returns
+    ``(slots int32 (S, G, l), scores f32 (S, G, l))`` ascending by
+    (score, slot); slots index the list's own rows.
+    """
+    s_n, g, d = qg.shape
+    kernel = functools.partial(
+        _flash_scan_lists_kernel, block_w=block_w,
+        n_w=blocks.shape[0] // s_n, l=l, paged=paged, mxu=not interpret)
+    return pl.pallas_call(
+        kernel,
+        name="flash_scan_lists",
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=4,
+            grid=(s_n,),
+            in_specs=[
+                pl.BlockSpec((None, g, d), lambda s, *_: (s, 0, 0)),
+                pl.BlockSpec(memory_space=pl.ANY),
+            ],
+            out_specs=[
+                pl.BlockSpec((None, g, l), lambda s, *_: (s, 0, 0)),
+                pl.BlockSpec((None, g, l), lambda s, *_: (s, 0, 0)),
+            ],
+            scratch_shapes=[
+                pltpu.VMEM((2, block_w, d), payload.dtype),
+                pltpu.SemaphoreType.DMA((2,)),
+            ]),
+        out_shape=[
+            jax.ShapeDtypeStruct((s_n, g, l), jnp.int32),
+            jax.ShapeDtypeStruct((s_n, g, l), jnp.float32),
+        ],
+        # the copies chain from one segment to the next: steps in order
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=interpret,
+    )(seg_count, seg_base, seg_next, blocks, qg, payload)
 
 
 def flash_probe_grouped_q8_raw(qp: Array, codes: Array, scales: Array, *,

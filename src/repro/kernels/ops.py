@@ -476,6 +476,59 @@ def flash_probe_grouped_q8(qp: Array, codes: Array, scales: Array, *,
     return idx, v
 
 
+@functools.partial(jax.jit, static_argnames=("l", "block_w", "interpret"))
+def flash_scan_lists(qg: Array, payload: Array, seg_count: Array,
+                     blocks: Array, *, l: int, block_w: int,
+                     interpret: bool | None = None) -> tuple[Array, Array]:
+    """List-major posting-list scan: each segment's query group against
+    its list's rows, streamed from the store in place.
+
+    qg: (S, G, d) query groups; payload: (A, R, d) store row blocks,
+    ``block_w <= R``; seg_count: (S,) int32 real rows of each segment's
+    list, 0 on padding segments; blocks: int32 where each list lives, as
+    ``index.store.list_blocks`` lays it out — ``(S,)``: segment ``s``'s
+    list is contiguous rows of ``payload[blocks[s]]``, read in tiles of
+    ``block_w`` rows; ``(S, W)``: tile ``t`` is the whole block
+    ``payload[blocks[s, t]]`` (a page, ``block_w == R``). ``G`` and
+    ``block_w`` come from the planner's ``list_scan`` op (the caller
+    builds the groups, so neither is re-chosen here). Returns ``(slots
+    int32 (S, G, l), scores f32 (S, G, l))`` ascending by (score, slot),
+    the score ``||c||^2 - 2 q.c`` (no ``||q||^2``); where a list holds
+    fewer than ``l`` rows the rest score ``+inf``.
+    """
+    if interpret is None:
+        interpret = default_interpret()
+    if l < 1:
+        raise ValueError(f"flash_scan_lists needs l >= 1, got l={l}")
+    s_n, g, d = qg.shape
+    paged = blocks.ndim == 2
+    if (blocks.shape[0] != s_n or block_w > payload.shape[1]
+            or (paged and block_w != payload.shape[1])):
+        raise ValueError(
+            f"flash_scan_lists: blocks {blocks.shape} for {s_n} segments, "
+            f"tile {block_w} over blocks of {payload.shape[1]} rows")
+    from repro.core import heuristics as H
+    from repro.core import plan as _planmod
+    hw = _planmod.hardware_by_name(None)
+    need = H.list_scan_footprint(g, block_w, _round_up(l, 8), d,
+                                 payload.dtype.itemsize)
+    if need > hw.vmem_bytes:
+        raise ValueError(
+            f"list scan tiles (G={g}, B_W={block_w}) need {need} bytes of "
+            f"{hw.name} VMEM ({hw.vmem_bytes}) at d={d}")
+    seg_count = seg_count.astype(jnp.int32)
+    tiles = (seg_count + block_w - 1) // block_w
+    base = (jnp.cumsum(tiles) - tiles).astype(jnp.int32)
+    # the next segment with rows: a reversed running minimum of their ids
+    ids = jnp.where(tiles > 0, jnp.arange(s_n, dtype=jnp.int32), s_n)
+    ahead = jax.lax.cummin(ids, reverse=True)
+    nxt = jnp.concatenate([ahead[1:], jnp.full((1,), s_n, jnp.int32)])
+    return _fp.flash_scan_lists_raw(
+        qg, payload, seg_count, base, nxt,
+        blocks.reshape(-1).astype(jnp.int32), l=l, block_w=block_w,
+        paged=paged, interpret=interpret)
+
+
 # ---------------------------------------------------------------------------
 # Batched variants + centroid update convenience
 # ---------------------------------------------------------------------------

@@ -166,3 +166,124 @@ def test_scan_blocks_fit_budget_and_shape():
         budget = int(heuristics.TPU_V5E.vmem_bytes * 0.7)
         l_pad = ((max(1, l) + 7) // 8) * 8
         assert heuristics.scan_footprint(bb, bc, l_pad, d, 4) <= budget
+
+
+
+@pytest.mark.parametrize("pairs,k,width,d,l", [
+    (32, 1024, 3000, 128, 10), (4096, 1024, 3000, 128, 10),
+    (32768, 1024, 4096, 128, 10), (64, 8, 40, 16, 5),
+    (1 << 20, 256, 65536, 512, 100)])
+def test_list_scan_blocks_fit_budget(pairs, k, width, d, l):
+    """``G`` is a sublane multiple, at most one MXU width and no more than
+    the pairs; ``B_W`` a tile no wider than the width; both fit the
+    planner's VMEM budget; one query (32 pairs over 1,024 lists) keeps
+    the smallest group."""
+    g, bw = heuristics.choose_list_scan_blocks(pairs, k, width, d, l)
+    assert g % 8 == 0 and 8 <= g <= min(128, max(8, pairs))
+    assert bw == 8 or bw in heuristics._CANDIDATE_TILES
+    assert bw <= max(8, -(-width // 8) * 8)
+    budget = int(heuristics.TPU_V5E.vmem_bytes * 0.7)
+    l_pad = ((max(1, l) + 7) // 8) * 8
+    assert heuristics.list_scan_footprint(g, bw, l_pad, d, 4) <= budget
+    if pairs <= 32:
+        assert g == 8
+
+# --- list-major scan (probed lists streamed from the store) ---------------
+
+_PAD = 1e15
+
+
+def _list_store(counts, cap, d, seed, dup=False):
+    """A padded store laid out as ``index.store`` lays it out: list ``j``
+    holds ``counts[j]`` rows in slots ``[0, counts[j])``, padding rows at
+    the far sentinel with id -1. ``dup`` repeats one row across slots and
+    lists, so scores tie exactly."""
+    rng = np.random.default_rng(seed)
+    k = len(counts)
+    x = np.full((k, cap, d), _PAD, np.float32)
+    ids = np.full((k, cap), -1, np.int32)
+    nxt = 0
+    row = rng.normal(size=d).astype(np.float32)
+    for j, n in enumerate(counts):
+        x[j, :n] = rng.normal(size=(n, d)) * 2.0
+        if dup:
+            x[j, :n:2] = row
+        ids[j, :n] = np.arange(nxt, nxt + n)
+        nxt += n
+    return x, ids
+
+
+def _probed_topk_oracle(q, probe, x, ids, counts, topk):
+    """Brute force over each query's probed lists, in (rank, slot) order:
+    float64 distances, exact ties to the lower position; -1 where the
+    lists hold fewer than ``topk`` rows."""
+    out_i = np.full((len(q), topk), -1, np.int64)
+    out_d = np.full((len(q), topk), np.inf)
+    for i, lists in enumerate(probe):
+        cand = [(j, s) for j in lists for s in range(counts[j])]
+        dist = np.array([np.sum((x[j, s].astype(np.float64) - q[i]) ** 2)
+                         for j, s in cand])
+        order = np.argsort(dist, kind="stable")[:topk]
+        out_i[i, :len(order)] = [ids[cand[o]] for o in order]
+        out_d[i, :len(order)] = dist[order]
+    return out_i, out_d
+
+
+# (B, K, counts, cap, nprobe, topk, G, B_W, hot list, duplicate rows)
+LIST_SCAN = {
+    "ragged-empty-lists": (10, 12, [0, 5, 37, 0, 12, 1, 30, 0, 8, 22, 3, 16],
+                           40, 4, 5, 8, 16, False, False),
+    "hot-list-over-G": (21, 8, [9, 14, 30, 2, 17, 25, 6, 11],
+                        32, 3, 6, 8, 8, True, False),
+    "width-not-tile-multiple": (6, 5, [37, 21, 40, 3, 29],
+                                40, 2, 7, 8, 16, False, False),
+    "one-query": (1, 9, [4, 17, 0, 26, 9, 13, 2, 31, 8],
+                  32, 4, 10, 8, 8, False, False),
+    "exact-ties": (7, 6, [12, 9, 16, 7, 14, 10],
+                   16, 3, 8, 8, 8, False, True),
+    "fewer-rows-than-topk": (5, 6, [2, 0, 1, 3, 0, 2],
+                             8, 3, 6, 8, 8, False, False),
+}
+
+
+@pytest.mark.parametrize("case", list(LIST_SCAN))
+def test_list_scan_matches_probed_brute_force(case):
+    """``ivf._scan_lists`` (inversion into query groups, the
+    ``flash_scan_lists`` kernel, the per-query merge) against brute force
+    over each query's probed lists: ragged and empty lists, a hot list
+    probed by more than ``G`` queries (several segments), a width that is
+    not a multiple of the tile, one query, exact ties (lower (rank, slot)
+    wins) and lists with fewer rows than ``topk`` (id -1)."""
+    from repro.index import ivf
+    b, k, counts, cap, nprobe, topk, g, bw, hot, dup = LIST_SCAN[case]
+    d = 16
+    rng = np.random.default_rng(len(case))
+    x, ids = _list_store(counts, cap, d, seed=b + k, dup=dup)
+    if hot:     # every query probes list 0 first
+        probe = np.stack([np.r_[0, 1 + rng.permutation(k - 1)[:nprobe - 1]]
+                          for _ in range(b)])
+        assert b > g
+    else:
+        probe = np.stack([rng.permutation(k)[:nprobe] for _ in range(b)])
+    q = (rng.normal(size=(b, d)) * 2.0).astype(np.float32)
+    if dup:
+        q[:] = q[:1]
+    width = cap
+    got_i, got_d = ivf._scan_lists(
+        jnp.asarray(q), jnp.asarray(probe, jnp.int32),
+        jnp.asarray(counts, jnp.int32),
+        (jnp.asarray(x), jnp.asarray(ids)), kind="padded", topk=topk,
+        width=width, ps=0, nsh=1, g=g, bw=bw, interpret=True)
+    got_i, got_d = np.asarray(got_i), np.asarray(got_d)
+    ref_i, ref_d = _probed_topk_oracle(q, probe, x, ids, counts, topk)
+    filled = ref_i >= 0
+    assert np.array_equal(got_i < 0, ~filled)
+    # an unfilled slot reads as a padding row: finite, astronomically far
+    assert np.all(np.isfinite(got_d)) and np.all(got_d[~filled] > 1e29)
+    if dup:
+        assert np.array_equal(got_i, ref_i)
+    tol = float(f32_score_tol(jnp.asarray(q), jnp.asarray(
+        x[x[:, :, 0] < _PAD])))
+    assert_topk_match(np.where(filled, got_i, -1), np.where(filled, got_d, 0),
+                      np.where(filled, ref_i, -1), np.where(filled, ref_d, 0),
+                      tol=tol, rtol=0)

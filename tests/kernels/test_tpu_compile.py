@@ -17,6 +17,7 @@ The compiled HLO also carries the names a device trace is read by: each
 kernel's explicit ``pallas_call`` name, and the ``ivf.probe``,
 ``ivf.gather`` and ``ivf.scan`` scopes of the search program.
 """
+import math
 import re
 import warnings
 
@@ -73,6 +74,20 @@ CASES = {
         [((128, 32, 128), F32), ((128, 32, 2048, 128), I8),
          ((128, 32, 2048), F32)],
         {"l": 40}),
+    # list-major scan at the search_backlog unit: 128 x 32 pairs over
+    # K=1024 lists in 1,280 segments of G=16, a padded (1024, 3000, 128)
+    # store in 2048-row tiles, and the same unit over a paged pool of
+    # 64-row pages (47 per segment)
+    "lists-S1280-G16-padded-W3000-l10": (
+        "flash_scan_lists",
+        [((1280, 16, 128), F32), ((1024, 3000, 128), F32), ((1280,), I32),
+         ((1280,), I32)],
+        {"l": 10, "block_w": 2048}),
+    "lists-S1280-G16-paged-P64-l10": (
+        "flash_scan_lists",
+        [((1280, 16, 128), F32), ((16384, 64, 128), F32), ((1280,), I32),
+         ((1280, 47), I32)],
+        {"l": 10, "block_w": 64}),
 }
 
 
@@ -136,6 +151,10 @@ NAMED = {
         "flash_probe_grouped_q8",
         [((128, 32, 128), F32), ((128, 32, 64, 128), I8),
          ((128, 32, 64), F32)], {"l": 40}),
+    "flash_scan_lists": (
+        "flash_scan_lists",
+        [((64, 8, 128), F32), ((32, 512, 128), F32), ((64,), I32),
+         ((64,), I32)], {"l": 10, "block_w": 256}),
 }
 
 
@@ -164,23 +183,32 @@ def test_kernel_hlo_carries_its_name(kernel, one_chip, no_compile_cache):
 
 
 def test_ivf_search_hlo_carries_stage_scopes(one_chip, no_compile_cache):
+    """The flat fp32 search program names its three stages, runs the
+    probe and the list-major scan kernels, and holds no gathered
+    ``(B, nprobe·width, d)`` candidate block: every f32 buffer in it is
+    smaller than that block would be."""
     from repro.core import plan as _plan
     from repro.index import ivf
     b, k, d, cap, nprobe, topk = 128, 1024, 128, 256, 32, 10
     planner = _plan.default_planner()
     bqn, bqk = planner.plan("probe", (b, k, d, nprobe), F32).blocks
-    bsb, bsc = planner.plan("scan", (b, nprobe * cap, d, topk), F32).blocks
+    g, bw = planner.plan("list_scan", (b * nprobe, k, cap, d, topk),
+                         F32).blocks
     args = [jax.ShapeDtypeStruct(s, dt, sharding=one_chip) for s, dt in
-            [((b, d), F32), ((k, d), F32), ((k,), F32)]]
+            [((b, d), F32), ((k, d), F32), ((k,), F32), ((k,), I32)]]
     store = tuple(jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
                   for s, dt in [((k, cap, d), F32), ((k, cap), I32)])
     hlo = ivf._ivf_search.lower(
         *args, store, kind="padded", topk=topk, nprobe=nprobe, width=cap,
-        ps=0, nsh=1, bqn=bqn, bqk=bqk, bsb=bsb, bsc=bsc,
+        ps=0, nsh=1, bqn=bqn, bqk=bqk, g=g, bw=bw,
         interpret=False).compile().as_text()
     op_names = re.findall(r'op_name="([^"]*)"', hlo)
     for stage in ("ivf.probe", "ivf.gather", "ivf.scan"):
         assert any(f"/{stage}/" in n for n in op_names), stage
     names = _custom_call_names(hlo)
     assert sorted(n.split(".")[0] for n in names) == [
-        "flash_probe", "flash_probe_grouped"], names
+        "flash_probe", "flash_scan_lists"], names
+    block = b * nprobe * cap * d
+    sizes = [math.prod(int(v) for v in dims.split(",") if v)
+             for dims in re.findall(r"\bf32\[([0-9,]*)\]", hlo)]
+    assert sizes and max(sizes) < block, max(sizes)

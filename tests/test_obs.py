@@ -185,13 +185,18 @@ def test_request_spans_share_its_rid_in_order(corpus, tracing):
     assert parents["engine.pump"] == "engine.take"
 
 
-@pytest.mark.parametrize("index_kw", [{}, {"codec": "q8"},
-                                      {"store": "paged", "page_size": 8}],
-                         ids=["padded-fp32", "padded-q8", "paged-fp32"])
-def test_gathered_rows_per_unit(corpus, tracing, index_kw):
+@pytest.mark.parametrize("index_kw,list_major", [
+    ({}, True), ({"codec": "q8"}, False),
+    ({"store": "paged", "page_size": 8}, True)],
+    ids=["padded-fp32", "padded-q8", "paged-fp32"])
+def test_gathered_rows_per_unit(corpus, tracing, index_kw, list_major):
+    """The q8 path gathers nprobe lists of the gather width per query;
+    the fp32 paths take the list-major scan, count its units, and count
+    the store rows its grid addresses (segments x the width's tiles)."""
     x, q = corpus
     eng = _engine(x, **index_kw)
-    width = eng.index._gather_width(5, 4)
+    idx = eng.index
+    width = idx._gather_width(5, 4)
     tracing.reset()
     sizes = [32, 7, 20]                 # buckets 32, 8, 32
     for n in sizes:
@@ -199,7 +204,18 @@ def test_gathered_rows_per_unit(corpus, tracing, index_kw):
     assert [s[4]["bucket"] for s in _spans("engine.form")] == [32, 8, 32]
     counters = tracing.snapshot()["counters"]
     assert counters["ivf.units"] == 3
-    assert counters["ivf.gathered_rows"] == (32 + 8 + 32) * 4 * width
+    if not list_major:
+        assert "ivf.list_scan_units" not in counters
+        assert counters["ivf.gathered_rows"] == (32 + 8 + 32) * 4 * width
+        return
+    assert counters["ivf.list_scan_units"] == 3
+    rows = 0
+    for b in (32, 8, 32):
+        _, _, g, bw = idx.plan_search(b, 5, 4)
+        bw = index_kw.get("page_size") or min(bw, idx.cap)
+        segs = -(-b * 4 // g) + min(K, b * 4)
+        rows += segs * -(-width // bw) * bw
+    assert counters["ivf.gathered_rows"] == rows
 
 
 @pytest.mark.parametrize("step_impl,scopes", [
