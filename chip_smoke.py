@@ -18,10 +18,11 @@ One-chip phases (data generated on the device from ``--seed``):
 4. search, quantized path: the same corpus on the paged store with q8
    codes, the device rescore cache and the two-level router.
 
-``--four-chips`` runs the two paths that exist only across chips, each
-next to its single-device twin: the cells-sharded index on a 1x4 mesh
-(ids identical to one device's) and the K-sharded fit (centroids close to
-one device's).
+``--four-chips`` runs the paths that exist only across chips, each next
+to its single-device twin: the cells-sharded index on a 1x4 mesh (ids
+identical to one device's), the K-sharded fit (centroids close to one
+device's) and the N-sharded step of ``KMeans(cfg, mesh)`` on a 4x1 mesh
+(its first step checked as the K-sharded one is).
 
 Every check raises on failure, so the exit code is non-zero. Without a
 TPU the script fails before any phase and prints no result. The last
@@ -360,15 +361,47 @@ def bytes_per_device(label: str, index=None) -> list[int]:
     return held
 
 
+def check_first_step(label: str, x, c0, a1, c1, a1_ref, c1_ref) -> None:
+    """A sharded first Lloyd step ``(c1, a1)`` against one device's
+    ``(c1_ref, a1_ref)`` from the same centroids ``c0``: assignments equal
+    up to near-ties, centroids the reference update of the step's own
+    assignments, and one device's where no near-tie swap touched them."""
+    import jax
+    import numpy as np
+    from repro.kernels import ops, ref
+    n, k = x.shape[0], c0.shape[0]
+    a1 = jax.device_put(a1, x.sharding)
+    da, db = (np.asarray(v) for v in _pair_dists(x, c0, a1, a1_ref))
+    diff = np.asarray(a1) != np.asarray(a1_ref)
+    tol = 8 * np.finfo(np.float32).eps * float(np.max(db) + 1.0)
+    print(f"  first step: {int(diff.sum())} of {n} assignments differ from "
+          "one device's", flush=True)
+    check(not np.any(diff & (np.abs(da - db) > tol)),
+          f"{label} first-step assignments match one device's up to "
+          f"near-ties ({tol:.3g})")
+    s_r, cnt_r = jax.jit(ref.update_scatter_ref, static_argnums=2)(x, a1, k)
+    c1_r = ops.finalize_centroids(s_r, cnt_r, c0)
+    c1, c1_ref = np.asarray(c1), np.asarray(c1_ref)
+    check(np.allclose(c1, np.asarray(c1_r), rtol=1e-5, atol=1e-4),
+          f"{label} first-step centroids allclose to the reference update "
+          "of its assignments (rtol 1e-5, atol 1e-4)")
+    moved = np.zeros(k, bool)     # centroids a near-tie swap touched
+    moved[np.asarray(a1)[diff]] = moved[np.asarray(a1_ref)[diff]] = True
+    print(f"  first step: max |{label} - one device| = "
+          f"{np.max(np.abs(c1 - c1_ref)):.3g}", flush=True)
+    check(np.allclose(c1[~moved], c1_ref[~moved], rtol=1e-5, atol=1e-4),
+          f"{label} first-step centroids allclose to one device's "
+          f"(rtol 1e-5, atol 1e-4; {int(moved.sum())} touched by near-tie "
+          "swaps excluded)")
+
+
 def four_chip_phases(seed: int) -> None:
     import jax
-    import jax.numpy as jnp
     import numpy as np
     from repro.core import KMeans, KMeansConfig
     from repro.core.init import init_centroids
     from repro.core.parallel import ParallelContext, parse_mesh_flag
     from repro.index import IVFIndex
-    from repro.kernels import ops, ref
 
     check(len(jax.devices()) == 4, f"four devices (got {len(jax.devices())})")
 
@@ -418,38 +451,18 @@ def four_chip_phases(seed: int) -> None:
     print(f"  {kp.describe()}", flush=True)
     with phase_timer("k_sharded_fit 1x4 fit and first step"):
         xs, c0s = kp.shard_points(x), kp.shard_centroids(c0)
-        c, _, j = kp.make_kmeans_fit(cfg)(xs, c0s)
-        c1, a1, _ = kp.make_kmeans_fit(
+        st_k = kp.make_kmeans_fit(cfg)(xs, c0s)
+        c, j = st_k.centroids, st_k.inertia
+        first = kp.make_kmeans_fit(
             KMeansConfig(k=k, max_iters=1, tol=0.0))(xs, c0s)
+        c1, a1 = first.centroids, first.assignments
         jax.block_until_ready((c, c1))
     bytes_per_device("during the K-sharded fit")
     del xs
 
     # one Lloyd step from the same centroids: the two-stage argmin and
     # the owned statistics must reproduce one device's step
-    a1 = jax.device_put(a1, x.sharding)
-    da, db = (np.asarray(v) for v in _pair_dists(x, c0, a1, a1_ref))
-    diff = np.asarray(a1) != np.asarray(a1_ref)
-    tol = 8 * np.finfo(np.float32).eps * float(np.max(db) + 1.0)
-    print(f"  first step: {int(diff.sum())} of {n} assignments differ from "
-          "one device's", flush=True)
-    check(not np.any(diff & (np.abs(da - db) > tol)),
-          "K-sharded first-step assignments match one device's up to "
-          f"near-ties ({tol:.3g})")
-    s_r, cnt_r = jax.jit(ref.update_scatter_ref, static_argnums=2)(x, a1, k)
-    c1_r = ops.finalize_centroids(s_r, cnt_r, c0)
-    c1, c1_ref = np.asarray(c1), np.asarray(c1_ref)
-    check(np.allclose(c1, np.asarray(c1_r), rtol=1e-5, atol=1e-4),
-          "K-sharded first-step centroids allclose to the reference update "
-          "of its assignments (rtol 1e-5, atol 1e-4)")
-    moved = np.zeros(k, bool)     # centroids a near-tie swap touched
-    moved[np.asarray(a1)[diff]] = moved[np.asarray(a1_ref)[diff]] = True
-    print(f"  first step: max |K-sharded - one device| = "
-          f"{np.max(np.abs(c1 - c1_ref)):.3g}", flush=True)
-    check(np.allclose(c1[~moved], c1_ref[~moved], rtol=1e-5, atol=1e-4),
-          f"K-sharded first-step centroids allclose to one device's "
-          f"(rtol 1e-5, atol 1e-4; {int(moved.sum())} touched by near-tie "
-          "swaps excluded)")
+    check_first_step("K-sharded", x, c0, a1, c1, a1_ref, c1_ref)
 
     # the whole fit: near-tie swaps compound over the iterations, so the
     # centroids are reported and the inertia is what must agree
@@ -461,6 +474,19 @@ def four_chip_phases(seed: int) -> None:
     check(rel <= 1e-4, f"{iters}-iteration inertia {float(j):.7g} within "
                        f"1e-4 of one device's {float(st.inertia):.7g}")
 
+    print(f"phase n_sharded_fit: N={n} over a 4x1 data axis vs one device, "
+          f"K={k}, d={d}: KMeans(cfg, mesh).iterate", flush=True)
+    km4 = KMeans(KMeansConfig(k=k, max_iters=iters, tol=0.0),
+                 mesh=parse_mesh_flag("4"))
+    print(f"  {km4.pctx.describe()}", flush=True)
+    with phase_timer("n_sharded_fit 4x1 first step"):
+        xs = km4.pctx.shard_points(x)
+        c1n, a1n, _ = km4.iterate(xs, c0)
+        jax.block_until_ready((c1n, a1n))
+    bytes_per_device("during the N-sharded step")
+    del xs
+    check_first_step("N-sharded", x, c0, a1n, c1n, a1_ref, c1_ref)
+
 
 # --- main -------------------------------------------------------------------
 
@@ -468,8 +494,9 @@ def main() -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--four-chips", action="store_true",
-                    help="run only the sharded search and K-sharded fit "
-                         "against their single-device twins (4 chips)")
+                    help="run only the sharded search, the K-sharded fit "
+                         "and the N-sharded step against their "
+                         "single-device twins (4 chips)")
     args = ap.parse_args()
 
     try:

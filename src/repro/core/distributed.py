@@ -1,15 +1,15 @@
 """Distributed flash-kmeans — the thin adapter over ``core.parallel``.
 
-Historically this module owned the shard_map machinery; that now lives
-in ``core.parallel.ParallelContext``, the single execution layer every
+The entry point of a multi-device fit is ``KMeans(cfg, mesh)``
+(``fit``, ``iterate``, ``predict``), which resolves a
+``ParallelContext.for_mesh(mesh)`` — the single execution layer every
 multi-device program (distributed Lloyd, streaming ``partial_fit``,
-sharded FlashIVF) is built on. This adapter keeps the stable public
-surface:
+sharded FlashIVF) is built on. This adapter keeps the older surface:
 
 - ``make_distributed_kmeans(mesh, cfg, data_axes, k_axis,
-  compress_pod_axis)`` — builds a ``ParallelContext`` and returns its
-  jitted Lloyd loop ``fit(x_sharded, c0) -> (centroids, assignments,
-  inertia)``;
+  compress_pod_axis)`` — builds a ``ParallelContext`` with explicit
+  axes and returns its jitted Lloyd loop ``fit(x_sharded, c0) ->
+  (centroids, assignments, inertia)``;
 - ``shard_points`` — host-array placement along the data axes.
 
 The centroid statistics ``(s_k, n_k)`` are *sufficient statistics* and
@@ -20,10 +20,12 @@ reduction here, and the multi-pod reduction are all the same tree:
   per-shard Lloyd statistics  ->  psum over data axes  ->  replicated
   ``finalize_centroids`` update.
 
-Two sharding modes compose (see ``ParallelContext`` for the details):
+Two sharding modes compose, each with one Lloyd step body in
+``ParallelContext`` that the loop and the single step (``make_step``)
+both run:
 
 - **N-sharding** (``data_axes``): points sharded; centroids replicated.
-  One psum of (K, d) + (K,) per iteration — collective bytes are
+  One psum of (K, d) + (K,) + () per iteration — collective bytes are
   O(K d), independent of N. The fused single-pass FlashLloyd kernel
   runs distributed exactly as it does on one chip.
 - **K-sharding** (``k_axis``): centroids sharded too (very large K).
@@ -61,7 +63,11 @@ def make_distributed_kmeans(mesh: Mesh, cfg: KMeansConfig,
     per iteration. See ``ParallelContext.make_kmeans_fit``.
     """
     pctx = ParallelContext(mesh, data_axes=data_axes, k_axis=k_axis)
-    return pctx.make_kmeans_fit(cfg, compress_pod_axis=compress_pod_axis)
+    loop = pctx.make_kmeans_fit(cfg, compress_pod_axis=compress_pod_axis)
+
+    def fit(x, c0):
+        return loop(x, c0)[:3]
+    return fit
 
 
 def shard_points(mesh: Mesh, x, data_axes: Sequence[str] = ("data",)):

@@ -7,15 +7,30 @@ import jax.numpy as jnp
 Array = jax.Array
 
 
-def random_init(key: Array, x: Array, k: int) -> Array:
-    """k distinct data points, uniformly sampled."""
-    n = x.shape[0]
+def random_indices(key: Array, n: int, k: int) -> Array:
+    """The row indices of ``k`` distinct points out of ``n``, uniformly
+    sampled: what ``random_init`` takes, and what the sharded init
+    (``ParallelContext.make_random_init``) takes shard by shard."""
     if k > n:
         raise ValueError(
             f"random_init needs at least k data points to draw k distinct "
             f"centroids, got k={k} > n={n}")
-    idx = jax.random.choice(key, n, (k,), replace=False)
-    return jnp.take(x, idx, axis=0)
+    return jax.random.choice(key, n, (k,), replace=False)
+
+
+def random_init(key: Array, x: Array, k: int) -> Array:
+    """k distinct data points, uniformly sampled."""
+    return jnp.take(x, random_indices(key, x.shape[0], k), axis=0)
+
+
+def owned_rows(x_local: Array, idx: Array, lo) -> Array:
+    """Rows ``idx`` of a global array of which ``x_local`` holds rows
+    ``lo, lo + 1, ...``: the rows it holds, zeros in place of the rest.
+    Summed over the shards (a psum), these are exactly the rows."""
+    rel = idx - lo
+    own = (rel >= 0) & (rel < x_local.shape[0])
+    rows = jnp.take(x_local, jnp.clip(rel, 0, x_local.shape[0] - 1), axis=0)
+    return jnp.where(own[:, None], rows, jnp.zeros_like(rows))
 
 
 def kmeans_plus_plus(key: Array, x: Array, k: int) -> Array:

@@ -3,7 +3,10 @@
 ``KMeans`` is the composable module: configure once, then ``fit`` (full
 Lloyd loop under ``lax.while_loop``), ``iterate`` (single step — the online
 primitive used inside models), or ``fit_batched`` (vmapped B independent
-problems, the paper's batch axis).
+problems, the paper's batch axis). Given a mesh (``KMeans(cfg, mesh)``)
+it runs ``fit``, ``iterate`` and ``predict`` across the mesh's devices
+through ``core.parallel.ParallelContext``: points sharded along N, one
+psum of the statistics per step.
 
 The math is byte-for-byte Lloyd's algorithm — no approximation anywhere
 (paper's "mathematically exact" contract); only the dataflow differs by
@@ -18,6 +21,7 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
+from repro import obs
 from repro.core import plan as _plan
 from repro.core.init import init_centroids
 from repro.kernels import ops, ref
@@ -200,18 +204,72 @@ class KMeans:
     >>> state = km.fit(jax.random.PRNGKey(0), x)          # (N, d)
     >>> states = km.fit_batched(key, xb)                  # (B, N, d)
     >>> c1, a, j = km.iterate(x, c0)                      # online single step
+
+    With ``mesh`` the points are sharded ``P(data_axes, None)`` over the
+    mesh's data axes (``ParallelContext.for_mesh``; a host array or an
+    array placed otherwise is placed so, and N must divide by the data
+    shards) and the centroids replicated, or sharded over a cells axis
+    of size above 1. ``iterate`` is one shard_map'd Lloyd step: per-shard
+    statistics (fused or two-pass, as the planner picks at the per-shard
+    shape), one psum of (sums, counts, inertia), a replicated update; it
+    returns the assignments sharded like ``x``. ``fit`` runs the same step
+    in the sharded loop from the sharded random init, which takes each
+    chosen row on the device that holds it. ``fit_batched`` and the
+    k-means++ init run on one device only.
     """
 
-    def __init__(self, cfg: KMeansConfig):
+    def __init__(self, cfg: KMeansConfig, mesh=None):
         self.cfg = cfg
-        self._fit = jax.jit(make_kmeans_fn(cfg))
-        self._fit_batched = jax.jit(jax.vmap(make_kmeans_fn(cfg)))
-        self._step = jax.jit(functools.partial(lloyd_step, cfg=cfg))
+        self.mesh = mesh
+        if mesh is None:
+            self._fit = jax.jit(make_kmeans_fn(cfg))
+            self._fit_batched = jax.jit(jax.vmap(make_kmeans_fn(cfg)))
+            self._step = jax.jit(functools.partial(lloyd_step, cfg=cfg))
+            return
+        from repro.core.parallel import ParallelContext
+        self.pctx = pctx = ParallelContext.for_mesh(mesh)
+        self._step = pctx.make_step(cfg)
+        self._loop = pctx.make_kmeans_fit(cfg)
+        self._init = pctx.make_random_init(cfg.k)
+        self._assign = pctx.make_assign(cfg)
+        self._x_sharding = jax.sharding.NamedSharding(mesh, pctx.data_spec)
+        self._c_sharding = jax.sharding.NamedSharding(mesh,
+                                                      pctx.centroid_spec)
+
+    def _place(self, x: Array, sharding) -> Array:
+        """``x`` as ``sharding`` lays it out, moved only where it is not."""
+        if (isinstance(x, jax.Array)
+                and x.sharding.is_equivalent_to(sharding, x.ndim)):
+            return x
+        return jax.device_put(x, sharding)
+
+    def _points(self, x: Array) -> Array:
+        if self.mesh is None:
+            return self._cast(x)
+        shards = self.pctx.n_data_shards
+        if x.shape[0] % shards:
+            raise ValueError(f"N={x.shape[0]} must divide by the mesh's "
+                             f"{shards} data shards")
+        return self._cast(self._place(x, self._x_sharding))
+
+    def _centroids(self, c: Array) -> Array:
+        if self.mesh is None:
+            return self._cast(c)
+        return self._cast(self._place(c, self._c_sharding))
 
     def fit(self, key: Array, x: Array) -> KMeansState:
-        return self._fit(key, x)
+        if self.mesh is None:
+            return self._fit(key, x)
+        if self.cfg.init != "random":
+            raise NotImplementedError(
+                f"init={self.cfg.init!r} runs on one device; with a mesh "
+                "the init is 'random'")
+        x = self._points(x)
+        return self._loop(x, self._centroids(self._init(key, x)))
 
     def fit_batched(self, key: Array, x: Array) -> KMeansState:
+        if self.mesh is not None:
+            raise NotImplementedError("fit_batched runs on one device")
         b = x.shape[0]
         keys = jax.random.split(key, b)
         return self._fit_batched(keys, x)
@@ -223,9 +281,15 @@ class KMeans:
         return x if self.cfg.dtype is None else x.astype(self.cfg.dtype)
 
     def iterate(self, x: Array, c: Array) -> tuple[Array, Array, Array]:
-        return self._step(self._cast(x), self._cast(c))
+        if self.mesh is not None and obs.enabled():
+            obs.count("lloyd.sharded_steps")
+            obs.count("lloyd.allreduce_bytes", self.pctx.collective_bytes(
+                "stats_psum", k=self.cfg.k, d=x.shape[1]))
+        return self._step(self._points(x), self._centroids(c))
 
     def predict(self, x: Array, c: Array) -> Array:
-        x, c = self._cast(x), self._cast(c)
+        x, c = self._points(x), self._centroids(c)
+        if self.mesh is not None:
+            return self._assign(x, c)[0]
         blk = self.cfg.blocks_for(x.shape[0], x.shape[1], x.dtype.itemsize)
         return _assign(x, c, self.cfg, blk)[0]

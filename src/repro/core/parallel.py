@@ -1,10 +1,19 @@
 """ParallelContext — the one shard_map execution layer of flash-kmeans.
 
-Every multi-device program in this repo — the distributed Lloyd loop
-(core.distributed), the data-parallel streaming ``partial_fit``
+Every multi-device program in this repo — the distributed Lloyd step
+and loop (``KMeans(cfg, mesh)``, the entry point; ``core.distributed``
+is a thin adapter), the data-parallel streaming ``partial_fit``
 (core.streaming), and the sharded FlashIVF build/search/add pipeline
 (index.ivf) — is built from the same four collective primitives, and this
-module is the only place that calls ``shard_map``:
+module is the only place that calls ``shard_map``.
+
+Each sharding mode has one Lloyd step body (``_n_sharded_step``: points
+sharded, centroids replicated; ``_k_sharded_step``: centroids sharded
+too), and both the single step (``make_step``) and the ``while_loop`` fit
+(``make_kmeans_fit``) run it. The step's psums sit under the scope
+``lloyd.allreduce``, beside the kernels' ``lloyd.fused`` /
+``lloyd.assign`` / ``lloyd.update`` and the update's ``lloyd.finalize``.
+The primitives:
 
 - **stats psum-tree** (``psum_stats`` / ``owned_stats``): per-shard
   ``SufficientStats`` are reduced with one ``psum`` over the data axes —
@@ -47,6 +56,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from repro.core import init as _init
 from repro.core import kmeans as _km
 from repro.core.kmeans import KMeansConfig
 from repro.core.streaming import SufficientStats
@@ -126,6 +136,7 @@ class ParallelContext:
     >>> mesh = build_mesh((2, 4), ("data", "model"))
     >>> pctx = ParallelContext(mesh, data_axes=("data",), k_axis="model")
     >>> fit = pctx.make_kmeans_fit(cfg)          # distributed Lloyd loop
+    >>> lloyd = pctx.make_step(cfg)              # one Lloyd step
     >>> step = pctx.make_partial_fit(cfg)        # streaming mini-batch
     >>> assign = pctx.make_assign(cfg)           # two-stage argmin
 
@@ -247,9 +258,7 @@ class ParallelContext:
         axes = tuple(axes) if axes is not None else self.data_axes
         if not axes:
             return stats
-        return SufficientStats(jax.lax.psum(stats.sums, axes),
-                               jax.lax.psum(stats.counts, axes),
-                               jax.lax.psum(stats.inertia, axes))
+        return SufficientStats(*jax.lax.psum(tuple(stats), axes))
 
     def merge_topl(self, idx: Array, val: Array, l: int, *,
                    axis: str | None = None, tie: Array | None = None,
@@ -361,10 +370,8 @@ class ParallelContext:
             x, a_eff, k=k_eff, impl=cfg.stats_only_update_impl(),
             block_n=blk.update_block_n, block_k=blk.update_block_k,
             interpret=cfg.interpret)
-        s, n = s[:k], n[:k]
-        s = jax.lax.psum(s, self.data_axes)
-        n = jax.lax.psum(n, self.data_axes)
-        return s, n
+        with jax.named_scope("lloyd.allreduce"):
+            return jax.lax.psum((s[:k], n[:k]), self.data_axes)
 
     # -- program builders ---------------------------------------------------
 
@@ -389,13 +396,14 @@ class ParallelContext:
                         masked: bool = False):
         """Build the distributed Lloyd loop for this context.
 
-        Returns ``fit(x_sharded, c0) -> (centroids, assignments,
-        inertia)`` — or ``fit(x_sharded, mask_sharded, c0)`` with
-        ``masked=True`` (ragged N padded to a shard multiple; padding
-        rows are excluded from statistics and inertia). The loop runs
-        entirely inside one shard_map'd program: one collective round
-        per iteration (O(K·d) psum — plus, under K-sharding, the
-        O(N_local · P_k) assignment merge), under the same
+        Returns ``fit(x_sharded, c0) -> KMeansState`` — or
+        ``fit(x_sharded, mask_sharded, c0)`` with ``masked=True`` (ragged
+        N padded to a shard multiple; padding rows are excluded from
+        statistics and inertia). The loop runs the sharding mode's step
+        body (the one ``make_step`` runs once) entirely inside one
+        shard_map'd program: one collective round per iteration (O(K·d)
+        psum — plus, under K-sharding, the O(N_local · P_k) assignment
+        merge), under the same
         ``while (iter < max_iters and shift > tol)`` early-stop rule as
         the single-device fit (the shift is replicated — a scalar psum
         over the cells axis under K-sharding — so every shard exits on
@@ -415,96 +423,153 @@ class ParallelContext:
                 "K-sharding")
         return self._make_fit_k_sharded(cfg, masked)
 
+    # -- Lloyd step bodies: one per sharding mode, shared by the loop -------
+    # (``make_kmeans_fit``) and the single step (``make_step``)
+
+    def _n_sharded_step(self, cfg: KMeansConfig, x: Array, c: Array,
+                        mask: Array | None = None, err=None,
+                        compress_pod_axis: str | None = None):
+        """One Lloyd step with points sharded and centroids replicated
+        (inside a shard_map body): per-shard statistics (fused or
+        two-pass, planned at the per-shard shape), one psum of (sums,
+        counts, inertia) over the data axes, a replicated update.
+
+        ``err``: the error-feedback residuals ``(err_s, err_n)`` of the
+        compressed cross-pod exchange (``compress_pod_axis``), carried
+        by the loop; returned updated (unchanged without compression).
+        Returns ``(c_new, assignments, inertia, err)``.
+        """
+        batch, a = SufficientStats.from_batch(x, c, cfg, mask=mask)
+        with jax.named_scope("lloyd.allreduce"):
+            if compress_pod_axis is None:
+                batch = self.psum_stats(batch)
+            else:
+                from repro.optim import compression
+                intra = tuple(ax for ax in self.data_axes
+                              if ax != compress_pod_axis)
+                s, n = jax.lax.psum((batch.sums, batch.counts), intra)
+                s, err_s = compression.ef_quantized_allreduce(
+                    s, err[0], compress_pod_axis)
+                n, err_n = compression.ef_quantized_allreduce(
+                    n, err[1], compress_pod_axis)
+                batch = SufficientStats(
+                    s, n, jax.lax.psum(batch.inertia, self.data_axes))
+                err = (err_s, err_n)
+        with jax.named_scope("lloyd.finalize"):
+            return batch.finalize(c), a, batch.inertia, err
+
+    def _k_sharded_step(self, cfg: KMeansConfig, x: Array, c_local: Array,
+                        mask: Array | None = None):
+        """One Lloyd step with points and centroids sharded (inside a
+        shard_map body): the two-stage argmin, the owned statistics
+        psum'd over the data axes, the update of the owned centroid
+        slice. Returns ``(c_local_new, global assignments, inertia)``."""
+        a_glob, m_glob = self.two_stage_assign(x, c_local, cfg)
+        j = jnp.sum(jnp.where(mask, m_glob, 0.0) if mask is not None
+                    else m_glob)
+        with jax.named_scope("lloyd.allreduce"):
+            inertia = jax.lax.psum(j, self.data_axes)
+        s, n = self.owned_stats(x, a_glob, cfg.k, cfg, mask=mask)
+        with jax.named_scope("lloyd.finalize"):
+            c_new = ops.finalize_centroids(s, n, c_local)
+        return c_new, a_glob.astype(jnp.int32), inertia
+
+    def make_step(self, cfg: KMeansConfig):
+        """Jitted single Lloyd step ``(x_sharded, c) -> (c_new,
+        assignments, inertia)``: the loop's step body run once, the
+        online primitive ``KMeans(cfg, mesh).iterate`` dispatches. ``c``
+        and ``c_new`` are replicated (sharded ``P(k_axis, None)`` under
+        K-sharding); the assignments are sharded like ``x``."""
+        if self.k_axis is None:
+            def shard_fn(x, c):
+                return self._n_sharded_step(cfg, x, c)[:3]
+        else:
+            self.k_local(cfg.k)
+
+            def shard_fn(x, c):
+                return self._k_sharded_step(cfg, x, c)
+        c_spec = self.centroid_spec
+        return jax.jit(self.spmd(shard_fn,
+                                 in_specs=(self.data_spec, c_spec),
+                                 out_specs=(c_spec, P(self.data_axes), P())))
+
+    def make_random_init(self, k: int):
+        """Jitted ``(key, x_sharded) -> c0``: the ``k`` rows ``random_init``
+        picks from the same key, replicated. Each shard takes the chosen
+        rows it owns (zeros elsewhere) and one psum of (k, d) combines
+        them, so no device holds more of ``x`` than its own shard."""
+        axes = self.data_axes
+
+        def shard_fn(key, x):
+            idx = _init.random_indices(key, x.shape[0] * self.n_data_shards,
+                                       k)
+            lo = jax.lax.axis_index(axes) * x.shape[0]
+            return jax.lax.psum(_init.owned_rows(x, idx, lo), axes)
+
+        return jax.jit(self.spmd(shard_fn, in_specs=(P(), self.data_spec),
+                                 out_specs=P(None, None)))
+
     def _make_fit_n_sharded(self, cfg: KMeansConfig,
                             compress_pod_axis: str | None, masked: bool):
-        data_axes = self.data_axes
-        intra_axes = tuple(a for a in data_axes if a != compress_pod_axis)
-
         def shard_fn(x, mask, c0):
-            from repro.optim import compression
-
             def body(carry):
                 c, _, _, err_s, err_n, it, _ = carry
-                if masked:
-                    batch, a = SufficientStats.from_batch(x, c, cfg,
-                                                          mask=mask)
-                    s, n, j_local = batch.sums, batch.counts, batch.inertia
-                else:
-                    a, s, n, j_local = _km.lloyd_stats(x, c, cfg)
-                if compress_pod_axis is None:
-                    s = jax.lax.psum(s, data_axes)
-                    n = jax.lax.psum(n, data_axes)
-                else:
-                    s = jax.lax.psum(s, intra_axes)
-                    n = jax.lax.psum(n, intra_axes)
-                    s, err_s = compression.ef_quantized_allreduce(
-                        s, err_s, compress_pod_axis)
-                    n, err_n = compression.ef_quantized_allreduce(
-                        n, err_n, compress_pod_axis)
-                inertia = jax.lax.psum(j_local, data_axes)
-                c_new = ops.finalize_centroids(s, n, c)
+                c_new, a, inertia, (err_s, err_n) = self._n_sharded_step(
+                    cfg, x, c, mask if masked else None, (err_s, err_n),
+                    compress_pod_axis)
                 shift = jnp.sum((c_new.astype(jnp.float32)
                                  - c.astype(jnp.float32)) ** 2)
                 return c_new, a, inertia, err_s, err_n, it + 1, shift
 
             zero_s = jnp.zeros((cfg.k, x.shape[1]), jnp.float32)
             zero_n = jnp.zeros((cfg.k,), jnp.float32)
-            c, a, inertia, _, _, _, _ = jax.lax.while_loop(
+            c, a, inertia, _, _, it, shift = jax.lax.while_loop(
                 _fit_cond(cfg), body,
                 (c0, jnp.zeros((x.shape[0],), jnp.int32),
                  jnp.array(jnp.inf, jnp.float32), zero_s, zero_n,
                  jnp.array(0, jnp.int32), jnp.array(jnp.inf, jnp.float32)))
-            return c, a, inertia
+            return c, a, inertia, it, shift
 
         return self._finish_fit(shard_fn, masked, k_sharded=False)
 
     def _make_fit_k_sharded(self, cfg: KMeansConfig, masked: bool):
-        data_axes = self.data_axes
-        k_parts = self.n_k_shards
-        if cfg.k % k_parts != 0:
-            raise ValueError(f"K={cfg.k} must divide the k_axis size "
-                             f"{k_parts}")
+        self.k_local(cfg.k)
 
         def shard_fn(x, mask, c0_local):
             def body(carry):
                 c_local, _, _, it, _ = carry
-                a_glob, m_glob = self.two_stage_assign(x, c_local, cfg)
-                j = jnp.where(mask, m_glob, 0.0) if masked else m_glob
-                inertia = jax.lax.psum(jnp.sum(j), data_axes)
-                s, n = self.owned_stats(x, a_glob, cfg.k, cfg,
-                                        mask=mask if masked else None)
-                c_new = ops.finalize_centroids(s, n, c_local)
+                c_new, a, inertia = self._k_sharded_step(
+                    cfg, x, c_local, mask if masked else None)
                 # global centroid shift: local slice + psum over cells
                 shift = jax.lax.psum(
                     jnp.sum((c_new.astype(jnp.float32)
                              - c_local.astype(jnp.float32)) ** 2),
                     self.k_axis)
-                return (c_new, a_glob.astype(jnp.int32), inertia, it + 1,
-                        shift)
+                return c_new, a, inertia, it + 1, shift
 
-            c, a, inertia, _, _ = jax.lax.while_loop(
+            return jax.lax.while_loop(
                 _fit_cond(cfg), body,
                 (c0_local, jnp.zeros((x.shape[0],), jnp.int32),
                  jnp.array(jnp.inf, jnp.float32), jnp.array(0, jnp.int32),
                  jnp.array(jnp.inf, jnp.float32)))
-            return c, a, inertia
 
         return self._finish_fit(shard_fn, masked, k_sharded=True)
 
     def _finish_fit(self, shard_fn, masked: bool, k_sharded: bool):
         c_spec = P(self.k_axis, None) if k_sharded else P(None, None)
         in_specs = (self.data_spec, P(self.data_axes), c_spec)
-        out_specs = (c_spec, P(self.data_axes), P())
-        fn = self.spmd(shard_fn, in_specs=in_specs,
-                            out_specs=out_specs)
+        out_specs = (c_spec, P(self.data_axes), P(), P(), P())
+        jitted = jax.jit(self.spmd(shard_fn, in_specs=in_specs,
+                                   out_specs=out_specs))
         if masked:
-            return jax.jit(fn)
-        # unmasked callers keep the historical fit(x, c0) signature; the
-        # dummy mask is closed over as a constant (never touched)
-        jitted = jax.jit(fn)
-
-        def fit(x, c0):
-            return jitted(x, jnp.ones((x.shape[0],), jnp.bool_), c0)
+            def fit(x, mask, c0):
+                return _km.KMeansState(*jitted(x, mask, c0))
+        else:
+            # unmasked callers keep the fit(x, c0) signature; the dummy
+            # mask is never read
+            def fit(x, c0):
+                return _km.KMeansState(
+                    *jitted(x, jnp.ones((x.shape[0],), jnp.bool_), c0))
         return fit
 
     def make_partial_fit(self, cfg: KMeansConfig, *, decay: float = 1.0,

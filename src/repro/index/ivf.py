@@ -143,9 +143,9 @@ def _train_sharded(pctx, cfg: KMeansConfig, key, x: Array
     xs = pctx.shard_points(x_pad)
     c0s = pctx.shard_centroids(c0)
     if ragged:
-        c, _, _ = fit(xs, pctx.put(mask, P(pctx.data_axes)), c0s)
+        c = fit(xs, pctx.put(mask, P(pctx.data_axes)), c0s).centroids
     else:
-        c, _, _ = fit(xs, c0s)
+        c = fit(xs, c0s).centroids
     a, m = pctx.make_assign(cfg)(xs, c)
     return c, a[:n], m[:n]
 

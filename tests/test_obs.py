@@ -232,3 +232,31 @@ def test_lloyd_step_carries_stage_scopes(tracing, step_impl, scopes):
     assert set(found) == scopes
     KMeans(cfg).iterate(x, x[:8])
     assert _spans() == []
+
+
+def test_sharded_iterate_counts_steps_and_allreduce_bytes():
+    """On 4 fake CPU devices (a subprocess, ``_dp_worker.py --obs``): each
+    sharded ``iterate`` adds 1 to ``lloyd.sharded_steps`` and the modelled
+    ``stats_psum`` bytes to ``lloyd.allreduce_bytes``, and one device's
+    step adds nothing; off, nothing is recorded. The step names its psum
+    ``lloyd.allreduce`` beside the kernel and update scopes."""
+    import json
+    import subprocess
+    import sys
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    env["PYTHONPATH"] = os.pathsep.join(
+        [os.path.join(root, "src"), root, env.get("PYTHONPATH", "")])
+    r = subprocess.run(
+        [sys.executable, os.path.join(root, "tests", "distributed",
+                                      "_dp_worker.py"), "--obs"],
+        capture_output=True, text=True, env=env, timeout=600)
+    assert r.returncode == 0, r.stderr[-4000:]
+    out = json.loads(r.stdout.strip().splitlines()[-1])
+    assert out["off"] == {"spans": [], "counters": {}, "dropped": 0}
+    assert out["psum_bytes"] == 2 * 4 * (16 * 32 + 16 + 1)
+    assert out["counters"] == {"lloyd.sharded_steps": 3,
+                               "lloyd.allreduce_bytes": 3 * out["psum_bytes"]}
+    assert out["spans"] == 0
+    assert "lloyd.allreduce" in out["scopes"]
+    assert "lloyd.finalize" in out["scopes"]
