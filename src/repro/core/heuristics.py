@@ -182,9 +182,12 @@ def fused_footprint(bn: int, bk: int, d: int, bytes_in: int,
     c_res = 2 * k_pad * d * bytes_in    # resident centroid block
     acc = 2 * (k_pad * d * 4 + k_pad * 4)   # resident f32 sums + counts
     sweep = 4 * bn * bk * 4             # score / ids / one-hot slices
-    mxu = _mxu_split((2 * bn + bk) * d, bytes_in)
+    mxu = _mxu_split((bn + bk) * d, bytes_in)   # sweep 1's HIGHEST split
+    # sweep 2 holds the tile's three exact bf16 terms (the one-hot is
+    # exact in bf16 and is not split): ``kernels.flash_lloyd.split_bf16x3``
+    terms = _mxu_split(bn * d, bytes_in)
     state = bn * (4 + 4) * 8 + 2 * bn * 4 * 8   # argmin rows + out row
-    return x_tiles + c_res + acc + sweep + mxu + state
+    return x_tiles + c_res + acc + sweep + mxu + terms + state
 
 
 def probe_footprint(bn: int, bk: int, l: int, d: int, bytes_in: int) -> int:

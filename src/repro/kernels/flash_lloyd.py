@@ -16,12 +16,18 @@ Structure: grid ``(N_tiles,)`` with an inner ``fori_loop`` K-sweep.
 - sweep 1 replays the FlashAssign online argmin over ``K_pad/B_K``
   centroid slices of the VMEM-resident centroid block (the ``||x||^2``
   term is dropped on-chip, re-added for the inertia only);
-- sweep 2 revisits the same centroid slices, builds the tile-local one-hot
-  ``(B_N, B_K)`` in registers, and accumulates one MXU matmul
-  ``onehot^T @ x_tile`` plus counts into the ``(K_pad, d)`` sums and
+- sweep 2 revisits the same centroid slices, builds the tile-local
+  one-hot ``(B_K, B_N)`` in registers, and accumulates ``onehot @ x_tile``
+  plus counts into the ``(K_pad, d)`` sums and
   ``(K_pad/B_K, B_K)`` count rows, f32 output blocks that stay resident
   in VMEM for the whole grid
   (constant index map — initialized at tile 0, flushed once at the end).
+
+For float32 points the statistics product takes three bf16 MXU passes
+where ``Precision.HIGHEST`` takes six: the one-hot is exact in bf16, so
+only ``x`` needs splitting, and ``split_bf16x3`` splits it exactly, once
+per tile (DESIGN.md §3). The distance product of sweep 1 stays at
+HIGHEST.
 
 The price is that the full centroid set and the f32 accumulators must be
 VMEM-resident: ``~2 K_pad·d·4`` bytes. ``core.heuristics.fused_footprint``
@@ -50,6 +56,19 @@ Array = jax.Array
 _INF = float("inf")
 
 
+def split_bf16x3(x: Array) -> tuple[Array, Array, Array]:
+    """``x`` (float32) as three bfloat16 terms ``hi + mid + lo == x``,
+    exactly: each remainder is taken in float32, where it is exact, and
+    8 + 8 + 8 significand bits cover float32's 24. Products of an operand
+    that bfloat16 holds exactly (a one-hot) with each term are exact, so
+    three bf16 MXU passes give what ``Precision.HIGHEST``'s six give."""
+    hi = x.astype(jnp.bfloat16)
+    r = x - hi.astype(jnp.float32)
+    mid = r.astype(jnp.bfloat16)
+    lo = (r - mid.astype(jnp.float32)).astype(jnp.bfloat16)
+    return hi, mid, lo
+
+
 def _flash_lloyd_kernel(x_ref, c_ref, a_ref, s_ref, cnt_ref, j_ref, *,
                         block_n: int, block_k: int, k_actual: int,
                         n_actual: int):
@@ -57,9 +76,10 @@ def _flash_lloyd_kernel(x_ref, c_ref, a_ref, s_ref, cnt_ref, j_ref, *,
 
     Sweep 1 scores transposed ``(B_K, B_N)`` slices (FlashAssign's
     layout), so the argmin state and the assignment row are lane-dense
-    ``(1, B_N)`` rows; sweep 2 turns the row into a column once and
-    builds the ``(B_N, B_K)`` one-hot, whose column sums are the
-    lane-dense ``(1, B_K)`` count rows.
+    ``(1, B_N)`` rows. Sweep 2 compares that row with the slice's
+    centroid ids down the sublanes: the ``(B_K, B_N)`` one-hot is the
+    MXU's plain left operand, with no transpose, and a ones row times its
+    transpose gives the lane-dense ``(1, B_K)`` count row.
     """
     i = pl.program_id(0)
     nk = c_ref.shape[0] // block_k
@@ -103,26 +123,40 @@ def _flash_lloyd_kernel(x_ref, c_ref, a_ref, s_ref, cnt_ref, j_ref, *,
     a_ref[...] = a
 
     # Inertia: re-add the dropped ||x||^2, clamp fp residue, mask padding.
-    a_col, m_col = row_to_col(a), row_to_col(m)       # (bn, 1) each
+    m_col = row_to_col(m)                             # (bn, 1)
     x32 = x.astype(jnp.float32)
     xsq = jnp.sum(x32 * x32, axis=-1, keepdims=True)
     dist = jnp.maximum(m_col + xsq, 0.0)
     j_ref[0, 0] += jnp.sum(jnp.where(row_valid, dist, 0.0))
 
     # ---- sweep 2: one-hot statistics into the resident accumulators.
+    # float32 x: its exact bf16 terms, split once per tile, smallest first
+    # (one bf16 pass each, against HIGHEST's six for the whole product).
+    if x.dtype == jnp.float32:
+        terms = split_bf16x3(x)[::-1]
+    else:
+        terms = (x,)
+    oh_dtype = terms[0].dtype
+    col_ids = i * block_n + jax.lax.broadcasted_iota(
+        jnp.int32, (1, block_n), 1)
+    a_valid = jnp.where(col_ids < n_actual, a, -1)    # padding matches none
+    ones = jnp.ones((8, block_n), oh_dtype)           # one sublane tile
+
     def _accum_body(kt, _):
         start = pl.multiple_of(kt * block_k, block_k)
-        cols = kt * block_k + jax.lax.broadcasted_iota(
-            jnp.int32, (block_n, block_k), 1)
-        onehot = jnp.logical_and(a_col == cols, row_valid)
-        oh = onehot.astype(x.dtype)
+        k_ids = kt * block_k + jax.lax.broadcasted_iota(
+            jnp.int32, (block_k, block_n), 0)
+        oh = (a_valid == k_ids).astype(oh_dtype)     # (bk, bn), exact
         # MXU: (bk, bn) @ (bn, d) f32-accumulated == slice-local sums.
-        partial = jax.lax.dot_general(
-            oh, x, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32, precision=prec)
+        partial = None
+        for t in terms:
+            p = jnp.dot(oh, t, preferred_element_type=jnp.float32)
+            partial = p if partial is None else partial + p
         s_ref[pl.ds(start, block_k), :] += partial
-        cnt_ref[pl.ds(kt, 1), :] += jnp.sum(onehot.astype(jnp.float32),
-                                            axis=0, keepdims=True)
+        cnt = jax.lax.dot_general(                    # (8, bk), exact
+            ones, oh, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        cnt_ref[pl.ds(kt, 1), :] += cnt[0:1, :]
         return 0
 
     jax.lax.fori_loop(0, nk, _accum_body, 0)
