@@ -44,7 +44,7 @@ os.environ.setdefault("TPU_LOG_DIR", "disabled")
 os.environ.setdefault("REPRO_PLAN_CACHE", "off")
 
 
-# the paper's two fit regimes (bench_e2e): fused FlashLloyd, two-pass
+# the paper's two fit regimes: fused FlashLloyd, two-pass
 FUSED = dict(n=1 << 23, d=128, k=1024, iters=5)
 TWO_PASS = dict(n=1 << 20, d=512, k=65536, iters=2)
 # the search corpus (SIFT1M-shaped) and its serving load

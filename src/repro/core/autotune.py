@@ -1,7 +1,6 @@
 """Exhaustive block-shape autotuner — the *baseline* the paper's
-cache-aware heuristic is measured against (paper Fig. 5 / our
-benchmarks/bench_compile.py), and the measurement backend of the
-``KernelPlanner``'s ``refine="measure"`` path
+cache-aware heuristic is measured against (paper Fig. 5), and the
+measurement backend of the ``KernelPlanner``'s ``refine="measure"`` path
 (``core.plan.KernelPlanner.fold_measured`` folds a ``TuneReport`` back
 into the plan cache, so the oracle config is paid for once and then
 served from memory/disk like any other plan).

@@ -367,8 +367,7 @@ def choose_scan_blocks(b: int, c: int, d: int, l: int, *,
 
 
 # --- per-iteration HBM traffic models -------------------------------------
-# Single source of truth: the runtime crossover below and the benchmark
-# roofline tables (benchmarks/common.py) must never disagree.
+# Single source of truth for the runtime crossover below.
 
 def assign_bytes_flash(n: int, k: int, d: int, b: int = 4) -> float:
     """FlashAssign: stream X once, C once (per point-tile reuse in VMEM),

@@ -44,8 +44,8 @@ one cached plan per shard geometry, not per global shape.
 
 The collective-bytes model (``collective_bytes``) mirrors the HBM-bytes
 models in ``core.heuristics``: a closed-form per-shard wire-byte count
-for each primitive, used by DESIGN.md, ``benchmarks/bench_index.py`` and
-the regression tests that pin sharded search traffic to O(b·L).
+for each primitive, used by DESIGN.md and the regression tests that pin
+sharded search traffic to O(b·L).
 """
 from __future__ import annotations
 

@@ -23,15 +23,23 @@ this repo already has:
   once per group, in place, through scalar-prefetched block indices; a
   per-query top-k over the ``nprobe`` per-list results finishes it. No
   ``(B, nprobe·width, d)`` candidate copy is written. The routed,
-  quantized, rescore and sharded searches still gather candidates and
-  scan them with the grouped variant ``ops.flash_probe_grouped``. The
-  score matrix never exists in HBM at any stage;
+  quantized and sharded searches still gather candidates and scan them
+  with the grouped variant ``ops.flash_probe_grouped`` (or its q8 twin,
+  followed by an exact **rescore**). The score matrix never exists in
+  HBM at any stage;
 - **online** — ``add`` assigns new vectors with FlashAssign, appends
   them to their lists in CSR batch order, and folds their sufficient
   statistics into the running per-cluster ``SufficientStats``
   (core.streaming); a periodic ``refresh`` commits the pending evidence
   and re-centers the coarse centroids via the warm-start
   ``finalize`` M-step — one O(K·d) reduction, never a refit.
+
+Search is one composition of stages — route (flat or two-level) →
+scan (list-major, or gather → grouped fp32 / q8 scan) → rescore (q8) —
+fixed per query geometry by a ``_SearchSpec``: one jitted program,
+``_ivf_search``, on one device, and one shard_map'd program per spec
+(``_make_sharded_search``) on a cells-sharded mesh, over the same
+stage functions.
 
 Storage layout: posting-list payloads live behind ``index/store.py``
 (``BucketStore``) — the index never touches a raw bucket tensor. The
@@ -59,7 +67,7 @@ runs inside one shard_map'd program:
   cells' buckets  ->  global top-k merge (O(b·topk) bytes).
 
 Posting-list payloads never cross shards; the only wire traffic is the
-two (value, index) list merges. ``build`` trains through the same
+two (value, index) list merges (q8 adds one O(b·R·d) row exchange). ``build`` trains through the same
 context (data-parallel and/or two-stage K-sharded Lloyd), and
 ``add``/``refresh`` route the pending ``SufficientStats`` through the
 same O(K·d) psum tree as every other driver.
@@ -68,6 +76,7 @@ from __future__ import annotations
 
 import functools
 import time
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -118,7 +127,8 @@ def recall_at_k(ids, ids_ref) -> float:
 
     ``ids``/``ids_ref``: (B, topk) id arrays (brute-force order as the
     reference); unfilled ``-1`` slots count as misses. The one recall
-    definition shared by the serve launcher and the index benchmark.
+    definition shared by the serve launcher, ``chip_smoke.py`` and the
+    tests.
     """
     ids, ids_ref = np.asarray(ids), np.asarray(ids_ref)
     k = ids_ref.shape[1]
@@ -148,6 +158,66 @@ def _train_sharded(pctx, cfg: KMeansConfig, key, x: Array
         c = fit(xs, c0s).centroids
     a, m = pctx.make_assign(cfg)(xs, c)
     return c, a[:n], m[:n]
+
+
+class _SearchSpec(NamedTuple):
+    """Everything a search program closes over, built in one place
+    (``IVFIndex._search_spec``): the one static argument of
+    ``_ivf_search`` and the key of the sharded program cache. A named
+    tuple, so building and hashing it per call costs the host little."""
+
+    b: int                       # query rows of the unit (data-padded)
+    topk: int
+    nprobe: int
+    width: int                   # the store's occupied gather width
+    kind: str                    # store backend: "padded" | "paged"
+    ps: int                      # page size (0: padded)
+    nsh: int                     # store shards
+    k: int
+    shards: int = 0              # K-shards of a cells mesh (0: one device)
+    route: tuple = ()            # two-level (K_c, nprobe_c, gcap); () flat
+    codec: str = "fp32"          # "fp32" | "q8"
+    r: int = 0                   # q8 proposal depth
+    cache: tuple = ()            # device rescore cache fingerprint; () none
+    interpret: bool | None = None
+    route_tiles: tuple = ()      # (bqn, bqk) | (bcn, bck, bfb, bfc)
+    scan_tiles: tuple = ()       # (g, bw) list-major | (bsb, bsc) | (bsb, bsw) q8
+    rescore_tiles: tuple = ()    # (brb, brc), q8 only
+
+    @property
+    def routed(self) -> bool:
+        return bool(self.route)
+
+    @property
+    def cached(self) -> bool:
+        return bool(self.cache)
+
+    @property
+    def list_major(self) -> bool:
+        """Flat router, fp32 payload, one device: the list-major scan."""
+        return self.codec == "fp32" and not self.routed and not self.shards
+
+    @property
+    def leff(self) -> int:
+        """Cells the fine route scan keeps (< nprobe on thin groups)."""
+        _, npc, gcap = self.route
+        return min(self.nprobe, npc * gcap)
+
+    @property
+    def k_local(self) -> int:
+        return self.k // self.shards
+
+    @property
+    def ll(self) -> int:
+        """Most owned cells one query probes on a K-shard."""
+        return min(self.nprobe, self.k_local)
+
+    @property
+    def geometry(self) -> tuple:
+        """What cached programs and plans re-key on (``search_geometry``)."""
+        return ((self.nprobe, self.topk, self.width)
+                + ((self.shards,) if self.shards else ())
+                + self.route + self.cache)
 
 
 def _list_segments(probe: Array, g: int, k: int
@@ -226,242 +296,221 @@ def _scan_lists(q: Array, probe: Array, counts: Array, store_arrays: tuple,
         return jnp.where(filled, ids, -1), jnp.where(filled, dist, pad)
 
 
-@functools.partial(jax.jit, static_argnames=("kind", "topk", "nprobe",
-                                             "width", "ps", "nsh", "bqn",
-                                             "bqk", "g", "bw", "interpret"))
-def _ivf_search(q: Array, centroids: Array, c_sq: Array, counts: Array,
-                store_arrays: tuple, *,
-                kind: str, topk: int, nprobe: int, width: int, ps: int,
-                nsh: int, bqn: int, bqk: int, g: int, bw: int,
-                interpret: bool | None) -> tuple[Array, Array]:
-    """Batched two-stage IVF search, fully fused (one jit per geometry).
-
-    Stage 1: FlashProbe over the coarse centroids -> (B, nprobe) cells
-    (``c_sq`` is the index's cached ``||c||^2`` strip — no per-call
-    norm recompute).
-    Stage 2: the list-major scan (``_scan_lists``): the unit's (query,
-    probe) pairs inverted into per-list query groups, each probed list
-    read in place from the store (padded tiles or pages, up to its
-    ``counts`` rows) once per group, then a per-query top-k merge.
-    """
-    with jax.named_scope("ivf.probe"):
-        probe, _ = ops.flash_probe(q, centroids.astype(q.dtype), l=nprobe,
-                                   block_n=bqn, block_k=bqk,
-                                   interpret=interpret, want_dists=False,
-                                   c_sq=c_sq)
-    return _scan_lists(q, probe, counts, store_arrays, kind=kind, topk=topk,
-                       width=width, ps=ps, nsh=nsh, g=g, bw=bw,
-                       interpret=interpret)
+def _probe(q: Array, c: Array, c_sq: Array, *, l: int, tiles: tuple,
+           interpret: bool | None) -> tuple[Array, Array]:
+    """FlashProbe: the ``l`` nearest of ``c`` per query (``c_sq`` its
+    cached ``||c||^2`` strip), as ``(indices, ||q||^2-free scores)``."""
+    bn, bk = tiles
+    return ops.flash_probe(q, c.astype(q.dtype), l=l, block_n=bn,
+                           block_k=bk, interpret=interpret,
+                           want_dists=False, c_sq=c_sq)
 
 
-def _route_cells(q: Array, centroids: Array, coarse: Array,
-                 coarse_sq: Array, members: Array, *, k: int, nprobe: int,
-                 npc: int, leff: int, bcn: int, bck: int, bfb: int,
-                 bfc: int, interpret: bool | None) -> Array:
-    """Two-level cell selection (the TwoLevelRouter's jit-side half).
+def _coarse_cells(q: Array, coarse: Array, coarse_sq: Array,
+                  members: Array, spec: _SearchSpec) -> Array:
+    """The two-level router's coarse half: a FlashProbe picks each
+    query's ``npc`` nearest group centroids and the member table expands
+    them into its ``(B, npc·gcap)`` candidate fine cells (sentinel ``K``
+    on thin-group padding)."""
+    _, npc, gcap = spec.route
+    cidx, _ = _probe(q, coarse, coarse_sq, l=npc,
+                     tiles=spec.route_tiles[:2], interpret=spec.interpret)
+    return jnp.take(members, cidx, axis=0).reshape(q.shape[0], npc * gcap)
 
-    Coarse FlashProbe picks the ``npc`` nearest of the K_c group
-    centroids; the member table expands them into each query's candidate
-    fine cells (sentinel ``K`` on thin-group padding, which gathers the
-    ``_PAD_COORD`` row and can never win); a grouped FlashProbe over
-    only those ``npc * gcap`` fine centroids yields the probe list —
-    the flat kernel's ``O(K·d)`` stream never happens. Returns
-    ``(B, nprobe)`` cells in ``[0, K]`` (``K`` = no cell), ascending by
-    distance — at ``leff < nprobe`` the tail is sentinel-padded.
-    """
-    b, d = q.shape
-    gcap = members.shape[1]
-    cidx, _ = ops.flash_probe(q, coarse.astype(q.dtype), l=npc,
-                              block_n=bcn, block_k=bck,
-                              interpret=interpret, want_dists=False,
-                              c_sq=coarse_sq)
-    cand_cells = jnp.take(members, cidx, axis=0).reshape(b, npc * gcap)
-    cpad = jnp.concatenate(
-        [centroids.astype(q.dtype),
-         jnp.full((1, d), _PAD_COORD, q.dtype)], axis=0)
-    cand_c = jnp.take(cpad, cand_cells, axis=0)      # (B, npc*gcap, d)
-    li, _ = ops.flash_probe_grouped(q, cand_c, l=leff, block_b=bfb,
-                                    block_c=bfc, interpret=interpret,
-                                    want_dists=False)
+
+def _with_pad_row(c: Array, dtype) -> Array:
+    """``c`` plus one ``_PAD_COORD`` row: the row sentinel or not-owned
+    cells gather, which can never win a probe."""
+    return jnp.concatenate(
+        [c.astype(dtype), jnp.full((1, c.shape[1]), _PAD_COORD, dtype)],
+        axis=0)
+
+
+def _route(q: Array, centroids: Array, c_sq: Array, route_arrays: tuple,
+           spec: _SearchSpec) -> Array:
+    """One device: the ``(B, nprobe)`` cells each query probes.
+
+    Flat: FlashProbe over all K centroids. Two-level: a grouped
+    FlashProbe over only the coarse candidates' ``npc·gcap`` fine
+    centroids — the flat kernel's ``O(K·d)`` stream never happens — and
+    cells come out in ``[0, K]`` (``K`` = no cell), ascending by distance;
+    at ``leff < nprobe`` the tail is sentinel-padded."""
+    if not spec.routed:
+        probe, _ = _probe(q, centroids, c_sq, l=spec.nprobe,
+                          tiles=spec.route_tiles, interpret=spec.interpret)
+        return probe
+    cand_cells = _coarse_cells(q, *route_arrays, spec)
+    cand_c = jnp.take(_with_pad_row(centroids, q.dtype), cand_cells, axis=0)
+    li, _ = ops.flash_probe_grouped(
+        q, cand_c, l=spec.leff, block_b=spec.route_tiles[2],
+        block_c=spec.route_tiles[3], interpret=spec.interpret,
+        want_dists=False)
     cells = jnp.take_along_axis(cand_cells, li, axis=1)
-    if leff < nprobe:
-        cells = jnp.pad(cells, ((0, 0), (0, nprobe - leff)),
-                        constant_values=k)
+    if spec.leff < spec.nprobe:
+        cells = jnp.pad(cells, ((0, 0), (0, spec.nprobe - spec.leff)),
+                        constant_values=spec.k)
     return cells
 
 
-@functools.partial(jax.jit, static_argnames=("kind", "k", "topk", "nprobe",
-                                             "npc", "leff", "width", "ps",
-                                             "nsh", "bcn", "bck", "bfb",
-                                             "bfc", "bsb", "bsc",
-                                             "interpret"))
-def _ivf_search_routed(q: Array, centroids: Array, coarse: Array,
-                       coarse_sq: Array, members: Array,
-                       store_arrays: tuple, *, kind: str, k: int,
-                       topk: int, nprobe: int, npc: int, leff: int,
-                       width: int, ps: int, nsh: int, bcn: int, bck: int,
-                       bfb: int, bfc: int, bsb: int, bsc: int,
-                       interpret: bool | None) -> tuple[Array, Array]:
-    """Routed two-stage search: ``_route_cells`` replaces the flat probe;
-    the bucket gather/scan is byte-identical to ``_ivf_search`` except
-    that sentinel cells (``probe == K``) are clamped for the gather and
-    masked back to padding rows / ``-1`` ids afterwards."""
-    with jax.named_scope("ivf.probe"):
-        probe = _route_cells(q, centroids, coarse, coarse_sq, members, k=k,
-                             nprobe=nprobe, npc=npc, leff=leff, bcn=bcn,
-                             bck=bck, bfb=bfb, bfc=bfc, interpret=interpret)
-    with jax.named_scope("ivf.gather"):
-        safe = jnp.minimum(probe, k - 1)
-        cand_x, cand_ids = _store.gather_global(kind, store_arrays, safe,
-                                                width, ps, nsh)
-        pad = jnp.repeat(probe >= k, width, axis=1)  # (B, nprobe*width)
-        cand_x = jnp.where(pad[:, :, None],
-                           jnp.asarray(_PAD_COORD, cand_x.dtype), cand_x)
-        cand_ids = jnp.where(pad, -1, cand_ids)
-    with jax.named_scope("ivf.scan"):
-        li, dist = ops.flash_probe_grouped(q, cand_x, l=topk,
-                                           block_b=bsb, block_c=bsc,
-                                           interpret=interpret)
-        ids = jnp.take_along_axis(cand_ids, li, axis=1)
-    return ids, dist
+def _route_sharded(pctx, q: Array, c_local: Array, csq_local: Array,
+                   route_arrays: tuple, *, lo: Array, alive: Array,
+                   spec: _SearchSpec) -> Array:
+    """One K-shard: the global ``(bl, nprobe)`` probe list, merged across
+    shards (O(b·L) wire bytes; a dead shard contributes nothing).
 
-
-def _route_cells_sharded(pctx, ka, q, c_local, coarse, coarse_sq, members,
-                         *, k, k_local, nprobe, npc, leff, bcn, bck,
-                         bfb, bfc, alive, interpret):
-    """Two-level cell selection under cells sharding.
-
-    The coarse probe runs on replicated state (coarse centroids +
-    member table), so every K-shard computes the identical candidate
-    cell list with zero wire traffic; each shard then scores only the
-    candidate fine centroids *it owns* (non-owned slots gather the
-    local padding row) and the probe list comes out of the same
-    cross-shard top-L merge the flat sharded path uses. The tie key is
-    the candidate-axis position — the exact order the single-device
-    ``_route_cells`` scan sees — so the merged probe list matches the
-    single-device routed list entry for entry.
-    """
-    bl, d = q.shape
-    gcap = members.shape[1]
-    cidx, _ = ops.flash_probe(q, coarse.astype(q.dtype), l=npc,
-                              block_n=bcn, block_k=bck,
-                              interpret=interpret, want_dists=False,
-                              c_sq=coarse_sq)
-    cand_cells = jnp.take(members, cidx, axis=0).reshape(bl, npc * gcap)
-    lo = jax.lax.axis_index(ka) * k_local
+    Flat: a local top-``ll`` probe over the owned centroids. Two-level:
+    the coarse half runs on replicated state, so every shard derives the
+    same candidate list with no wire traffic, and scores only the
+    candidates it owns (the rest gather the padding row). The tie key is
+    the candidate-axis position — the order the one-device route sees —
+    so the merged list matches it entry for entry."""
+    if not spec.routed:
+        idx, val = _probe(q, c_local, csq_local, l=spec.ll,
+                          tiles=spec.route_tiles, interpret=spec.interpret)
+        gcell, _ = pctx.merge_topl(idx + lo, val, spec.nprobe, valid=alive)
+        return gcell
+    kl = spec.k_local
+    cand_cells = _coarse_cells(q, *route_arrays, spec)
     relc = cand_cells - lo
-    ownc = jnp.logical_and(relc >= 0, relc < k_local)
-    cpad = jnp.concatenate(
-        [c_local.astype(q.dtype),
-         jnp.full((1, d), _PAD_COORD, q.dtype)], axis=0)
-    cand_c = jnp.take(cpad, jnp.where(ownc, relc, k_local), axis=0)
-    fli, flv = ops.flash_probe_grouped(q, cand_c, l=leff, block_b=bfb,
-                                       block_c=bfc, interpret=interpret,
-                                       want_dists=False)
-    fcell = jnp.take_along_axis(jnp.where(ownc, cand_cells, k), fli,
+    ownc = jnp.logical_and(relc >= 0, relc < kl)
+    cand_c = jnp.take(_with_pad_row(c_local, q.dtype),
+                      jnp.where(ownc, relc, kl), axis=0)
+    fli, flv = ops.flash_probe_grouped(
+        q, cand_c, l=spec.leff, block_b=spec.route_tiles[2],
+        block_c=spec.route_tiles[3], interpret=spec.interpret,
+        want_dists=False)
+    fcell = jnp.take_along_axis(jnp.where(ownc, cand_cells, spec.k), fli,
                                 axis=1)
-    gcell, _ = pctx.merge_topl(fcell, flv, nprobe, tie=fli, valid=alive)
+    gcell, _ = pctx.merge_topl(fcell, flv, spec.nprobe, tie=fli,
+                               valid=alive)
     return gcell
 
 
-def _q8_propose_routed(q: Array, centroids: Array, coarse: Array,
-                       coarse_sq: Array, members: Array,
-                       store_arrays: tuple, *, kind: str, k: int,
-                       r: int, nprobe: int, npc: int, leff: int,
-                       width: int, ps: int, nsh: int, bcn: int,
-                       bck: int, bfb: int, bfc: int, bsb: int,
-                       bsw: int, interpret: bool | None
-                       ) -> tuple[Array, Array]:
-    """Routed phase-1 proposer body on a quantized store:
-    ``_route_cells`` in the probe seat; sentinel cells gather at a
-    clamped index and mask out through scale 0.0 / id -1 (the same
-    contract thin cells already use), so the rescore phase needs no
-    change. Plain traceable function — jitted standalone (host-rescore
-    oracle) and fused with cache lookup + rescore (device path)."""
-    with jax.named_scope("ivf.probe"):
-        probe = _route_cells(q, centroids, coarse, coarse_sq, members, k=k,
-                             nprobe=nprobe, npc=npc, leff=leff, bcn=bcn,
-                             bck=bck, bfb=bfb, bfc=bfc, interpret=interpret)
-    *arrays, anchors = store_arrays
-    with jax.named_scope("ivf.gather"):
-        safe = jnp.minimum(probe, k - 1)
-        codes, scales, cand_ids = _store.gather_global_q8(
-            kind, tuple(arrays), safe, width, ps, nsh)
-        pad = probe >= k                                 # (B, nprobe)
+def _owned_cells(gcell: Array, lo: Array, spec: _SearchSpec
+                 ) -> tuple[Array, Array]:
+    """Compact a K-shard's owned probed cells (stable: global probe order
+    kept) into a fixed ``(bl, ll)`` block of local indices; not-owned
+    slots point at the padding cell ``k_local``, which the store's gather
+    maps onto padding slots. Also returns each slot's global probe rank."""
+    bl, nprobe = gcell.shape
+    rel = gcell - lo
+    owned = jnp.logical_and(rel >= 0, rel < spec.k_local)
+    pos = jax.lax.broadcasted_iota(jnp.int32, (bl, nprobe), 1)
+    order = jnp.argsort(jnp.where(owned, pos, nprobe), axis=1)[:, :spec.ll]
+    cell = jnp.take_along_axis(rel, order, axis=1)
+    ok = jnp.take_along_axis(owned, order, axis=1)
+    return jnp.where(ok, cell, spec.k_local), order
+
+
+def _gather(probe: Array, store_arrays: tuple, spec: _SearchSpec):
+    """One device: the probed lists' candidate block — rows ``(x, ids)``,
+    or on a q8 store ``(codes, scales, ids)``. Two-level routes pad with
+    the sentinel cell ``K``: it gathers at a clamped index and masks back
+    to padding (the ``_PAD_COORD`` row, or scale 0.0) and id -1. Returns
+    ``(safe probe, sentinel mask or None, block)``."""
+    k, width, fp32 = spec.k, spec.width, spec.codec == "fp32"
+    pad, safe = None, probe
+    if spec.routed:
+        pad, safe = probe >= k, jnp.minimum(probe, k - 1)
+    gather = _store.gather_global if fp32 else _store.gather_global_q8
+    *payload, ids = gather(spec.kind, store_arrays if fp32
+                           else store_arrays[:-1], safe, width, spec.ps,
+                           spec.nsh)
+    if pad is not None:
         padw = jnp.repeat(pad, width, axis=1)
-        scales = jnp.where(padw, 0.0, scales)
-        cand_ids = jnp.where(padw, -1, cand_ids)
-    b, d = q.shape
-    with jax.named_scope("ivf.scan"):
-        anch = jnp.take(anchors, safe, axis=0)           # (B, nprobe, d)
-        anch = jnp.where(pad[:, :, None], 0.0, anch)
-        qp = q.astype(jnp.float32)[:, None, :] - anch
-        li, val = ops.flash_probe_grouped_q8(
-            qp, codes.reshape(b, nprobe, width, d),
-            scales.reshape(b, nprobe, width), l=r,
-            block_b=bsb, block_w=bsw, interpret=interpret)
-        ids = jnp.where(jnp.isfinite(val),
-                        jnp.take_along_axis(cand_ids, li, axis=1), -1)
-        deq = (jnp.take_along_axis(anch, (li // width)[:, :, None], axis=1)
-               + jnp.take_along_axis(codes, li[:, :, None], axis=1
-                                     ).astype(jnp.float32)
-               * jnp.take_along_axis(scales, li, axis=1)[:, :, None])
-    return ids, deq
+        if fp32:
+            payload[0] = jnp.where(padw[:, :, None], jnp.asarray(
+                _PAD_COORD, payload[0].dtype), payload[0])
+        else:
+            payload[1] = jnp.where(padw, 0.0, payload[1])
+        ids = jnp.where(padw, -1, ids)
+    return safe, pad, (*payload, ids)
 
 
-_ivf_search_q8_routed = functools.partial(
-    jax.jit, static_argnames=("kind", "k", "r", "nprobe", "npc", "leff",
-                              "width", "ps", "nsh", "bcn", "bck", "bfb",
-                              "bfc", "bsb", "bsw", "interpret")
-)(_q8_propose_routed)
+def _scan_q8(q: Array, anch: Array, codes: Array, scales: Array,
+             cand_ids: Array, *, l: int, spec: _SearchSpec
+             ) -> tuple[Array, Array, Array]:
+    """The quantized proposal scan, in the residual frame: with
+    ``q' = q - anchor[cell]`` the kernel's ``||q' - r||^2`` is the true
+    quantized distance, comparable across probe slots. Returns the
+    top-``l`` ``(slots, distances, ids)``; id -1 where fewer than ``l``
+    live candidates exist."""
+    b, n, d = anch.shape
+    bsb, bsw = spec.scan_tiles
+    qp = q.astype(jnp.float32)[:, None, :] - anch
+    li, val = ops.flash_probe_grouped_q8(
+        qp, codes.reshape(b, n, spec.width, d),
+        scales.reshape(b, n, spec.width), l=l, block_b=bsb, block_w=bsw,
+        interpret=spec.interpret)
+    ids = jnp.where(jnp.isfinite(val),
+                    jnp.take_along_axis(cand_ids, li, axis=1), -1)
+    return li, val, ids
 
 
-def _q8_propose(q: Array, centroids: Array, c_sq: Array,
-                store_arrays: tuple, *,
-                kind: str, r: int, nprobe: int, width: int, ps: int,
-                nsh: int, bqn: int, bqk: int, bsb: int, bsw: int,
-                interpret: bool | None) -> tuple[Array, Array]:
-    """Phase 1 of two-phase search on a quantized store: the cheap
-    proposer. Probe as usual, gather int8 codes + scales instead of f32
-    rows, and scan in the residual frame — ``q' = q - anchor[cell]``
-    makes the kernel's ``||q' - r||^2`` the *true* quantized distance
-    (globally comparable across probe slots, no per-candidate anchor
-    gather). Returns the top-``r`` candidate ids (-1 where fewer than
-    ``r`` live candidates exist) and their dequantized f32 rows — the
-    rescore fallback for ids the rescore tier no longer holds. Plain
-    traceable body; jitted standalone below and fused on the device
-    path.
-    """
+def _dequant(anch: Array, codes: Array, scales: Array, li: Array,
+             width: int) -> Array:
+    """The f32 rows of candidate slots ``li``: anchor + code · scale."""
+    return (jnp.take_along_axis(anch, (li // width)[:, :, None], axis=1)
+            + jnp.take_along_axis(codes, li[:, :, None], axis=1
+                                  ).astype(jnp.float32)
+            * jnp.take_along_axis(scales, li, axis=1)[:, :, None])
+
+
+def _probe_rank_pos(order: Array, li: Array, width: int) -> Array:
+    """A K-shard candidate's global probe-rank-major position — where the
+    one-device scan sees it — the tie key of the cross-shard merges."""
+    return jnp.take_along_axis(order, li // width, axis=1) * width \
+        + li % width
+
+
+@functools.partial(jax.jit, static_argnames=("spec",))
+def _ivf_search(q: Array, centroids: Array, c_sq: Array | None,
+                counts: Array | None, store_arrays: tuple,
+                route_arrays: tuple = (), cache_arrays: tuple = (), *,
+                spec: _SearchSpec):
+    """The one-device search program, one jit per spec: route → scan.
+
+    - fp32, flat router: the list-major scan (``_scan_lists``) →
+      ``(ids, dists)``;
+    - fp32, two-level router: gather → grouped scan → ``(ids, dists)``;
+    - q8: gather codes → quantized top-``r`` proposal → ``(ids, deq)``,
+      the dequantized rows the rescore falls back on; with a device
+      rescore cache also its ``(rows, found)`` lookup, in the same
+      program (no host transfer). The exact rescore is ``_ivf_rescore``.
+
+    ``c_sq`` (the flat probe's ``||c||^2``), ``counts`` (the list-major
+    scan's) and the router's and cache's arrays are passed only where
+    the spec's stages read them."""
     with jax.named_scope("ivf.probe"):
-        probe, _ = ops.flash_probe(q, centroids.astype(q.dtype), l=nprobe,
-                                   block_n=bqn, block_k=bqk,
-                                   interpret=interpret, want_dists=False,
-                                   c_sq=c_sq)
-    *arrays, anchors = store_arrays
+        probe = _route(q, centroids, c_sq, route_arrays, spec)
+    if spec.list_major:
+        g, bw = spec.scan_tiles
+        return _scan_lists(q, probe, counts, store_arrays, kind=spec.kind,
+                           topk=spec.topk, width=spec.width, ps=spec.ps,
+                           nsh=spec.nsh, g=g, bw=bw,
+                           interpret=spec.interpret)
     with jax.named_scope("ivf.gather"):
-        codes, scales, cand_ids = _store.gather_global_q8(
-            kind, tuple(arrays), probe, width, ps, nsh)
-    b, d = q.shape
+        safe, pad, cand = _gather(probe, store_arrays, spec)
     with jax.named_scope("ivf.scan"):
-        anch = jnp.take(anchors, probe, axis=0)      # (B, nprobe, d)
-        qp = q.astype(jnp.float32)[:, None, :] - anch
-        li, val = ops.flash_probe_grouped_q8(
-            qp, codes.reshape(b, nprobe, width, d),
-            scales.reshape(b, nprobe, width), l=r,
-            block_b=bsb, block_w=bsw, interpret=interpret)   # (B, r)
-        ids = jnp.where(jnp.isfinite(val),
-                        jnp.take_along_axis(cand_ids, li, axis=1), -1)
-        deq = (jnp.take_along_axis(anch, (li // width)[:, :, None], axis=1)
-               + jnp.take_along_axis(codes, li[:, :, None], axis=1
-                                     ).astype(jnp.float32)
-               * jnp.take_along_axis(scales, li, axis=1)[:, :, None])
-    return ids, deq
-
-
-_ivf_search_q8 = functools.partial(
-    jax.jit, static_argnames=("kind", "r", "nprobe", "width", "ps", "nsh",
-                              "bqn", "bqk", "bsb", "bsw", "interpret")
-)(_q8_propose)
+        if spec.codec == "fp32":
+            cand_x, cand_ids = cand
+            bsb, bsc = spec.scan_tiles
+            li, dist = ops.flash_probe_grouped(q, cand_x, l=spec.topk,
+                                               block_b=bsb, block_c=bsc,
+                                               interpret=spec.interpret)
+            return jnp.take_along_axis(cand_ids, li, axis=1), dist
+        codes, scales, cand_ids = cand
+        anch = jnp.take(store_arrays[-1], safe, axis=0)   # (B, nprobe, d)
+        if pad is not None:
+            anch = jnp.where(pad[:, :, None], 0.0, anch)
+        li, _, ids = _scan_q8(q, anch, codes, scales, cand_ids, l=spec.r,
+                              spec=spec)
+        deq = _dequant(anch, codes, scales, li, spec.width)
+    if not spec.cached:
+        return ids, deq
+    with jax.named_scope("ivf.rescore"):
+        rows, found = _rcache.cache_lookup(*cache_arrays, ids)
+    return ids, deq, rows, found
 
 
 def _rescore_body(q: Array, cand: Array, ids: Array, res_rows: Array,
@@ -481,62 +530,105 @@ def _rescore_body(q: Array, cand: Array, ids: Array, res_rows: Array,
         return jnp.take_along_axis(ids, li, axis=1), dist
 
 
+# its own executable, shared by every tier and mesh: sharing the compiled
+# program is what makes device-vs-host distances bitwise identical, not
+# merely close (fusing it into the proposal program changes XLA's
+# contraction order at the ulp level)
 _ivf_rescore = functools.partial(
     jax.jit, static_argnames=("topk", "bsb", "bsc", "interpret")
 )(_rescore_body)
 
 
-@functools.partial(jax.jit, static_argnames=("kind", "r", "nprobe",
-                                             "width", "ps", "nsh", "bqn",
-                                             "bqk", "bsb", "bsw",
-                                             "interpret"))
-def _ivf_search_q8_device(q: Array, centroids: Array, c_sq: Array,
-                          store_arrays: tuple, ckeys: Array, crows: Array,
-                          *, kind: str, r: int, nprobe: int, width: int,
-                          ps: int, nsh: int, bqn: int, bqk: int, bsb: int,
-                          bsw: int, interpret: bool | None
-                          ) -> tuple[Array, Array, Array, Array]:
-    """The device-resident hot path, phase 1: propose + cache lookup in
-    one jitted program — phase 2 reads original rows from the
-    ``DeviceRescoreCache`` gather instead of a host reservoir
-    round-trip, so no device→host transfer happens anywhere. The exact
-    rescore itself runs through the *same* ``_ivf_rescore`` executable
-    the host oracle uses (deliberately not fused here: sharing the
-    compiled program is what makes device-vs-host distances bitwise
-    identical, not merely close — fusing the scan changes XLA's
-    contraction order at the ulp level)."""
-    ids, deq = _q8_propose(q, centroids, c_sq, store_arrays, kind=kind,
-                           r=r, nprobe=nprobe, width=width, ps=ps,
-                           nsh=nsh, bqn=bqn, bqk=bqk, bsb=bsb, bsw=bsw,
-                           interpret=interpret)
-    with jax.named_scope("ivf.rescore"):
-        rows, found = _rcache.cache_lookup(ckeys, crows, ids)
-    return ids, deq, rows, found
+def _make_sharded_search(pctx, spec: _SearchSpec, store_specs: tuple,
+                         cache_specs: tuple):
+    """The search program on a cells-sharded mesh: the one-device stages
+    in one shard_map'd program per spec. Queries are sharded over the
+    data axes; each K-shard routes (``_route_sharded``), compacts its
+    owned probed cells (``_owned_cells``), gathers and scans them, and
+    the lists merge across shards by the global probe-rank-major tie key.
+    Posting-list payloads never leave their shard.
 
+    - fp32: the local grouped scan, then the global top-k merge
+      (O(b·topk) wire bytes) plus ``||q||^2`` → ``(ids, dists)``;
+    - q8: the quantized top-R merge, then one O(b·R·d) psum row exchange
+      (each proposal's row summed through a one-hot id match; every live
+      id has one owner) → ``(ids, rows)``: the single-device phase-1
+      contract. With a device rescore cache each shard swaps its cache
+      hits into its local rows before the exchange.
 
-@functools.partial(jax.jit, static_argnames=("kind", "k", "r", "nprobe",
-                                             "npc", "leff", "width", "ps",
-                                             "nsh", "bcn", "bck", "bfb",
-                                             "bfc", "bsb", "bsw",
-                                             "interpret"))
-def _ivf_search_q8_routed_device(q: Array, centroids: Array,
-                                 coarse: Array, coarse_sq: Array,
-                                 members: Array, store_arrays: tuple,
-                                 ckeys: Array, crows: Array, *, kind: str,
-                                 k: int, r: int, nprobe: int, npc: int,
-                                 leff: int, width: int, ps: int, nsh: int,
-                                 bcn: int, bck: int, bfb: int, bfc: int,
-                                 bsb: int, bsw: int, interpret: bool | None
-                                 ) -> tuple[Array, Array, Array, Array]:
-    """Routed variant of the device-resident hot path (phase 1)."""
-    ids, deq = _q8_propose_routed(
-        q, centroids, coarse, coarse_sq, members, store_arrays, kind=kind,
-        k=k, r=r, nprobe=nprobe, npc=npc, leff=leff, width=width, ps=ps,
-        nsh=nsh, bcn=bcn, bck=bck, bfb=bfb, bfc=bfc, bsb=bsb, bsw=bsw,
-        interpret=interpret)
-    with jax.named_scope("ivf.rescore"):
-        rows, found = _rcache.cache_lookup(ckeys, crows, ids)
-    return ids, deq, rows, found
+    ``shard_ok`` ((P_k,) bool) is a traced input: a ``False`` entry
+    blanks that K-shard in every merge — the dead-shard path shares the
+    healthy program."""
+    ka, width, ll = pctx.k_axis, spec.width, spec.ll
+    nr, ns = 3 if spec.routed else 0, len(store_specs)
+
+    def shard_fn(q, c_local, csq_local, *rest):
+        route, arrays = rest[:nr], rest[nr:nr + ns]
+        shard_ok, cache = rest[nr + ns], rest[nr + ns + 1:]
+        rank = jax.lax.axis_index(ka)
+        alive = shard_ok[rank]
+        lo = rank * spec.k_local
+        with jax.named_scope("ivf.probe"):
+            gcell = _route_sharded(pctx, q, c_local, csq_local, route,
+                                   lo=lo, alive=alive, spec=spec)
+        with jax.named_scope("ivf.gather"):
+            cell, order = _owned_cells(gcell, lo, spec)
+            if spec.codec == "fp32":
+                cand_x, cand_ids = _store.gather_cells(
+                    spec.kind, arrays, cell, width, spec.ps)
+            else:
+                codes, scales, cand_ids = _store.gather_cells_q8(
+                    spec.kind, arrays[:-1], cell, width, spec.ps)
+        with jax.named_scope("ivf.scan"):
+            if spec.codec == "fp32":
+                bsb, bsc = spec.scan_tiles
+                lidx, lval = ops.flash_probe_grouped(
+                    q, cand_x, l=min(spec.topk, ll * width), block_b=bsb,
+                    block_c=bsc, interpret=spec.interpret, want_dists=False)
+                gids, gval = pctx.merge_topl(
+                    jnp.take_along_axis(cand_ids, lidx, axis=1), lval,
+                    spec.topk, tie=_probe_rank_pos(order, lidx, width),
+                    valid=alive)
+                q32 = q.astype(jnp.float32)
+                gval = gval + jnp.sum(q32 * q32, axis=-1, keepdims=True)
+                # blanked (dead-shard) slots carry inf: report them as
+                # honest empty results, never a non-finite distance
+                return gids, jnp.where(jnp.isfinite(gval),
+                                       jnp.maximum(gval, 0.0), 0.0)
+            # the padding cell k_local maps to a zero anchor row: its
+            # slots carry scale 0.0 and mask out
+            anch = jnp.take(jnp.concatenate(
+                [arrays[-1].astype(jnp.float32),
+                 jnp.zeros((1, q.shape[1]), jnp.float32)], axis=0),
+                cell, axis=0)                            # (bl, ll, d)
+            lidx, lval, ids_loc = _scan_q8(
+                q, anch, codes, scales, cand_ids,
+                l=min(spec.r, ll * width), spec=spec)
+            gids, _ = pctx.merge_topl(
+                ids_loc, lval, spec.r,
+                tie=_probe_rank_pos(order, lidx, width), valid=alive)
+            deq_loc = _dequant(anch, codes, scales, lidx, width)
+            if spec.cached:
+                crs, cfound = _rcache.cache_lookup(*cache, ids_loc)
+                deq_loc = jnp.where(cfound[:, :, None], crs, deq_loc)
+            match = jnp.logical_and(
+                gids[:, :, None] == ids_loc[:, None, :],
+                (ids_loc >= 0)[:, None, :]).astype(jnp.float32)
+            rows = jax.lax.psum(
+                jnp.einsum("brl,bld->brd", match, deq_loc), ka)
+            hit = jax.lax.psum(jnp.sum(match, axis=-1), ka)
+            return gids, jnp.where((hit > 0.0)[:, :, None], rows,
+                                   _PAD_COORD)
+
+    route_specs = (P(None, None), P(None), P(None, None)) if nr else ()
+    dp = pctx.data_axes
+    fn = pctx.spmd(
+        shard_fn,
+        in_specs=(pctx.data_spec, P(ka, None), P(ka), *route_specs,
+                  *store_specs, P(None), *cache_specs),
+        out_specs=(P(dp, None), P(dp, None) if spec.codec == "fp32"
+                   else P(dp, None, None)))
+    return jax.jit(fn)
 
 
 class IVFIndex:
@@ -646,8 +738,8 @@ class IVFIndex:
         self.router = _router.make_router(router, centroids,
                                           planner=self.planner,
                                           interpret=interpret)
-        self._search_plans: dict[tuple, tuple[int, int, int, int]] = {}
-        self._sharded_search: dict[tuple, object] = {}
+        self._search_plans: dict[tuple, tuple[int, ...]] = {}
+        self._sharded_search: dict[_SearchSpec, object] = {}
         self._add_programs: dict[int, object] = {}
         # hoisted all-shards-alive mask: the healthy sharded path reuses
         # one device constant instead of rebuilding np.ones + uploading
@@ -1097,6 +1189,39 @@ class IVFIndex:
         or the host-reservoir oracle path)."""
         return getattr(self.store, "cache", None)
 
+    def _search_spec(self, b: int, topk: int, nprobe: int,
+                     nprobe_c: int | None = None, *,
+                     plan: bool = True) -> _SearchSpec:
+        """The one place a query geometry becomes a search program's
+        static arguments. ``plan=False`` skips the tiles (the geometry
+        alone, for ``search_geometry``); otherwise they come from the
+        index's plan cache, keyed ``(b,) + spec.geometry``, planned on a
+        miss (``_plan_tiles``)."""
+        nprobe = min(nprobe, self.k)
+        st, cache = self.store, self._rescore_cache()
+        width = self._gather_width(topk, nprobe)
+        q8 = st.codec_kind != "fp32"
+        spec = _SearchSpec(
+            b=int(b), topk=int(topk), nprobe=nprobe, width=width,
+            kind=st.kind, ps=st.page_param, nsh=st.n_shards, k=self.k,
+            shards=self.pctx.n_k_shards if self._k_sharded else 0,
+            route=(self.router.fingerprint(nprobe, nprobe_c)
+                   if self.router.kind == "two_level" else ()),
+            codec=st.codec_kind,
+            r=self._rescore_r(topk, nprobe, width) if q8 else 0,
+            cache=cache.fingerprint() if cache is not None else (),
+            interpret=self.interpret)
+        if not plan:
+            return spec
+        key = (spec.b,) + spec.geometry
+        tiles = self._search_plans.get(key)
+        if tiles is None:
+            tiles = self._search_plans[key] = self._plan_tiles(spec)
+        head = 4 if spec.routed else 2
+        return spec._replace(route_tiles=tiles[:head],
+                             scan_tiles=tiles[head:head + 2],
+                             rescore_tiles=tiles[head + 2:])
+
     def search_geometry(self, topk: int = 10, nprobe: int = 8,
                         nprobe_c: int | None = None) -> tuple:
         """Cheap geometry fingerprint for serving layers: it changes
@@ -1105,15 +1230,8 @@ class IVFIndex:
         router — a re-grouping crossed a ``gcap`` bucket / moved the
         effective coarse width, or the device rescore cache grew its
         table), so a scheduler can re-pin its plans only then."""
-        nprobe = min(nprobe, self.k)
-        width = self._gather_width(topk, nprobe)
-        rfp = (self.router.fingerprint(nprobe, nprobe_c)
-               if self.router.kind == "two_level" else ())
-        cache = self._rescore_cache()
-        cfp = cache.fingerprint() if cache is not None else ()
-        if self._k_sharded:
-            return (nprobe, topk, width, self.pctx.n_k_shards) + rfp + cfp
-        return (nprobe, topk, width) + rfp + cfp
+        return self._search_spec(0, topk, nprobe, nprobe_c,
+                                 plan=False).geometry
 
     def _rescore_r(self, topk: int, nprobe: int, width: int) -> int:
         """Phase-1 proposal depth for two-phase search: ``rescore_mult``
@@ -1146,9 +1264,10 @@ class IVFIndex:
         gather width (a power-of-two bucket — occupancy growth changes
         the scan's tile count and naturally re-keys); the paths that
         still gather candidates (routed, q8, sharded) take the grouped
-        scan's ``(bsb, bsc)`` in its place. The plan is cached on the
-        index per ``(b, nprobe, topk, width)``, so the per-call chooser
-        recompute this method replaces can never return to the hot path.
+        scan's ``(bsb, bsc)`` in its place, and q8 appends the
+        quantized scan's ``(bsb, bsw)`` and the rescore's ``(brb, brc)``
+        instead. The plan is cached on the index per ``(b,) +``
+        ``search_geometry``, so repeated traffic never reaches a chooser.
         Serving layers with a fixed padded batch shape
         (``serve.engine.SearchEngine``) call this once at config time.
 
@@ -1165,74 +1284,50 @@ class IVFIndex:
         under partitioning (a plan taken at the global shapes would
         size tiles for a kernel that never runs).
         """
-        nprobe = min(nprobe, self.k)
-        width = self._gather_width(topk, nprobe)
-        routed = self.router.kind == "two_level"
-        rfp = self.router.fingerprint(nprobe, nprobe_c) if routed else ()
-        cache = self._rescore_cache()
-        cfp = cache.fingerprint() if cache is not None else ()
-        if self._k_sharded:
-            kl = self.pctx.k_local(self.k)
-            ll = min(nprobe, kl)          # max owned cells one query probes
-            li = min(topk, ll * width)    # local result-list length
+        s = self._search_spec(b, topk, nprobe, nprobe_c)
+        return s.route_tiles + s.scan_tiles + s.rescore_tiles
+
+    def _plan_tiles(self, spec: _SearchSpec) -> tuple[int, ...]:
+        """Ask the planner for each stage's tiles at the shapes the
+        spec's programs launch (see ``plan_search``)."""
+        plan, d, dt = self.planner.plan, self.d, self.dtype
+        nprobe, width, topk = spec.nprobe, spec.width, spec.topk
+        if spec.shards:
             pd = self.pctx.n_data_shards  # queries are data-sharded too
-            bl = max(1, ((int(b) + pd - 1) // pd))
-            geom = (int(b), nprobe, int(topk), width,
-                    self.pctx.n_k_shards) + rfp + cfp
-            probe_shape = (bl, kl, self.d, ll)
-            scan_shape = (bl, ll * width, self.d, li)
-            bq = bl
+            bq = max(1, -(-spec.b // pd))
+            ll = spec.ll
+            probe_shape = (bq, spec.k_local, d, ll)
+            scan_shape = (bq, ll * width, d, min(topk, ll * width))
         else:
-            geom = (int(b), nprobe, int(topk), width) + rfp + cfp
-            probe_shape = (b, self.k, self.d, nprobe)
-            scan_shape = (b, nprobe * width, self.d, topk)
-            bq = int(b)
-        plans = self._search_plans.get(geom)
-        if plans is None:
-            dt = self.dtype
-            if routed:
-                # routed head: coarse-probe tiles at (b, K_c) and
-                # fine-route scan tiles over each query's npc·gcap
-                # candidate centroids (the planner's "route" op models
-                # the same split for byte accounting)
-                kc, npc, gcap = rfp
-                leff = min(nprobe, npc * gcap)
-                rp = self.planner.plan("probe", (bq, kc, self.d, npc), dt)
-                rs = self.planner.plan(
-                    "scan", (bq, npc * gcap, self.d, leff), dt)
-                head = (*rp.blocks, *rs.blocks)
-            else:
-                head = self.planner.plan("probe", probe_shape, dt).blocks
-            if self.store.codec_kind != "fp32":
-                # two-phase geometry: the quantized proposal scan is
-                # planned as "scan_q8" (codec-aware bytes model) at the
-                # proposal depth, the exact rescore as a plain f32 scan
-                # over the R proposed rows (full batch — the rescore is
-                # never sharded; proposals already crossed the wire)
-                r = self._rescore_r(topk, nprobe, width)
-                if self._k_sharded:
-                    rl = min(r, scan_shape[1])
-                    q8_shape = (scan_shape[0], scan_shape[1], self.d, rl)
-                else:
-                    q8_shape = (b, nprobe * width, self.d, r)
-                q8 = self.planner.plan("scan_q8", q8_shape, jnp.int8)
-                # with a device cache the rescore stage is planned as
-                # its own "rescore" op (cache-gather-aware bytes model);
-                # the host-oracle path keeps the plain f32 scan pricing
-                rop = "scan" if cache is None else "rescore"
-                rescore = self.planner.plan(
-                    rop, (int(b), r, self.d, min(topk, r)), jnp.float32)
-                plans = (*head, *q8.blocks, *rescore.blocks)
-            elif self._list_major():
-                scan = self.planner.plan(
-                    "list_scan", (b * nprobe, self.k, width, self.d, topk),
-                    dt)
-                plans = (*head, *scan.blocks)
-            else:
-                scan = self.planner.plan("scan", scan_shape, dt)
-                plans = (*head, *scan.blocks)
-            self._search_plans[geom] = plans
-        return plans
+            bq = spec.b
+            probe_shape = (bq, self.k, d, nprobe)
+            scan_shape = (bq, nprobe * width, d, topk)
+        if spec.routed:
+            # coarse-probe tiles at (b, K_c) and fine-route scan tiles
+            # over each query's npc·gcap candidate centroids
+            kc, npc, gcap = spec.route
+            head = (*plan("probe", (bq, kc, d, npc), dt).blocks,
+                    *plan("scan", (bq, npc * gcap, d, spec.leff), dt).blocks)
+        else:
+            head = plan("probe", probe_shape, dt).blocks
+        if spec.codec != "fp32":
+            # the quantized proposal scan at the proposal depth
+            # (codec-aware bytes model), the exact rescore over the R
+            # proposed rows — full batch, never sharded; with a device
+            # cache as its own "rescore" op (cache-gather-aware bytes)
+            r = spec.r
+            q8_shape = (scan_shape[:3] + (min(r, scan_shape[1]),)
+                        if spec.shards else (bq, nprobe * width, d, r))
+            q8 = plan("scan_q8", q8_shape, jnp.int8)
+            rescore = plan("rescore" if spec.cached else "scan",
+                           (spec.b, r, d, min(topk, r)), jnp.float32)
+            return (*head, *q8.blocks, *rescore.blocks)
+        if spec.list_major:
+            scan = plan("list_scan", (bq * nprobe, self.k, width, d, topk),
+                        dt)
+        else:
+            scan = plan("scan", scan_shape, dt)
+        return (*head, *scan.blocks)
 
     def search(self, q, topk: int = 10, nprobe: int = 8, *,
                nprobe_c: int | None = None) -> tuple[Array, Array]:
@@ -1245,6 +1340,12 @@ class IVFIndex:
         group coverage; see ``TwoLevelRouter.effective_nprobe_c``).
         ``nprobe_c`` overrides that coarse width explicitly (two-level
         router only; ignored by the flat router).
+
+        One dispatch for every path: the spec (``_search_spec``) picks
+        the program — ``_ivf_search`` on one device, the cached
+        ``_make_sharded_search`` program on a cells-sharded mesh (the
+        batch padded to a data-shard multiple and sliced back) — and a
+        q8 store finishes through ``_rescore``.
         """
         q = jnp.asarray(q, self.dtype)
         nprobe = min(nprobe, self.k)
@@ -1268,459 +1369,91 @@ class IVFIndex:
                     else:   # one replica == the whole index: hard fail
                         raise InjectedFault(
                             f"injected replica death ({ev})")
-        if obs.enabled():
-            obs.count("ivf.units")
-            if self._list_major():
-                obs.count("ivf.list_scan_units")
-            obs.count("ivf.gathered_rows", self._gathered_rows(
-                q.shape[0], topk, nprobe))
-        if self.store.codec_kind != "fp32":
-            return self._search_q8(q, topk, nprobe, shard_ok=shard_ok,
-                                   nprobe_c=nprobe_c)
-        if self._k_sharded:
-            return self._search_sharded(q, topk, nprobe,
-                                        shard_ok=shard_ok,
-                                        nprobe_c=nprobe_c)
-        st = self.store
-        width = self._gather_width(topk, nprobe)
-        if self.router.kind == "two_level":
-            rt = self.router
-            kc, npc, gcap = rt.fingerprint(nprobe, nprobe_c)
-            leff = min(nprobe, npc * gcap)
-            bcn, bck, bfb, bfc, bsb, bsc = self.plan_search(
-                q.shape[0], topk, nprobe, nprobe_c)
-            return _ivf_search_routed(
-                q, self.centroids, rt.coarse, rt.coarse_sq, rt.members,
-                st.device_arrays(), kind=st.kind, k=self.k, topk=topk,
-                nprobe=nprobe, npc=npc, leff=leff, width=width,
-                ps=st.page_param, nsh=st.n_shards, bcn=bcn, bck=bck,
-                bfb=bfb, bfc=bfc, bsb=bsb, bsc=bsc,
-                interpret=self.interpret)
-        bqn, bqk, g, bw = self.plan_search(q.shape[0], topk, nprobe)
-        return _ivf_search(q, self.centroids, self._centroid_norms(),
-                           st.counts, st.device_arrays(),
-                           kind=st.kind, topk=topk, nprobe=nprobe,
-                           width=width,
-                           ps=st.page_param, nsh=st.n_shards,
-                           bqn=bqn, bqk=bqk, g=g, bw=bw,
-                           interpret=self.interpret)
-
-    def _list_major(self) -> bool:
-        """Whether search takes the list-major scan (flat router, fp32
-        payload, one device); the other paths gather candidates."""
-        return (self.store.codec_kind == "fp32" and not self._k_sharded
-                and self.router.kind != "two_level")
-
-    def _gathered_rows(self, b: int, topk: int, nprobe: int) -> int:
-        """Candidate rows one search call of ``b`` queries gathers, over
-        all devices: ``nprobe`` lists of the gather width per query, or,
-        on a cells-sharded mesh, the ``min(nprobe, K_local)`` owned lists
-        each K-shard gathers for every query of the data-padded batch.
-        The list-major scan gathers no candidates; its count is a bound on
-        the store rows it reads, every tile of the width for every
-        segment: it reads only each list's rows, and nothing for padding
-        segments."""
-        width = self._gather_width(topk, nprobe)
-        if self._list_major():
-            _, _, g, bw = self.plan_search(b, topk, nprobe)
-            bw = self.store.page_param or min(bw, self.store.capacity)
-            p = b * nprobe
-            segs = -(-p // g) + min(self.k, p)
-            return segs * -(-width // bw) * bw
-        if not self._k_sharded:
-            return b * nprobe * width
-        pctx = self.pctx
-        pd = pctx.n_data_shards
-        b_pad = -(-b // pd) * pd
-        ll = min(nprobe, pctx.k_local(self.k))
-        return b_pad * ll * width * pctx.n_k_shards
-
-    def _search_q8(self, q: Array, topk: int, nprobe: int,
-                   shard_ok=None, nprobe_c: int | None = None
-                   ) -> tuple[Array, Array]:
-        """Two-phase search on a quantized store.
-
-        Phase 1 proposes the top-``R`` candidates from the int8 payload
-        (``R = rescore_mult * topk``, clamped to the probed pool) — on a
-        mesh, each shard scans its owned buckets and the proposals merge
-        exactly like the fp32 path's final top-k, followed by one
-        O(b·R·d) psum row exchange so every proposal's row is
-        batch-local. Phase 2 rescores the R rows at full precision for
-        the final top-k, reading original rows from the rescore tier —
-        the :class:`DeviceRescoreCache` gather fused into the same
-        jitted program (zero host transfers; decoded codes where the
-        cache missed), or, on the ``rescore="host"`` oracle path, the
-        reservoir's host lookup by id. At full ``nprobe`` with R
-        covering the live candidates this reproduces brute force
-        exactly.
-        """
-        st = self.store
         b = q.shape[0]
-        width = self._gather_width(topk, nprobe)
-        r = self._rescore_r(topk, nprobe, width)
-        cache = self._rescore_cache()
         if self._k_sharded:
-            pctx = self.pctx
-            pd = pctx.n_data_shards
-            b_pad = ((b + pd - 1) // pd) * pd
+            pd = self.pctx.n_data_shards
+            b_pad = -(-b // pd) * pd
             if b_pad != b:
                 q = jnp.pad(q, ((0, b_pad - b), (0, 0)))
-            routed = self.router.kind == "two_level"
-            rfp = (self.router.fingerprint(nprobe, nprobe_c)
-                   if routed else ())
-            cfp = cache.fingerprint() if cache is not None else ()
-            *_, brb, brc = self.plan_search(b_pad, topk, nprobe, nprobe_c)
-            key = ("q8", b_pad, nprobe, topk, width) + rfp + cfp
-            prog = self._sharded_search.get(key)
+        spec = self._search_spec(q.shape[0], topk, nprobe, nprobe_c)
+        if obs.enabled():
+            obs.count("ivf.units")
+            if spec.list_major:
+                obs.count("ivf.list_scan_units")
+            obs.count("ivf.gathered_rows", self._gathered_rows(spec))
+        st, rt = self.store, self.router
+        route = (rt.coarse, rt.coarse_sq, rt.members) if spec.routed else ()
+        cache = st.cache_arrays() if spec.cached else ()
+        if spec.shards:
+            prog = self._sharded_search.get(spec)
             if prog is None:
-                prog = self._make_sharded_q8_candidates(b_pad, topk,
-                                                        nprobe, nprobe_c)
-                self._sharded_search[key] = prog
+                ka = self.pctx.k_axis
+                prog = self._sharded_search[spec] = _make_sharded_search(
+                    self.pctx, spec, st.shard_specs(ka),
+                    st.cache.shard_specs(ka) if spec.cached else ())
             ok = self._shard_ok_all() if shard_ok is None \
                 else jnp.asarray(shard_ok)
-            args = [pctx.shard_points(q), self.centroids,
-                    self._centroid_norms()]
-            if routed:
-                rt = self.router
-                args += [rt.coarse, rt.coarse_sq, rt.members]
-            if cache is not None:
-                # cache hits were substituted into the exchanged rows
-                # inside the program; finish through the SAME
-                # _ivf_rescore executable the host oracle uses (with a
-                # vacuous overlay) so the scan's rounding matches the
-                # oracle's bit for bit — still zero host operands
-                ids, rows = prog(*args, *st.device_arrays(), ok,
-                                 *st.cache_arrays())
-                out_ids, dist = _ivf_rescore(
-                    q, rows, ids, rows, jnp.zeros(ids.shape, bool),
-                    topk=topk, bsb=brb, bsc=brc,
-                    interpret=self.interpret)
-                return out_ids[:b], dist[:b]
-            ids, deq = prog(*args, *st.device_arrays(), ok)
-        elif self.router.kind == "two_level":
-            rt = self.router
-            kc, npc, gcap = rt.fingerprint(nprobe, nprobe_c)
-            leff = min(nprobe, npc * gcap)
-            bcn, bck, bfb, bfc, bsb, bsw, brb, brc = self.plan_search(
-                b, topk, nprobe, nprobe_c)
-            if cache is not None:
-                ids, deq, rows, found = _ivf_search_q8_routed_device(
-                    q, self.centroids, rt.coarse, rt.coarse_sq,
-                    rt.members, st.device_arrays(), *st.cache_arrays(),
-                    kind=st.kind, k=self.k, r=r, nprobe=nprobe, npc=npc,
-                    leff=leff, width=width, ps=st.page_param,
-                    nsh=st.n_shards, bcn=bcn, bck=bck, bfb=bfb, bfc=bfc,
-                    bsb=bsb, bsw=bsw, interpret=self.interpret)
-                out_ids, dist = _ivf_rescore(q, deq, ids, rows, found,
-                                             topk=topk, bsb=brb, bsc=brc,
-                                             interpret=self.interpret)
-                return out_ids[:b], dist[:b]
-            ids, deq = _ivf_search_q8_routed(
-                q, self.centroids, rt.coarse, rt.coarse_sq, rt.members,
-                st.device_arrays(), kind=st.kind, k=self.k, r=r,
-                nprobe=nprobe, npc=npc, leff=leff, width=width,
-                ps=st.page_param, nsh=st.n_shards, bcn=bcn, bck=bck,
-                bfb=bfb, bfc=bfc, bsb=bsb, bsw=bsw,
-                interpret=self.interpret)
+            out = prog(self.pctx.shard_points(q), self.centroids,
+                       self._centroid_norms(), *route, *st.device_arrays(),
+                       ok, *cache)
         else:
-            bqn, bqk, bsb, bsw, brb, brc = self.plan_search(b, topk,
-                                                            nprobe)
-            if cache is not None:
-                ids, deq, rows, found = _ivf_search_q8_device(
-                    q, self.centroids, self._centroid_norms(),
-                    st.device_arrays(), *st.cache_arrays(), kind=st.kind,
-                    r=r, nprobe=nprobe, width=width, ps=st.page_param,
-                    nsh=st.n_shards, bqn=bqn, bqk=bqk, bsb=bsb, bsw=bsw,
-                    interpret=self.interpret)
-                out_ids, dist = _ivf_rescore(q, deq, ids, rows, found,
-                                             topk=topk, bsb=brb, bsc=brc,
-                                             interpret=self.interpret)
-                return out_ids[:b], dist[:b]
-            ids, deq = _ivf_search_q8(
-                q, self.centroids, self._centroid_norms(),
-                st.device_arrays(), kind=st.kind,
-                r=r, nprobe=nprobe, width=width, ps=st.page_param,
-                nsh=st.n_shards, bqn=bqn, bqk=bqk, bsb=bsb, bsw=bsw,
-                interpret=self.interpret)
-        ids_np = np.asarray(ids)
-        res = getattr(st, "reservoir", None)
-        if res is not None:
-            rows, found = res.lookup(ids_np)
+            out = _ivf_search(
+                q, self.centroids,
+                None if spec.routed else self._centroid_norms(),
+                st.counts if spec.list_major else None, st.device_arrays(),
+                route, cache, spec=spec)
+        if spec.codec != "fp32":
+            out = self._rescore(q, out, spec)
+        if q.shape[0] != b:
+            out = (out[0][:b], out[1][:b])
+        return out
+
+    def _rescore(self, q: Array, out: tuple, spec: _SearchSpec
+                 ) -> tuple[Array, Array]:
+        """Phase 2 of q8 search: the exact rescore of the R proposals
+        (``_ivf_rescore``). Original rows come from the device rescore
+        cache — looked up inside the one-device program, or already
+        swapped into the exchanged rows on a mesh (a vacuous overlay
+        here) — else, on the ``rescore="host"`` oracle tier, from the
+        reservoir's host lookup by id; the dequantized codes fill in
+        where neither holds a row. At full ``nprobe`` with R covering
+        the live candidates this reproduces brute force exactly."""
+        ids, cand, *hit = out
+        if hit:
+            rows, found = hit
+        elif spec.cached:
+            rows, found = cand, jnp.zeros(ids.shape, bool)
         else:
-            rows = np.zeros(ids_np.shape + (self.d,), np.float32)
-            found = np.zeros(ids_np.shape, bool)
-        out_ids, dist = _ivf_rescore(q, deq, ids, jnp.asarray(rows),
-                                     jnp.asarray(found), topk=topk,
-                                     bsb=brb, bsc=brc,
-                                     interpret=self.interpret)
-        return out_ids[:b], dist[:b]
+            ids_np = np.asarray(ids)
+            res = getattr(self.store, "reservoir", None)
+            if res is not None:
+                rows, found = res.lookup(ids_np)
+            else:
+                rows = np.zeros(ids_np.shape + (self.d,), np.float32)
+                found = np.zeros(ids_np.shape, bool)
+            rows, found = jnp.asarray(rows), jnp.asarray(found)
+        brb, brc = spec.rescore_tiles
+        return _ivf_rescore(q, cand, ids, rows, found, topk=spec.topk,
+                            bsb=brb, bsc=brc, interpret=self.interpret)
 
-    def _make_sharded_q8_candidates(self, b_pad: int, topk: int,
-                                    nprobe: int,
-                                    nprobe_c: int | None = None):
-        """Phase-1 proposal program under cells sharding: the fp32
-        sharded search's probe/compact/scan skeleton with the quantized
-        kernel in the scan seat, a top-R (not top-k) merge, and one
-        psum row exchange — each proposal's row is summed across shards
-        through a one-hot id match (every live id is owned by exactly
-        one shard), so the rescore sees the same (ids, rows) contract
-        as the single-device phase 1. With a device rescore cache the
-        cache partitions over the same cells axis and each shard
-        substitutes its hits into the local rows *before* the exchange
-        — the existing O(b·R·d) wire carries original fp32 rows instead
-        of host re-uploads, and the whole program stays host-free.
-        Under the two-level router the probe seat runs the
-        replicated-coarse routed selection (``_route_cells_sharded``)
-        instead."""
-        pctx = self.pctx
-        ka = pctx.k_axis
-        k_local = pctx.k_local(self.k)
-        st = self.store
-        kind, ps = st.kind, st.page_param
-        width = self._gather_width(topk, nprobe)
-        r = self._rescore_r(topk, nprobe, width)
-        ll = min(nprobe, k_local)       # a query probes <= ll owned cells
-        rl = min(r, ll * width)         # local proposal-list length
-        routed = self.router.kind == "two_level"
-        if routed:
-            _, npc, gcap = self.router.fingerprint(nprobe, nprobe_c)
-            leff = min(nprobe, npc * gcap)
-            bcn, bck, bfb, bfc, bsb, bsw, _, _ = self.plan_search(
-                b_pad, topk, nprobe, nprobe_c)
-        else:
-            bqn, bqk, bsb, bsw, _, _ = self.plan_search(b_pad, topk,
-                                                        nprobe)
-        interpret = self.interpret
-        k, d = self.k, self.d
-        cached = self._rescore_cache() is not None
-
-        def shard_fn(q, c_local, csq_local, *rest):
-            if routed:
-                coarse, coarse_sq, members, *rest = rest
-            if cached:
-                *rest, ckeys, crows = rest
-            *arrays, anchors_l, shard_ok = rest
-            bl = q.shape[0]
-            alive = shard_ok[jax.lax.axis_index(ka)]
-            lo = jax.lax.axis_index(ka) * k_local
-            with jax.named_scope("ivf.probe"):
-                if routed:
-                    gcell = _route_cells_sharded(
-                        pctx, ka, q, c_local, coarse, coarse_sq, members,
-                        k=k, k_local=k_local, nprobe=nprobe, npc=npc,
-                        leff=leff, bcn=bcn, bck=bck, bfb=bfb, bfc=bfc,
-                        alive=alive, interpret=interpret)
-                else:
-                    idx, val = ops.flash_probe(q, c_local.astype(q.dtype),
-                                               l=ll, block_n=bqn,
-                                               block_k=bqk,
-                                               interpret=interpret,
-                                               want_dists=False,
-                                               c_sq=csq_local)
-                    gcell, _ = pctx.merge_topl(idx + lo, val, nprobe,
-                                               valid=alive)   # (bl, nprobe)
-            with jax.named_scope("ivf.gather"):
-                rel = gcell - lo
-                owned = jnp.logical_and(rel >= 0, rel < k_local)
-                pos = jax.lax.broadcasted_iota(jnp.int32, (bl, nprobe), 1)
-                order = jnp.argsort(jnp.where(owned, pos, nprobe),
-                                    axis=1)[:, :ll]
-                cell = jnp.take_along_axis(rel, order, axis=1)
-                ok = jnp.take_along_axis(owned, order, axis=1)
-                cell = jnp.where(ok, cell, k_local)
-                codes, scales, cand_ids = _store.gather_cells_q8(
-                    kind, tuple(arrays), cell, width, ps)
-            with jax.named_scope("ivf.scan"):
-                # residual-frame queries: the padding cell k_local maps to a
-                # zero anchor row — its slots carry scale 0.0 and mask out
-                anch = jnp.take(
-                    jnp.concatenate([anchors_l.astype(jnp.float32),
-                                     jnp.zeros((1, d), jnp.float32)], axis=0),
-                    cell, axis=0)                        # (bl, ll, d)
-                qp = q.astype(jnp.float32)[:, None, :] - anch
-                lidx, lval = ops.flash_probe_grouped_q8(
-                    qp, codes.reshape(bl, ll, width, d),
-                    scales.reshape(bl, ll, width), l=rl,
-                    block_b=bsb, block_w=bsw, interpret=interpret)
-                ids_loc = jnp.where(
-                    jnp.isfinite(lval),
-                    jnp.take_along_axis(cand_ids, lidx, axis=1), -1)
-                # same global probe-rank-major tie key as the fp32 merge
-                gpos = (jnp.take_along_axis(order, lidx // width, axis=1)
-                        * width + lidx % width)
-                gids, _ = pctx.merge_topl(ids_loc, lval, r, tie=gpos,
-                                          valid=alive)   # (bl, r)
-                # row exchange: dequantize the local proposals, match them
-                # against the merged id list, and psum — O(b·r·d) wire bytes
-                deq_loc = (
-                    jnp.take_along_axis(anch, (lidx // width)[:, :, None],
-                                        axis=1)
-                    + jnp.take_along_axis(codes, lidx[:, :, None], axis=1
-                                          ).astype(jnp.float32)
-                    * jnp.take_along_axis(scales, lidx, axis=1)[:, :, None])
-                if cached:
-                    # the local cache slice holds exactly the ids this
-                    # shard owns (home cell fixed at append time): swap
-                    # original fp32 rows in for the dequantized local
-                    # proposals, then let the existing exchange carry them
-                    crs, cfound = _rcache.cache_lookup(ckeys, crows, ids_loc)
-                    deq_loc = jnp.where(cfound[:, :, None], crs, deq_loc)
-                match = jnp.logical_and(
-                    gids[:, :, None] == ids_loc[:, None, :],
-                    (ids_loc >= 0)[:, None, :]).astype(jnp.float32)
-                rows = jax.lax.psum(jnp.einsum("brl,bld->brd", match, deq_loc),
-                                    ka)
-                hit = jax.lax.psum(jnp.sum(match, axis=-1), ka)
-                rows = jnp.where((hit > 0.0)[:, :, None], rows, _PAD_COORD)
-            return gids, rows
-
-        router_specs = ((P(None, None), P(None), P(None, None))
-                        if routed else ())
-        cache_specs = self._rescore_cache().shard_specs(ka) if cached \
-            else ()
-        fn = pctx.spmd(
-            shard_fn,
-            in_specs=(pctx.data_spec, P(ka, None), P(ka), *router_specs,
-                      *st.shard_specs(ka), P(None), *cache_specs),
-            out_specs=(P(pctx.data_axes, None),
-                       P(pctx.data_axes, None, None)))
-        return jax.jit(fn)
-
-    def _search_sharded(self, q: Array, topk: int, nprobe: int,
-                        shard_ok=None, nprobe_c: int | None = None
-                        ) -> tuple[Array, Array]:
-        """Two-stage sharded search (one shard_map'd program, cached per
-        geometry). Queries are sharded over the data axes (each data
-        shard searches its slice — no replicated compute; a ragged batch
-        is padded and sliced back); per-batch cross-shard traffic is two
-        (value, index) top-L merges over the cells axis —
-        ``pctx.search_collective_bytes`` models it; the posting-list
-        payloads never leave their owning shard.
-
-        ``shard_ok`` ((P_k,) bool, default all-alive) is a traced input:
-        a ``False`` entry blanks that K-shard's contribution to both
-        merges (``merge_topl(valid=...)``) — the dead-shard degradation
-        path shares the healthy program, no recompile."""
-        pctx = self.pctx
-        b = q.shape[0]
-        pd = pctx.n_data_shards
-        b_pad = ((b + pd - 1) // pd) * pd
-        if b_pad != b:
-            q = jnp.pad(q, ((0, b_pad - b), (0, 0)))
-        routed = self.router.kind == "two_level"
-        rfp = (self.router.fingerprint(nprobe, nprobe_c)
-               if routed else ())
-        key = (b_pad, nprobe, topk, self._gather_width(topk, nprobe)) + rfp
-        prog = self._sharded_search.get(key)
-        if prog is None:
-            prog = self._make_sharded_search(b_pad, topk, nprobe, nprobe_c)
-            self._sharded_search[key] = prog
-        ok = self._shard_ok_all() if shard_ok is None \
-            else jnp.asarray(shard_ok)
-        args = [pctx.shard_points(q), self.centroids,
-                self._centroid_norms()]
-        if routed:
-            rt = self.router
-            args += [rt.coarse, rt.coarse_sq, rt.members]
-        ids, dists = prog(*args, *self.store.device_arrays(), ok)
-        return ids[:b], dists[:b]
-
-    def _make_sharded_search(self, b_pad: int, topk: int, nprobe: int,
-                             nprobe_c: int | None = None):
-        pctx = self.pctx
-        ka = pctx.k_axis
-        k_local = pctx.k_local(self.k)
-        st = self.store
-        kind, ps = st.kind, st.page_param
-        width = self._gather_width(topk, nprobe)
-        ll = min(nprobe, k_local)       # a query probes <= ll owned cells
-        li = min(topk, ll * width)      # local result-list length
-        routed = self.router.kind == "two_level"
-        if routed:
-            _, npc, gcap = self.router.fingerprint(nprobe, nprobe_c)
-            leff = min(nprobe, npc * gcap)
-            bcn, bck, bfb, bfc, bsb, bsc = self.plan_search(
-                b_pad, topk, nprobe, nprobe_c)
-        else:
-            bqn, bqk, bsb, bsc = self.plan_search(b_pad, topk, nprobe)
-        interpret = self.interpret
-        k = self.k
-
-        def shard_fn(q, c_local, csq_local, *rest):
-            if routed:
-                coarse, coarse_sq, members, *rest = rest
-            *arrays, shard_ok = rest
-            bl = q.shape[0]             # per-data-shard query slice
-            # a dead shard (reliability seam) contributes to neither merge
-            alive = shard_ok[jax.lax.axis_index(ka)]
-            lo = jax.lax.axis_index(ka) * k_local
-            with jax.named_scope("ivf.probe"):
-                if routed:
-                    # stage 1': replicated coarse probe + owned-candidate
-                    # fine scoring + the same cross-shard top-L merge
-                    gcell = _route_cells_sharded(
-                        pctx, ka, q, c_local, coarse, coarse_sq, members,
-                        k=k, k_local=k_local, nprobe=nprobe, npc=npc,
-                        leff=leff, bcn=bcn, bck=bck, bfb=bfb, bfc=bfc,
-                        alive=alive, interpret=interpret)
-                else:
-                    # stage 1: local top-ll probe over the owned centroids,
-                    # then the cross-shard top-nprobe merge — O(b·ll) wire
-                    idx, val = ops.flash_probe(q, c_local.astype(q.dtype),
-                                               l=ll, block_n=bqn,
-                                               block_k=bqk,
-                                               interpret=interpret,
-                                               want_dists=False,
-                                               c_sq=csq_local)
-                    gcell, _ = pctx.merge_topl(idx + lo, val, nprobe,
-                                               valid=alive)   # (bl, nprobe)
-            with jax.named_scope("ivf.gather"):
-                # stage 2: compact this shard's owned probed cells (stable:
-                # global probe order preserved) into a fixed (bl, ll) block;
-                # non-owned slots point at the padding cell k_local, which
-                # the store's gather maps onto padding slots
-                rel = gcell - lo
-                owned = jnp.logical_and(rel >= 0, rel < k_local)
-                pos = jax.lax.broadcasted_iota(jnp.int32, (bl, nprobe), 1)
-                order = jnp.argsort(jnp.where(owned, pos, nprobe),
-                                    axis=1)[:, :ll]
-                cell = jnp.take_along_axis(rel, order, axis=1)
-                ok = jnp.take_along_axis(owned, order, axis=1)
-                cell = jnp.where(ok, cell, k_local)
-                cand_x, cand_ids = _store.gather_cells(kind, tuple(arrays),
-                                                       cell, width, ps)
-            with jax.named_scope("ivf.scan"):
-                # stage 3: local grouped scan of the owned buckets (payloads
-                # stay on-shard), then the global top-k merge — O(b·topk).
-                # The tie key is each candidate's *global probe-rank-major*
-                # position — exactly the candidate-axis position the
-                # single-device scan sees it at — so equal distances break
-                # identically to `jax.lax.top_k` over the reference
-                # candidate block, not toward the lower shard rank.
-                lidx, lval = ops.flash_probe_grouped(
-                    q, cand_x, l=li, block_b=bsb, block_c=bsc,
-                    interpret=interpret, want_dists=False)
-                ids_loc = jnp.take_along_axis(cand_ids, lidx, axis=1)
-                gpos = (jnp.take_along_axis(order, lidx // width, axis=1)
-                        * width + lidx % width)
-                gids, gval = pctx.merge_topl(ids_loc, lval, topk, tie=gpos,
-                                             valid=alive)
-                q32 = q.astype(jnp.float32)
-                gval = gval + jnp.sum(q32 * q32, axis=-1, keepdims=True)
-                # blanked (dead-shard) slots carry inf: report them as honest
-                # empty results, never a non-finite distance
-                gval = jnp.where(jnp.isfinite(gval), jnp.maximum(gval, 0.0),
-                                 0.0)
-            return gids, gval
-
-        router_specs = ((P(None, None), P(None), P(None, None))
-                        if routed else ())
-        fn = pctx.spmd(
-            shard_fn,
-            in_specs=(pctx.data_spec, P(ka, None), P(ka), *router_specs,
-                      *st.shard_specs(ka), P(None)),
-            out_specs=(P(pctx.data_axes, None), P(pctx.data_axes, None)))
-        return jax.jit(fn)
+    def _gathered_rows(self, spec: _SearchSpec) -> int:
+        """Candidate rows one search unit gathers, over all devices:
+        ``nprobe`` lists of the gather width per query, or, on a
+        cells-sharded mesh, the ``ll`` owned lists each K-shard gathers
+        for every query of the data-padded batch. The list-major scan
+        gathers no candidates; its count is a bound on the store rows it
+        reads, every tile of the width for every segment: it reads only
+        each list's rows, and nothing for padding segments."""
+        b, width = spec.b, spec.width
+        if spec.list_major:
+            g, bw = spec.scan_tiles
+            bw = spec.ps or min(bw, self.store.capacity)
+            p = b * spec.nprobe
+            segs = -(-p // g) + min(self.k, p)
+            return segs * -(-width // bw) * bw
+        if spec.shards:
+            return b * spec.ll * width * spec.shards
+        return b * spec.nprobe * width
 
     def search_brute(self, q, topk: int = 10) -> tuple[Array, Array]:
         """Dense brute-force reference over every indexed vector (the

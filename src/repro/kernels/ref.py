@@ -10,8 +10,7 @@ These implement the *standard* (paper Algorithm 1) dataflow faithfully:
 - ``update_dense_onehot_ref`` is the contention-free-but-FLOP-dense
   alternative (``S = A_onehot^T X``) used as a second baseline.
 
-They double as numerical oracles for the Pallas kernels in tests and as
-the *baseline implementations* in the paper-table benchmarks.
+They double as numerical oracles for the Pallas kernels in tests.
 """
 from __future__ import annotations
 

@@ -6,7 +6,9 @@ Two tiers:
   exactly 1 device) — marked slow, run explicitly by CI;
 - single-device invariants (mesh helpers, logical-axis rules, the
   collective-bytes model, and the "zero shard_map call sites outside
-  core/parallel.py" architecture guard) run in-process in tier-1.
+  core/parallel.py" architecture guard) run in-process in tier-1;
+- the in-process multi-device tests (``test_inprocess_*``) skip on one
+  device; tier-1 runs them in a child pytest on 8 fake CPU devices.
 """
 import os
 import re
@@ -138,10 +140,9 @@ def test_unsharded_index_reports_zero_collective_bytes(key):
 
 
 # ---------------------------------------------------------------------------
-# in-process multi-device tests — run by the CI leg that sets
-# XLA_FLAGS=--xla_force_host_platform_device_count=8; self-skip on the
-# plain single-device tier-1 run (the slow subprocess worker covers the
-# full matrix there)
+# in-process multi-device tests — run where
+# XLA_FLAGS=--xla_force_host_platform_device_count=8 is set (the CI leg,
+# and tier-1's child run below); self-skip on one device
 # ---------------------------------------------------------------------------
 
 def _require_devices(n: int):
@@ -422,6 +423,28 @@ def test_inprocess_result_merge_breaks_ties_by_probe_order():
     assert int(ids_ref[0, 0]) == 1
     assert np.array_equal(np.asarray(ids_sh), np.asarray(ids_ref))
     np.testing.assert_allclose(np.asarray(d_sh), np.asarray(d_ref))
+
+
+def test_sharded_search_on_eight_cpu_devices():
+    """The in-process tests above skip on one device, as tier-1 runs:
+    run them in a child process on 8 fake CPU devices, so tier-1 covers
+    the sharded search program on every router and codec."""
+    env = dict(os.environ)
+    env["PYTHONPATH"] = os.pathsep.join(
+        [os.path.join(ROOT, "src"), env.get("PYTHONPATH", "")])
+    env["JAX_PLATFORMS"] = "cpu"
+    env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "")
+                        + " --xla_force_host_platform_device_count=8")
+    r = subprocess.run(
+        [sys.executable, "-m", "pytest", "-q", "-p", "no:cacheprovider",
+         "-p", "no:randomly", "-p", "xdist", "-n", "2", "-k", "inprocess",
+         os.path.abspath(__file__)],
+        capture_output=True, text=True, env=env, cwd=ROOT, timeout=900)
+    tail = r.stdout[-4000:]
+    assert r.returncode == 0, tail + r.stderr[-2000:]
+    passed = re.search(r"(\d+) passed", tail)
+    assert passed and int(passed.group(1)) >= 8 and "skipped" not in tail, \
+        tail
 
 
 def test_streaming_rejects_k_sharded_context():

@@ -198,10 +198,12 @@ def test_ivf_search_hlo_carries_stage_scopes(one_chip, no_compile_cache):
             [((b, d), F32), ((k, d), F32), ((k,), F32), ((k,), I32)]]
     store = tuple(jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
                   for s, dt in [((k, cap, d), F32), ((k, cap), I32)])
-    hlo = ivf._ivf_search.lower(
-        *args, store, kind="padded", topk=topk, nprobe=nprobe, width=cap,
-        ps=0, nsh=1, bqn=bqn, bqk=bqk, g=g, bw=bw,
-        interpret=False).compile().as_text()
+    spec = ivf._SearchSpec(b=b, topk=topk, nprobe=nprobe, width=cap,
+                           kind="padded", ps=0, nsh=1, k=k,
+                           route_tiles=(bqn, bqk), scan_tiles=(g, bw),
+                           interpret=False)
+    hlo = ivf._ivf_search.lower(*args, store,
+                                spec=spec).compile().as_text()
     op_names = re.findall(r'op_name="([^"]*)"', hlo)
     for stage in ("ivf.probe", "ivf.gather", "ivf.scan"):
         assert any(f"/{stage}/" in n for n in op_names), stage
